@@ -115,6 +115,10 @@ def _run_child(n: int, chunk: int, budget: int, block: int, ell: float,
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + repo
+    if ndev > 1:
+        # a CPU rehearsal on forced host devices: it must never reach for a
+        # chip that this process may hold
+        env["JAX_PLATFORMS"] = "cpu"
     code = _INGEST_CHILD.format(n=n, chunk=chunk, budget=budget, block=block,
                                 ell=ell, ndev=ndev)
     r = subprocess.run([sys.executable, "-c", code], env=env,

@@ -244,6 +244,9 @@ def bench_scale(n: int = 1_048_576, methods=("nystrom", "wnystrom", "rff")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + repo
+    # a CPU rehearsal on forced host devices: it must never reach for a
+    # chip that this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     rows = []
     for method in methods:
         mknob = 256 if method == "rff" else 1024

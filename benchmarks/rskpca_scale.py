@@ -240,6 +240,9 @@ def bench_sharded(precision: str = "bf16"):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + repo
+    # a CPU rehearsal on forced host devices: it must never reach for a
+    # chip that this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", _SHARD_CHILD.format(precision=precision)],
         env=env, capture_output=True, text=True, timeout=1800)
@@ -451,7 +454,7 @@ from repro.core.distributed import distributed_shadow_rsde
 from repro.core import mmd as M
 from repro.data import make_dataset
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("data",))
 for n in (4096, 16384):
     x, _, sigma = make_dataset("pendigits", seed=0, n=n)
@@ -477,6 +480,9 @@ def main(fast: bool = True):
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "src")
+    # a CPU rehearsal on forced host devices: it must never reach for a
+    # chip that this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                        capture_output=True, text=True, timeout=1800)
     for line in r.stdout.splitlines():

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.kernels_math import Kernel, gram_matrix, gram_matrix_dense
 from repro.core.rsde import RSDE
 from repro.core import shadow as shadow_mod
@@ -60,7 +59,7 @@ def _two_level_select(x: Array, valid: Array, eps: Array, mesh: Mesh,
         all_w = jax.lax.all_gather(w, axis, tiled=True)   # (ndev*max_local,)
         return all_c, all_w
 
-    all_c, all_w = shard_map(
+    all_c, all_w = jax.shard_map(
         level1, mesh=mesh, in_specs=(P(axis, None), P(axis)),
         out_specs=(P(None, None), P(None)), check_vma=False,
     )(x, valid)
@@ -92,7 +91,7 @@ def _chunk_select_sharded(xp: Array, valid: Array, eps2: Array, mesh: Mesh,
             x_loc, eps2, block, v_loc, jnp.asarray(0, jnp.int32))
         return c, w
 
-    return shard_map(
+    return jax.shard_map(
         level1, mesh=mesh, in_specs=(P(axis, None), P(axis)),
         out_specs=(P(axis, None), P(axis)), check_vma=False,
     )(xp, valid)
@@ -145,7 +144,7 @@ def blocked_gram_rows(x, centers, kernel: Kernel, mesh: Mesh,
     def block(x_loc, c_rep):
         return gram_matrix(kernel, x_loc, c_rep)
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh, in_specs=(P(axis, None), P(None, None)),
         out_specs=P(axis, None), check_vma=False,
     )(x, c)
@@ -156,7 +155,7 @@ def _sharded_assign_jit(xp, c, v, mesh: Mesh, axis: str):
     def block(x_loc, c_rep, v_rep):
         return kernel_ops.shadow_assign(x_loc, c_rep, valid=v_rep)
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(None)),
         out_specs=(P(axis), P(axis)), check_vma=False,
@@ -208,7 +207,7 @@ def sharded_weighted_gram(centers, weights, kernel: Kernel, mesh: Mesh,
         g = gram_matrix_dense(kernel, c_loc, c_rep)
         return g * jnp.sqrt(w_loc)[:, None] * jnp.sqrt(w_rep)[None, :]
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(None, None), P(None)),
         out_specs=P(axis, None), check_vma=False,
@@ -230,7 +229,7 @@ def _sharded_project_jit(xp, c, a, kernel: Kernel, mesh: Mesh, axis: str,
                 chunk=chunk, precision=kernel.precision)
         return gram_matrix_dense(kernel, x_loc, c_rep) @ a_rep
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(None, None)),
         out_specs=P(axis, None), check_vma=False,
@@ -296,7 +295,7 @@ def _fit_rskpca_sharded(c: Array, w: Array, n: Array, kernel: Kernel,
                     c_loc, c_rep, v_rep, wx=w_loc, wy=w_rep,
                     sigma=kernel.sigma, p=kernel.p,
                     precision=kernel.precision, allow_dense=False)
-            out = shard_map(
+            out = jax.shard_map(
                 blk, mesh=mesh,
                 in_specs=(P(axis, None), P(axis), P(None, None), P(None),
                           P(None, None)),
@@ -312,7 +311,7 @@ def _fit_rskpca_sharded(c: Array, w: Array, n: Array, kernel: Kernel,
                 def blk(k_loc, v_rep):
                     return jnp.dot(k_loc, v_rep,
                                    preferred_element_type=jnp.float32)
-                return shard_map(
+                return jax.shard_map(
                     blk, mesh=mesh, in_specs=(P(axis, None), P(None, None)),
                     out_specs=P(axis, None), check_vma=False,
                 )(kt, v)
@@ -386,7 +385,7 @@ def _sharded_extend_jit(xp, lmk, bmat, kernel: Kernel, mesh: Mesh,
                 precision=kernel.precision)
         return gram_matrix_dense(kernel, x_loc, l_rep) @ b_rep
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(None, None)),
         out_specs=P(axis, None), check_vma=False,
@@ -427,7 +426,7 @@ def sharded_rff_cov(xd, ok, omega, phase, mesh: Mesh, axis: str = "data", *,
             preferred_element_type=jnp.float32)
         return jax.lax.psum(part, axis)
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(None, None), P(None)),
         out_specs=P(None, None), check_vma=False,
@@ -444,7 +443,7 @@ def _sharded_rff_project_jit(xp, omega, phase, u, mesh: Mesh, axis: str,
             x_loc, w_rep, b_rep, u_rep, scale=scale, chunk=chunk,
             precision=precision)
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(None), P(None, None)),
         out_specs=P(axis, None), check_vma=False,

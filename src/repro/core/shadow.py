@@ -226,6 +226,25 @@ def _pow2_ceil(v: int) -> int:
     return 1 << max(v - 1, 0).bit_length()
 
 
+def _pad_pow2(x: np.ndarray, orig: np.ndarray, w: np.ndarray | None):
+    """Pad a working set with dead zero rows to a power of two, so the
+    number of distinct jit shapes stays logarithmic in n.  Returns
+    (x, padded-row -> original-row map, alive mask, masses or None)."""
+    k = x.shape[0]
+    npad = _pow2_ceil(k)
+    xp = np.zeros((npad, x.shape[1]), np.float32)
+    xp[:k] = x
+    op = np.zeros((npad,), np.int64)
+    op[:k] = orig
+    alive = np.zeros((npad,), bool)
+    alive[:k] = True
+    if w is not None:
+        wp = np.zeros((npad,), np.float32)
+        wp[:k] = w
+        w = wp
+    return xp, op, alive, w
+
+
 def shadow_select_blocked(x, eps: float, block: int | None = None,
                           weights=None):
     """Blocked Algorithm 2: ~m/B sequential rounds instead of m iterations,
@@ -234,9 +253,11 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
     Work efficiency: every round's absorption pass costs O(alive_now * B),
     but late rounds mostly revisit dead points if the loop keeps the full
     array.  So the device loop runs until the alive set HALVES, the host
-    compacts the survivors (padded to a power of two so re-jit count stays
-    logarithmic), and selection resumes on the smaller array — total
-    absorption work drops from rounds*n to ~2x the first phase.
+    compacts the survivors, and selection resumes on the smaller array —
+    total absorption work drops from rounds*n to ~2x the first phase.  The
+    input and every compacted set are padded to a power of two, so a stream
+    of calls at varying n (the streaming merge's candidate batches) compiles
+    a logarithmic number of programs.
 
     Returns (centers (m, d), weights (m,), assign (n,), m) exactly like
     ``shadow_select_host``.  The center SET differs from the sequential order
@@ -258,10 +279,9 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
     assign = np.full((n,), -1, np.int64)
     centers_out, weights_out = [], []
     m = 0
-    cur_x = x_np                    # padded working set
-    cur_orig = np.arange(n)         # padded-row -> original-row map
-    cur_alive = np.ones((n,), bool)
-    cur_w = None if weights is None else np.asarray(weights, np.float32)
+    w_np = None if weights is None else np.asarray(weights, np.float32)
+    # padded working set: rows, padded-row -> original-row map, alive mask
+    cur_x, cur_orig, cur_alive, cur_w = _pad_pow2(x_np, np.arange(n), w_np)
     while cur_alive.any():
         b = max(1, min(block, cur_x.shape[0]))
         n_alive = int(cur_alive.sum())
@@ -279,21 +299,9 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
         still = np.flatnonzero(np.asarray(alive))
         if still.size == 0:
             break
-        # compact survivors; pad to a power of two with dead zero rows so
-        # the number of distinct jit shapes stays logarithmic
-        npad = _pow2_ceil(still.size)
-        nxt = np.zeros((npad, x_np.shape[1]), np.float32)
-        nxt[: still.size] = cur_x[still]
-        cur_x = nxt
-        nxt_orig = np.zeros((npad,), np.int64)
-        nxt_orig[: still.size] = cur_orig[still]
-        cur_orig = nxt_orig
-        cur_alive = np.zeros((npad,), bool)
-        cur_alive[: still.size] = True
-        if cur_w is not None:
-            nxt_w = np.zeros((npad,), np.float32)
-            nxt_w[: still.size] = cur_w[still]
-            cur_w = nxt_w
+        cur_x, cur_orig, cur_alive, cur_w = _pad_pow2(
+            cur_x[still], cur_orig[still],
+            None if cur_w is None else cur_w[still])
     return (np.concatenate(centers_out),
             np.concatenate(weights_out).astype(np.float64),
             assign, m)

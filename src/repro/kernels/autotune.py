@@ -26,11 +26,14 @@ OFF (hermetic runs) unless that variable is set explicitly.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import json
 import os
 import threading
 import time
 from typing import Callable
+
+import jax
 
 from repro.obs import metrics as _om
 from repro.obs.trace import span as _span
@@ -62,7 +65,6 @@ def env_tag() -> str:
     only ever replayed on the device kind and jax version that measured it."""
     global _ENV_TAG
     if _ENV_TAG is None:
-        import jax
         kind = jax.devices()[0].device_kind.replace(" ", "_").replace("|", "_")
         _ENV_TAG = f"{kind}|jax{jax.__version__}"
     return _ENV_TAG
@@ -90,14 +92,33 @@ def measurement_enabled() -> bool:
     return os.environ.get("REPRO_AUTOTUNE", "1") != "0"
 
 
+def repo_root() -> str:
+    """The checkout root: src/repro/kernels/autotune.py -> four levels up."""
+    return os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+
+
 def _cache_path() -> str:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return env
-    # repo root: src/repro/kernels/autotune.py -> three levels up from src/
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    return os.path.join(root, ".autotune_cache.json")
+    return os.path.join(repo_root(), ".autotune_cache.json")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone.  Otherwise the cache lives at ``<repo>/.jax_cache``: a fixed
+    path, because the directory is part of what a later process must find.
+    Entry points call this before their first jit; importing the library
+    never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(repo_root(), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _disk_enabled() -> bool:
@@ -172,14 +193,52 @@ def clear(in_memory_only: bool = True) -> None:
         _DISK_LOADED = in_memory_only  # True: don't re-read disk either
 
 
+class CandidateFailed(RuntimeError):
+    """A plan candidate raised while being measured.  Every candidate is a
+    plan the op may run, so a failure is a fault to fix (or a candidate to
+    prune), never a reason to quietly pick another plan."""
+
+
+def _time_candidates(key: str, candidates: dict[str, Callable[[], object]]
+                     ) -> dict[str, float]:
+    times: dict[str, float] = {}
+    for name, thunk in candidates.items():
+        try:
+            thunk()  # compile warmup
+            t = []
+            for _ in range(_REPS):
+                t0 = time.perf_counter()
+                thunk()
+                t.append(time.perf_counter() - t0)
+        except Exception as e:
+            raise CandidateFailed(
+                f"autotune candidate {name!r} for {key} raised "
+                f"{type(e).__name__}: {e}") from e
+        times[name] = min(t)
+    return times
+
+
+def _measure(key: str, candidates: dict[str, Callable[[], object]]
+             ) -> dict[str, float]:
+    """Best-of-``_REPS`` seconds per candidate after one compile warmup.
+
+    The timing runs on a fresh thread: JAX keeps its trace state per
+    thread, so the candidates execute for real even when the op asking for
+    a plan is being traced inside an outer ``jit`` — timing that trace
+    would rank candidates by tracing cost, not by what the device does."""
+    with concurrent.futures.ThreadPoolExecutor(1) as pool:
+        return pool.submit(_time_candidates, key, candidates).result()
+
+
 def best(key: str, candidates: dict[str, Callable[[], object]],
          default: str) -> str:
     """Winner for ``key``: cached if known, else measured once and persisted.
 
     ``candidates`` maps name -> thunk running that plan on bucket-shaped
     synthetic data (the thunk must block until the result is ready).  A thunk
-    that raises is disqualified.  With a single candidate, or measurement
-    disabled, no timing happens.
+    that raises aborts the call with ``CandidateFailed`` naming the key and
+    the candidate.  With a single candidate, or measurement disabled, no
+    timing happens.
 
     Keys are qualified with the device kind and jax version (``env_tag``)
     before lookup/storage, so a persisted plan can never be replayed on
@@ -197,24 +256,11 @@ def best(key: str, candidates: dict[str, Callable[[], object]],
         if len(candidates) == 1:
             return next(iter(candidates))
         _M_MISSES.inc()
-        times: dict[str, float] = {}
         with _span("autotune.measure", key=key, n_candidates=len(candidates)):
-            for name, thunk in candidates.items():
-                try:
-                    thunk()  # compile warmup
-                    t = []
-                    for _ in range(_REPS):
-                        t0 = time.perf_counter()
-                        thunk()
-                        t.append(time.perf_counter() - t0)
-                    times[name] = min(t) * 1e6
-                except Exception:
-                    continue
-        if not times:
-            return default
+            times = _measure(key, candidates)
         winner = min(times, key=times.get)
         _MEM[key] = {"winner": winner,
-                     "us": {k: round(v, 1) for k, v in times.items()}}
+                     "us": {k: round(v * 1e6, 1) for k, v in times.items()}}
         _save_disk()
         return winner
 
@@ -253,22 +299,9 @@ def best_roofline(key: str, candidates: dict[str, Callable[[], object]],
         if len(candidates) == 1:
             return next(iter(candidates))
         _M_MISSES.inc()
-        times: dict[str, float] = {}
         with _span("autotune.measure_roofline", key=key,
                    n_candidates=len(candidates)):
-            for name, thunk in candidates.items():
-                try:
-                    thunk()  # compile warmup
-                    t = []
-                    for _ in range(_REPS):
-                        t0 = time.perf_counter()
-                        thunk()
-                        t.append(time.perf_counter() - t0)
-                    times[name] = min(t)
-                except Exception:
-                    continue
-        if not times:
-            return default
+            times = _measure(key, candidates)
         peak_flops = max(costs[c][0] / t for c, t in times.items())
         peak_bytes = max(costs[c][1] / t for c, t in times.items())
         pred = {c: max(costs[c][0] / peak_flops, costs[c][1] / peak_bytes)

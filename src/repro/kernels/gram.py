@@ -11,6 +11,12 @@ With bn = bm = 256 and d <= 8192 that is 256*8192*4*2 + 256*256*4 ~= 17 MB --
 too big for v5e's 16 MB VMEM at the extreme, so ``ops.py`` picks the block
 size from d to stay under a VMEM budget (default 8 MB) and keeps the matmul
 dims multiples of the 128-lane MXU width.
+
+Weight vectors travel as 2-D blocks: the row weights as an (n, 1) column cut
+into (bn, 1) blocks, the column weights as a (1, m) row cut into (1, bm)
+blocks.  A 1-D block's tiling must match the layout XLA gives the whole
+vector, which the TPU compiler refuses for most tile sizes; the 2-D forms
+broadcast against the (bn, bm) tile with no relayout.
 """
 from __future__ import annotations
 
@@ -22,6 +28,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
+
+
+def cross(a: Array, b: Array) -> Array:
+    """a @ b.T with f32 accumulation.  f32 operands take the full-precision
+    MXU passes: the TPU's default f32 matmul rounds operands to bf16, and
+    ||a||^2 + ||b||^2 - 2 a.b amplifies that rounding into distances."""
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
+def contract(g: Array, v: Array) -> Array:
+    """g @ v with f32 accumulation, full precision for f32 operands."""
+    prec = jax.lax.Precision.HIGHEST if g.dtype == jnp.float32 else None
+    return jax.lax.dot_general(g, v, (((1,), (0,)), ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
+def sq_dists(x: Array, y: Array) -> Array:
+    """(bn, bm) partial ||x_i - y_j||^2 over the feature columns given
+    (unclamped, so K-chunks can be summed): f32 norms, MXU cross term."""
+    xf = x.astype(jnp.float32)
+    yf = y.astype(jnp.float32)
+    xx = jnp.sum(xf * xf, axis=-1, keepdims=True)        # (bn, 1)
+    yy = jnp.sum(yf * yf, axis=-1, keepdims=True).T      # (1, bm)
+    return xx + yy - 2.0 * cross(x, y)
+
+
+def kernel_of(d2: Array, sigma: float, p: int) -> Array:
+    """phi(||.||^p / sigma^p) = exp(-d^p / sigma^p) from clamped squared
+    distances, in f32."""
+    if p == 2:
+        s = d2 / (sigma * sigma)
+    elif p == 1:
+        s = jnp.sqrt(d2) / sigma
+    else:
+        s = d2 ** (p / 2.0) / sigma**p
+    return jnp.exp(-s)
 
 
 def _gram_kernel(x_ref, y_ref, wx_ref, wy_ref, o_ref, *, sigma: float, p: int,
@@ -38,17 +82,7 @@ def _gram_kernel(x_ref, y_ref, wx_ref, wy_ref, o_ref, *, sigma: float, p: int,
     k = pl.program_id(2)
     # mixed precision: bf16 inputs go to the MXU as-is (half the operand
     # bandwidth); norms, accumulation, and the nonlinearity stay f32
-    x = x_ref[...]                      # (bn, dk) f32 or bf16
-    y = y_ref[...]                      # (bm, dk)
-    xf = x.astype(jnp.float32)
-    yf = y.astype(jnp.float32)
-    xx = jnp.sum(xf * xf, axis=-1, keepdims=True)        # (bn, 1)
-    yy = jnp.sum(yf * yf, axis=-1, keepdims=True).T      # (1, bm)
-    cross = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                    # (bn, bm) on the MXU
-    partial = xx + yy - 2.0 * cross
+    partial = sq_dists(x_ref[...], y_ref[...])
 
     @pl.when(k == 0)
     def _init():
@@ -61,17 +95,10 @@ def _gram_kernel(x_ref, y_ref, wx_ref, wy_ref, o_ref, *, sigma: float, p: int,
 
     @pl.when(k == k_steps - 1)
     def _finish():
-        d2 = jnp.maximum(o_ref[...].astype(jnp.float32), 0.0)
-        if p == 2:
-            s = d2 / (sigma * sigma)
-        elif p == 1:
-            s = jnp.sqrt(d2) / sigma
-        else:
-            s = d2 ** (p / 2.0) / sigma**p
-        g = jnp.exp(-s)
+        g = kernel_of(jnp.maximum(o_ref[...].astype(jnp.float32), 0.0),
+                      sigma, p)
         if weighted:
-            g = g * jnp.sqrt(wx_ref[...].astype(jnp.float32))[:, None]
-            g = g * jnp.sqrt(wy_ref[...].astype(jnp.float32))[None, :]
+            g = g * jnp.sqrt(wx_ref[...]) * jnp.sqrt(wy_ref[...])
         o_ref[...] = g.astype(o_ref.dtype)
 
 
@@ -82,20 +109,12 @@ def _gram_row_kernel(x_ref, c_ref, w_ref, k_ref, d2_ref, *, sigma: float,
     the partial squared distance over feature chunk k.  On the LAST chunk it
     emits BOTH the (optionally weight-fused) kernel row — the new row/column
     of the weighted Gram — and the raw squared distances (the online
-    absorption decision of Algorithm 2 needs them in f32).
+    absorption decision of Algorithm 2 needs them in f32).  Rows are (1, bm)
+    blocks of (1, m) outputs.
     """
     k = pl.program_id(1)
-    x = x_ref[...]                      # (8, bk) f32 or bf16 (row 0 is real)
-    c = c_ref[...]                      # (bm, bk)
-    xf = x.astype(jnp.float32)
-    cf = c.astype(jnp.float32)
-    xx = jnp.sum(xf[0] * xf[0])                          # scalar
-    cc = jnp.sum(cf * cf, axis=-1)                       # (bm,)
-    cross = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[0]                                                 # (bm,) on the MXU
-    partial = xx + cc - 2.0 * cross
+    # x is padded to the 8-row sublane minimum (row 0 real, the rest zero)
+    partial = sq_dists(x_ref[...], c_ref[...])[0:1]      # (1, bm)
 
     @pl.when(k == 0)
     def _init():
@@ -109,15 +128,9 @@ def _gram_row_kernel(x_ref, c_ref, w_ref, k_ref, d2_ref, *, sigma: float,
     def _finish():
         d2 = jnp.maximum(d2_ref[...], 0.0)
         d2_ref[...] = d2
-        if p == 2:
-            s = d2 / (sigma * sigma)
-        elif p == 1:
-            s = jnp.sqrt(d2) / sigma
-        else:
-            s = d2 ** (p / 2.0) / sigma**p
-        g = jnp.exp(-s)
+        g = kernel_of(d2, sigma, p)
         if weighted:
-            g = g * jnp.sqrt(w_ref[...].astype(jnp.float32))
+            g = g * jnp.sqrt(w_ref[...])
         k_ref[...] = g.astype(k_ref.dtype)
 
 
@@ -125,12 +138,14 @@ def gram_row_pallas(x: Array, centers: Array, *, sigma: float, p: int = 2,
                     w: Array | None = None, block_m: int = 512,
                     block_k: int | None = None,
                     interpret: bool = False) -> tuple[Array, Array]:
-    """(k_row, d2_row) of one point against all centers in one fused pass.
+    """(k_row, d2_row), each (1, m), of one point against all centers in one
+    fused pass.
 
     x must be padded to (8, d) rows (row 0 real, the rest zero — the 8-row
     sublane minimum keeps the MXU happy); centers to (m % block_m == 0, d)
-    and d % block_k == 0 (ops.gram_row handles the padding).  ``w`` fuses the
-    sqrt(w_j) column weighting of Algorithm 1's W K W into the same pass.
+    and d % block_k == 0 (ops.gram_row handles the padding).  ``w`` is a
+    (1, m) row of center weights; it fuses the sqrt(w_j) column weighting of
+    Algorithm 1's W K W into the same pass.
     """
     m, d = centers.shape
     assert x.shape == (8, d), (x.shape, d)
@@ -140,26 +155,27 @@ def gram_row_pallas(x: Array, centers: Array, *, sigma: float, p: int = 2,
     k_steps = d // block_k
     weighted = w is not None
     if w is None:
-        w = jnp.ones((m,), jnp.float32)
+        w = jnp.ones((1, m), jnp.float32)
+    assert w.shape == (1, m), w.shape
 
     kernel = functools.partial(_gram_row_kernel, sigma=float(sigma),
                                p=int(p), weighted=weighted, k_steps=k_steps)
+    row = pl.BlockSpec((1, block_m), lambda j, k: (0, j))
     return pl.pallas_call(
         kernel,
         grid=(m // block_m, k_steps),
         in_specs=[
             pl.BlockSpec((8, block_k), lambda j, k: (0, k)),
             pl.BlockSpec((block_m, block_k), lambda j, k: (j, k)),
-            pl.BlockSpec((block_m,), lambda j, k: (j,)),
+            row,
         ],
-        out_specs=[
-            pl.BlockSpec((block_m,), lambda j, k: (j,)),
-            pl.BlockSpec((block_m,), lambda j, k: (j,)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.float32),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
+            jax.ShapeDtypeStruct((1, m), jnp.float32),
+            jax.ShapeDtypeStruct((1, m), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, centers, w)
 
@@ -178,17 +194,7 @@ def _gram_matvec_kernel(x_ref, y_ref, wx_ref, wy_ref, v_ref, o_ref, d2_ref, *,
     """
     j = pl.program_id(1)
     k = pl.program_id(2)
-    x = x_ref[...]                      # (bn, bk) f32 or bf16
-    y = y_ref[...]                      # (bm, bk)
-    xf = x.astype(jnp.float32)
-    yf = y.astype(jnp.float32)
-    xx = jnp.sum(xf * xf, axis=-1, keepdims=True)        # (bn, 1)
-    yy = jnp.sum(yf * yf, axis=-1, keepdims=True).T      # (1, bm)
-    cross = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                    # (bn, bm) on the MXU
-    partial = xx + yy - 2.0 * cross
+    partial = sq_dists(x_ref[...], y_ref[...])
 
     @pl.when(k == 0)
     def _init():
@@ -200,22 +206,11 @@ def _gram_matvec_kernel(x_ref, y_ref, wx_ref, wy_ref, v_ref, o_ref, d2_ref, *,
 
     @pl.when(k == k_steps - 1)
     def _contract():
-        d2 = jnp.maximum(d2_ref[...], 0.0)
-        if p == 2:
-            s = d2 / (sigma * sigma)
-        elif p == 1:
-            s = jnp.sqrt(d2) / sigma
-        else:
-            s = d2 ** (p / 2.0) / sigma**p
-        g = jnp.exp(-s)
+        g = kernel_of(jnp.maximum(d2_ref[...], 0.0), sigma, p)
         if weighted:
-            g = g * jnp.sqrt(wx_ref[...].astype(jnp.float32))[:, None]
-            g = g * jnp.sqrt(wy_ref[...].astype(jnp.float32))[None, :]
+            g = g * jnp.sqrt(wx_ref[...]) * jnp.sqrt(wy_ref[...])
         v = v_ref[...]                                   # (bm, r)
-        pv = jax.lax.dot_general(
-            g.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # (bn, r) on the MXU
+        pv = contract(g.astype(v.dtype), v)              # (bn, r) on the MXU
 
         @pl.when(j == 0)
         def _first():
@@ -225,6 +220,15 @@ def _gram_matvec_kernel(x_ref, y_ref, wx_ref, wy_ref, v_ref, o_ref, d2_ref, *,
         def _rest():
             o_ref[...] = (o_ref[...].astype(jnp.float32) + pv
                           ).astype(o_ref.dtype)
+
+
+def _weight_blocks(wx, wy, n: int, m: int):
+    """(n, 1) row-weight column and (1, m) column-weight row (ones when
+    unweighted)."""
+    wx = jnp.ones((n, 1), jnp.float32) if wx is None else wx
+    wy = jnp.ones((1, m), jnp.float32) if wy is None else wy
+    assert wx.shape == (n, 1) and wy.shape == (1, m), (wx.shape, wy.shape)
+    return wx, wy
 
 
 def gram_matvec_pallas(x: Array, y: Array, v: Array, *, sigma: float,
@@ -241,6 +245,7 @@ def gram_matvec_pallas(x: Array, y: Array, v: Array, *, sigma: float,
     equal to m with zero rows on any padded tail (``ops.gram_matvec``
     handles all padding; zero v-rows make unweighted padding exact, and
     zero-weight padding already kills padded columns on the weighted path).
+    ``wx`` is an (n, 1) column and ``wy`` a (1, m) row.
     """
     n, d = x.shape
     m, d2_ = y.shape
@@ -252,10 +257,7 @@ def gram_matvec_pallas(x: Array, y: Array, v: Array, *, sigma: float,
     k_steps = d // block_k
     r = v.shape[1]
     weighted = wx is not None
-    if wx is None:
-        wx = jnp.ones((n,), jnp.float32)
-    if wy is None:
-        wy = jnp.ones((m,), jnp.float32)
+    wx, wy = _weight_blocks(wx, wy, n, m)
 
     grid = (n // block_n, m // block_m, k_steps)
     kernel = functools.partial(_gram_matvec_kernel, sigma=float(sigma),
@@ -266,13 +268,15 @@ def gram_matvec_pallas(x: Array, y: Array, v: Array, *, sigma: float,
         in_specs=[
             pl.BlockSpec((block_n, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (j, k)),
-            pl.BlockSpec((block_n,), lambda i, j, k: (i,)),
-            pl.BlockSpec((block_m,), lambda i, j, k: (j,)),
+            pl.BlockSpec((block_n, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, block_m), lambda i, j, k: (0, j)),
             pl.BlockSpec((block_m, r), lambda i, j, k: (j, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, r), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, r), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_n, block_m), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, y, wx, wy, v)
 
@@ -285,7 +289,8 @@ def gram_pallas(x: Array, y: Array, *, sigma: float, p: int = 2,
     """K[i, j] = sqrt(wx_i) phi(||x_i-y_j||^p/sigma^p) sqrt(wy_j).
 
     Shapes must already be padded: n % block_n == 0, m % block_m == 0,
-    d % block_k == 0 (ops.gram handles padding/unpadding).
+    d % block_k == 0 (ops.gram handles padding/unpadding).  ``wx`` is an
+    (n, 1) column and ``wy`` a (1, m) row.
     """
     n, d = x.shape
     m, d2_ = y.shape
@@ -295,10 +300,7 @@ def gram_pallas(x: Array, y: Array, *, sigma: float, p: int = 2,
     assert d % block_k == 0, (d, block_k)
     k_steps = d // block_k
     weighted = wx is not None
-    if wx is None:
-        wx = jnp.ones((n,), jnp.float32)
-    if wy is None:
-        wy = jnp.ones((m,), jnp.float32)
+    wx, wy = _weight_blocks(wx, wy, n, m)
 
     grid = (n // block_n, m // block_m, k_steps)
     kernel = functools.partial(_gram_kernel, sigma=float(sigma), p=int(p),
@@ -309,10 +311,12 @@ def gram_pallas(x: Array, y: Array, *, sigma: float, p: int = 2,
         in_specs=[
             pl.BlockSpec((block_n, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (j, k)),
-            pl.BlockSpec((block_n,), lambda i, j, k: (i,)),
-            pl.BlockSpec((block_m,), lambda i, j, k: (j,)),
+            pl.BlockSpec((block_n, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, block_m), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_n, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, m), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, y, wx, wy)

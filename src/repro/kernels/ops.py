@@ -9,8 +9,8 @@ Responsibilities (DESIGN.md §3):
   * mixed precision: ``precision="bf16"`` feeds bf16 operands to the MXU
     matmuls while the distance accumulation and the exp nonlinearity stay
     f32;
-  * dispatch: real pallas on TPU, interpret=True elsewhere (this container is
-    CPU-only, so interpret mode is also what the tests exercise).
+  * dispatch: compiled Pallas on TPU, interpret=True elsewhere (the CPU
+    test suite exercises the kernel bodies in interpret mode).
 
 ``plan=`` forces a path explicitly ("pallas" | "pallas_fat" | "dense");
 tests use it to keep the kernel bodies exercised regardless of what the
@@ -23,6 +23,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import autotune
 
@@ -58,6 +59,26 @@ def _on_tpu() -> bool:
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+#: Center (or random-feature) tile of the kernels that sweep the operator
+#: along a grid axis: assignment, projection and the RFF transform.
+CENTER_TILE = 512
+
+
+def center_tile(m: int) -> int:
+    """Operator-axis tile for m centers: CENTER_TILE, shrunk to the
+    128-padded m so a small operator pads no further than a lane multiple."""
+    return min(CENTER_TILE, _round_up(max(m, 1), 128))
+
+
+#: TPU generations whose MXU has no fp8 path.
+_NO_FP8_MXU = ("TPU v2", "TPU v3", "TPU v4", "TPU v5", "TPU v6")
+
+
+def fp8_mxu() -> bool:
+    """Whether the default device contracts fp8 operands natively."""
+    return not jax.devices()[0].device_kind.startswith(_NO_FP8_MXU)
 
 
 def _pad_rows(a: Array, mult: int, value: float = 0.0) -> Array:
@@ -123,11 +144,8 @@ def _dense_sq_dists(x: Array, y: Array, precision: str) -> Array:
     cd = _compute_dtype(precision)
     xx = jnp.sum(x * x, axis=-1, keepdims=True)
     yy = jnp.sum(y * y, axis=-1, keepdims=True).T
-    cross = jax.lax.dot_general(
-        x.astype(cd), y.astype(cd), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return jnp.maximum(xx + yy - 2.0 * cross, 0.0)
+    return jnp.maximum(xx + yy - 2.0 * _gram.cross(x.astype(cd), y.astype(cd)),
+                       0.0)
 
 
 @functools.partial(jax.jit,
@@ -147,10 +165,7 @@ def _gram_matvec_dense(x, y, wx, wy, v, *, sigma, p, weighted, precision):
     g = _gram_dense(x, y, wx, wy, sigma=sigma, p=p, weighted=weighted,
                     precision=precision)
     cd = _compute_dtype(precision)
-    return jax.lax.dot_general(
-        g.astype(cd), v.astype(cd), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    return _gram.contract(g.astype(cd), v.astype(cd))
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -165,10 +180,7 @@ def _project_dense(x, c, a, *, sigma, p, precision):
     cd = _compute_dtype(precision)
     d2 = _dense_sq_dists(x, c, precision)
     g = jnp.exp(-_dist_pow(d2, p) / sigma**p)  # nonlinearity stays f32
-    return jax.lax.dot_general(
-        g.astype(cd), a.astype(cd), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    return _gram.contract(g.astype(cd), a.astype(cd))
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "p", "qmode"))
@@ -188,10 +200,7 @@ def _project_dense_quant(x, c, q, s, *, sigma, p, qmode):
             preferred_element_type=jnp.int32)
         return acc.astype(jnp.float32) * sg * sj
     gq = g.astype(_quantize.FP8_DTYPE)
-    acc = jax.lax.dot_general(
-        gq.astype(jnp.float32), q.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    return acc * sj
+    return _gram.contract(gq.astype(jnp.float32), q.astype(jnp.float32)) * sj
 
 
 # --------------------------------------------------------------------------
@@ -205,12 +214,13 @@ def _project_dense_quant(x, c, q, s, *, sigma, p, qmode):
 _MEASURE_MAX_ROWS = 8192
 
 
-def _bench_rows(n: int, d: int) -> Array:
+def _bench_rows(n: int, d: int) -> np.ndarray:
     # deterministic synthetic operands for plan measurement (values are
-    # irrelevant to timing; arange avoids a PRNG compile)
+    # irrelevant to timing).  Host arrays, so they stay concrete when the op
+    # asking for a plan is itself being traced.
     n = min(n, _MEASURE_MAX_ROWS)
-    return (jnp.arange(n * d, dtype=jnp.float32) % 977.0
-            ).reshape(n, d) / 977.0
+    return (np.arange(n * d, dtype=np.float32) % np.float32(977.0)
+            ).reshape(n, d) / np.float32(977.0)
 
 
 def _gram_plan(n: int, m: int, d: int, precision: str, interpret: bool):
@@ -349,15 +359,21 @@ def _project_plan(n: int, m: int, d: int, r: int, precision: str,
     key = f"project|n{nb}|m{mb}|d{db}|r{rb}|{precision}|{mode}"
     x, c = _bench_rows(nb, db), _bench_rows(mb, db)
     a = _bench_rows(c.shape[0], rb)
-    # pre-quantize the bench projector: the serving contract quantizes at
-    # snapshot publish, so per-call quantization must not pollute the timing
-    aq = (_quantize.quantize_projector(a, precision)
-          if precision in _quantize.QUANT_PRECISIONS else None)
+    # quantize the bench projector once, ahead of the timed calls: the
+    # serving contract quantizes at snapshot publish, so per-call
+    # quantization must not pollute the timing.  Lazily, inside the
+    # measurement, where arrays are concrete even if this op is traced.
+    aq = []
 
     def run(plan):
-        return lambda: jax.block_until_ready(kpca_project(
-            x, c, a, sigma=1.0, p=2, interpret=interpret,
-            precision=precision, plan=plan, projector_q=aq))
+        def call():
+            if precision in _quantize.QUANT_PRECISIONS and not aq:
+                aq.append(_quantize.quantize_projector(a, precision))
+            return jax.block_until_ready(kpca_project(
+                x, c, a, sigma=1.0, p=2, interpret=interpret,
+                precision=precision, plan=plan,
+                projector_q=aq[0] if aq else None))
+        return call
 
     neff, meff = x.shape[0], c.shape[0]
     tiles = _PROJECT_TILES_INTERPRET if interpret else _PROJECT_TILES_TPU
@@ -384,7 +400,8 @@ def _project_plan(n: int, m: int, d: int, r: int, precision: str,
 @functools.partial(jax.jit, static_argnames=("sigma", "p", "interpret",
                                              "bn", "bm", "bk"))
 def _gram_call(xp, yp, wxp, wyp, *, sigma, p, interpret, bn, bm, bk):
-    return _gram.gram_pallas(xp, yp, sigma=sigma, p=p, wx=wxp, wy=wyp,
+    return _gram.gram_pallas(xp, yp, sigma=sigma, p=p, wx=wxp.reshape(-1, 1),
+                             wy=wyp.reshape(1, -1),
                              block_n=bn, block_m=bm, block_k=bk,
                              interpret=interpret)
 
@@ -483,8 +500,10 @@ def matfree_fit(m: int) -> bool:
                                              "bn", "bm", "bk"))
 def _gram_matvec_call(xp, yp, wxp, wyp, vp, *, sigma, p, interpret, bn, bm,
                       bk):
-    return _gram.gram_matvec_pallas(xp, yp, vp, sigma=sigma, p=p, wx=wxp,
-                                    wy=wyp, block_n=bn, block_m=bm,
+    return _gram.gram_matvec_pallas(xp, yp, vp, sigma=sigma, p=p,
+                                    wx=wxp.reshape(-1, 1),
+                                    wy=wyp.reshape(1, -1), block_n=bn,
+                                    block_m=bm,
                                     block_k=bk, interpret=interpret)
 
 
@@ -584,9 +603,10 @@ def _gram_row_dense(x, c, w, *, sigma, p, weighted):
 @functools.partial(jax.jit, static_argnames=("sigma", "p", "interpret", "bm",
                                              "bk", "weighted"))
 def _gram_row_call(xp, cp, wp, *, sigma, p, interpret, bm, bk, weighted):
-    return _gram.gram_row_pallas(xp, cp, sigma=sigma, p=p,
-                                 w=wp if weighted else None,
-                                 block_m=bm, block_k=bk, interpret=interpret)
+    krow, d2 = _gram.gram_row_pallas(
+        xp, cp, sigma=sigma, p=p, w=wp.reshape(1, -1) if weighted else None,
+        block_m=bm, block_k=bk, interpret=interpret)
+    return krow[0], d2[0]
 
 
 def _gram_row_plan(m: int, d: int, interpret: bool) -> str:
@@ -654,8 +674,10 @@ def gram_row(x, centers, w=None, *, sigma: float, p: int = 2,
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
 def _assign_call(xp, cp, vp, *, bn, bm, interpret):
-    return _assign.shadow_assign_pallas(xp, cp, vp, block_n=bn, block_m=bm,
-                                        interpret=interpret)
+    idx, d2 = _assign.shadow_assign_pallas(xp, cp, vp.reshape(1, -1),
+                                           block_n=bn, block_m=bm,
+                                           interpret=interpret)
+    return idx[0], d2[0]
 
 
 def shadow_assign(x, centers, m_valid: int | None = None, *, valid=None,
@@ -688,7 +710,8 @@ def shadow_assign(x, centers, m_valid: int | None = None, *, valid=None,
     # off-TPU the grid loop itself is the overhead (no VMEM limit to respect),
     # so take far fewer, fatter row tiles: 8192 rows ~2.3x faster than 512 at
     # n=32k in interpret mode
-    block_n, block_m = (8192, 128) if interpret else (512, 128)
+    block_n = 8192 if interpret else 512
+    block_m = center_tile(m)
     # split the 128-padded row count into equal fat tiles rather than padding
     # up to a block_n multiple (that would waste up to block_n-1 rows of
     # distance work per call, ~2x for n just above a multiple)
@@ -697,7 +720,7 @@ def shadow_assign(x, centers, m_valid: int | None = None, *, valid=None,
     bn = min(block_n, _round_up(-(-npad // tiles), 128))
     xp = _pad_rows(x, bn)
     cp = _pad_rows(centers, block_m)
-    vp = _pad_rows(valid, block_m)
+    vp = _pad_rows(valid, block_m)  # zero: padded slots are invalid
     idx, d2 = _assign_call(xp, cp, vp, bn=bn, bm=block_m,
                            interpret=bool(interpret))
     return idx[:n], d2[:n]
@@ -715,7 +738,9 @@ def _project_call(xp, cp, ap, *, sigma, p, bn, interpret):
     # (kpca_project guarantees ownership before calling), so XLA reuses its
     # storage instead of holding chunk x d alive across the kernel
     return _project.kpca_project_pallas(xp, cp, ap, sigma=sigma, p=p,
-                                        block_n=bn, interpret=interpret)
+                                        block_n=bn,
+                                        block_m=center_tile(cp.shape[0]),
+                                        interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -725,7 +750,7 @@ def _project_call_quant(xp, cp, qp, sp, *, sigma, p, bn, qmode, interpret):
     # same donation contract as _project_call: xp is an owned padded chunk
     return _project.kpca_project_quant_pallas(
         xp, cp, qp, sp, sigma=sigma, p=p, qmode=qmode, block_n=bn,
-        interpret=interpret)
+        block_m=center_tile(cp.shape[0]), interpret=interpret)
 
 
 def projection_compile_count() -> int:
@@ -772,15 +797,20 @@ def kpca_project(x, centers, projector, *, sigma: float, p: int = 2,
         raise ValueError(
             f"projector_q only applies to {_quantize.QUANT_PRECISIONS}, "
             f"got precision={precision!r}")
+    if precision == "fp8" and not interpret and not fp8_mxu():
+        raise ValueError(
+            f"precision='fp8' needs an fp8 MXU; "
+            f"{jax.devices()[0].device_kind!r} has none (serve int8 or bf16 "
+            "on this chip)")
     if plan is None:
         plan = _project_plan(min(n, chunk or n), m, d, r, precision,
                              interpret)
     # the quantized tier keeps distance operands f32 (only the projector
     # contraction drops precision); f32/bf16 tiers cast as before
     cd = jnp.float32 if quant else _compute_dtype(precision)
-    # pad m to a lane multiple; padded projector rows are zero so padded
+    # pad m to the center tile; padded projector rows are zero so padded
     # centers cannot contribute
-    cp = _pad_rows(centers, 128).astype(cd)
+    cp = _pad_rows(centers, center_tile(m)).astype(cd)
     rp = _round_up(r, 128)
     if quant:
         if projector_q is None:
@@ -792,7 +822,7 @@ def kpca_project(x, centers, projector, *, sigma: float, p: int = 2,
         sp = jnp.pad(jnp.asarray(qs, jnp.float32), (0, rp - r),
                      constant_values=1.0).reshape(1, rp)
     else:
-        ap = _pad_rows(projector, 128)
+        ap = _pad_rows(projector, cp.shape[0])
         ap = jnp.pad(ap, ((0, 0), (0, rp - r)))
     tile = int(plan.split(":", 1)[1]) if plan.startswith("pallas:") else 512
 
@@ -849,11 +879,8 @@ def rff_features(x, omega, phase, *, scale, precision="f32"):
     matmul on bf16 operands with f32 accumulation; the cosine stays f32.
     """
     cd = _compute_dtype(precision)
-    s = jax.lax.dot_general(
-        jnp.asarray(x, jnp.float32).astype(cd),
-        jnp.asarray(omega, jnp.float32).astype(cd),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+    s = _gram.cross(jnp.asarray(x, jnp.float32).astype(cd),
+                    jnp.asarray(omega, jnp.float32).astype(cd))
     return jnp.cos(s + jnp.asarray(phase, jnp.float32)[None, :]) * scale
 
 
@@ -861,10 +888,7 @@ def rff_features(x, omega, phase, *, scale, precision="f32"):
 def _rff_dense(x, omega, phase, u, *, scale, precision):
     z = rff_features(x, omega, phase, scale=scale, precision=precision)
     cd = _compute_dtype(precision)
-    return jax.lax.dot_general(
-        z.astype(cd), jnp.asarray(u, jnp.float32).astype(cd),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+    return _gram.contract(z.astype(cd), jnp.asarray(u, jnp.float32).astype(cd))
 
 
 _RFF_TILES_TPU = (256, 512, 1024)
@@ -928,6 +952,7 @@ def _rff_call(xp, wp, bp, up, *, scale, bn, interpret):
     # xp (the padded query chunk) is donated under the same ownership
     # contract as _project_call
     return _rff.rff_project_pallas(xp, wp, bp, up, scale=scale, block_n=bn,
+                                   block_f=center_tile(wp.shape[0]),
                                    interpret=interpret)
 
 
@@ -958,11 +983,12 @@ def rff_project(x, omega, phase, u, *, scale: float | None = None,
         plan = _rff_plan(min(n, chunk or n), nfeat, d, r, precision,
                          interpret)
     cd = _compute_dtype(precision)
-    fpad = _round_up(nfeat, 128) - nfeat
-    wp = _pad_rows(omega, 128).astype(cd)
+    ft = center_tile(nfeat)
+    fpad = _round_up(nfeat, ft) - nfeat
+    wp = _pad_rows(omega, ft).astype(cd)
     bp = jnp.pad(phase_j, (0, fpad)).reshape(1, -1)
     rp = _round_up(r, 128)
-    up = _pad_rows(u, 128)
+    up = _pad_rows(u, ft)
     up = jnp.pad(up, ((0, 0), (0, rp - r)))
     tile = int(plan.split(":", 1)[1]) if plan.startswith("pallas:") else 512
 

@@ -10,7 +10,14 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the sharded paths here
+    place arrays with ``NamedSharding`` and let the compiler propagate, and
+    ``jax.make_mesh``'s own default is ``Explicit``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.models import api
 from repro.models.config import ArchConfig
 from repro.models.sharding_hooks import use_sharder
@@ -330,7 +329,7 @@ def make_train_step_opt(cfg: ArchConfig, mesh, *, accum: int = 1,
                 None, dp_axes, *([None] * (x.ndim - 2)))
         batch_manual = jax.tree.map(bspec, mb)
 
-        grads, loss_sum = compat.shard_map(
+        grads, loss_sum = jax.shard_map(
             local, mesh=mesh,
             in_specs=(manual_p_specs, batch_manual),
             out_specs=(grad_out_specs(mb), jax.sharding.PartitionSpec()),

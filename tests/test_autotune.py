@@ -56,7 +56,7 @@ def test_best_persists_to_disk_and_reloads(fresh_cache):
     assert disk["schema"] == autotune._SCHEMA
     assert disk["plans"][autotune.qualified("k2")]["winner"] == "fast"
     # a fresh process (cleared memory) must reload the winner WITHOUT
-    # measuring: candidates that raise would disqualify themselves
+    # measuring: a candidate that ran would raise
     autotune.clear(in_memory_only=False)
 
     def boom():
@@ -102,12 +102,42 @@ def test_single_candidate_skips_measurement(fresh_cache):
     assert not calls
 
 
-def test_failing_candidate_disqualified(fresh_cache):
+@pytest.mark.parametrize("roofline", [False, True])
+def test_failing_candidate_raises(fresh_cache, roofline):
+    """A plan the op may run that raises is a fault: it surfaces with the
+    key and the candidate's name, and the other candidate never wins by
+    default."""
     def boom():
         raise RuntimeError("no backend")
 
-    assert autotune.best("k4", {"bad": boom, "ok": lambda: None},
-                         default="bad") == "ok"
+    cands = {"ok": lambda: None, "bad": boom}
+    with pytest.raises(autotune.CandidateFailed, match="'bad'.*k4"):
+        if roofline:
+            autotune.best_roofline("k4", cands,
+                                   {"ok": (1.0, 1.0), "bad": (1.0, 1.0)},
+                                   default="ok")
+        else:
+            autotune.best("k4", cands, default="ok")
+    assert autotune.qualified("k4") not in autotune._MEM
+
+
+def test_measurement_runs_eagerly_inside_jit(fresh_cache):
+    """A plan asked for while an outer jit traces the op is measured on
+    concrete arrays: the candidates really run, so the ranking is the
+    device's, not the tracer's."""
+    import jax
+    seen = []
+
+    def cand():
+        seen.append(isinstance(jax.numpy.ones(3) + 1, jax.core.Tracer))
+
+    @jax.jit
+    def outer(x):
+        autotune.best("k_jit", {"a": cand, "b": cand}, default="a")
+        return x + 1
+
+    outer(np.ones(2, np.float32))
+    assert seen and not any(seen)
 
 
 def test_measurement_disabled_uses_heuristic(fresh_cache, monkeypatch):
@@ -191,3 +221,25 @@ def test_assign_plan_tag_namespaces_key(fresh_cache, monkeypatch):
             if k.startswith("assign|n256|m128|d8|interp")]
     assert len(keys) == 2
     assert sum("|ingest|" in k for k in keys) == 1
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compilation_cache_location(monkeypatch, tmp_path, env_set):
+    """JAX's own variable wins untouched; otherwise a fixed directory at
+    the checkout root (never a temporary or per-process path)."""
+    import jax
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert autotune.enable_compilation_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(autotune.repo_root(), ".jax_cache")
+            assert autotune.enable_compilation_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.isfile(os.path.join(autotune.repo_root(),
+                                               "chip_smoke.py"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -5,10 +5,7 @@ and ell — this is the strongest validation of the reproduction's math.
 """
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import gaussian, laplacian, shadow_select_host
 from repro.core import mmd as M

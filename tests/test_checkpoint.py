@@ -67,7 +67,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=@N@"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_checkpoint, restore_checkpoint
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((@N@,), ("data",))
 sh = NamedSharding(mesh, P("data", None))
 w = jax.device_put(jnp.arange(64.0).reshape(8, 8), sh)

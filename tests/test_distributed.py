@@ -37,7 +37,7 @@ from repro.core import mmd as M
 from repro.data import make_dataset
 x, y, sigma = make_dataset("pendigits", seed=1, n=1024)
 ker = gaussian(sigma)
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("data",))
 r1 = shadow_rsde(x, ker, 4.0)
 r2 = distributed_shadow_rsde(x, ker, 4.0, mesh)
@@ -61,7 +61,7 @@ def test_chunked_ingest_select_8dev():
     covering the uneven-last-shard and empty-local-shard regressions."""
     _run_multidevice("""
 import numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.ingest_pipeline import pad_block, select_streaming
 
 mesh = make_mesh((8,), ("data",))
@@ -100,7 +100,7 @@ from repro.models import api
 from repro.launch import steps, sharding as shd
 from jax.sharding import NamedSharding, PartitionSpec as P
 cfg = get_config("mixtral_8x7b", smoke=True)
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 2), ("data", "model"))
 shape = api.ShapeSpec("t", 32, 4, "train")
 params_spec = api.param_specs(cfg)
@@ -134,7 +134,7 @@ from repro.configs import get_config
 from repro.models import api
 from repro.launch import steps, sharding as shd
 cfg = get_config("gemma2_9b", smoke=True)
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 2), ("data", "model"))
 shape = api.ShapeSpec("d", 32, 4, "decode")
 lowered, _ = steps.lower_decode(cfg, shape, mesh)

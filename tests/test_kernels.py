@@ -9,6 +9,9 @@ from repro.kernels import ops, ref
 
 SHAPES = [(64, 16, 8), (100, 37, 24), (513, 129, 16), (256, 256, 256),
           (1000, 7, 96)]
+#: ragged m spanning several center tiles of the kernels that sweep the
+#: operator along a grid axis (ops.CENTER_TILE = 512)
+MULTI_TILE_SHAPES = [(300, 1100, 16), (129, 1537, 40)]
 
 
 @pytest.mark.parametrize("n,m,d", SHAPES)
@@ -73,7 +76,7 @@ def test_weighted_gram_is_algorithm1_ktilde():
     np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.parametrize("n,m,d", SHAPES)
+@pytest.mark.parametrize("n,m,d", SHAPES + MULTI_TILE_SHAPES)
 def test_shadow_assign_sweep(n, m, d):
     rng = np.random.default_rng(hash((n, m)) % 2**32)
     x = rng.normal(size=(n, d)).astype(np.float32)
@@ -95,7 +98,7 @@ def test_shadow_assign_padding_mask():
     assert (np.asarray(idx) < 5).all()
 
 
-@pytest.mark.parametrize("n,m,d", SHAPES)
+@pytest.mark.parametrize("n,m,d", SHAPES + MULTI_TILE_SHAPES)
 @pytest.mark.parametrize("r", [1, 5, 8])
 def test_kpca_project_sweep(n, m, d, r):
     rng = np.random.default_rng(hash((n, m, r)) % 2**32)

@@ -6,10 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 
@@ -218,7 +215,7 @@ def test_sharded_matfree_matches_single_device():
     """Row-tile-distributed matvec LOBPCG == single-device matfree fit
     (1-device mesh in-process; the 8-device variant runs in
     tests/test_sharded.py's subprocess harness)."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core import gaussian
     from repro.core.distributed import fit_rskpca_sharded
     from repro.core.rskpca import _fit_rskpca_device

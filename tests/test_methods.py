@@ -10,10 +10,7 @@ import os
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as core
 from repro.core import gaussian, laplacian

@@ -20,17 +20,13 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 @pytest.mark.parametrize("arch", ["mixtral_8x7b", "kimi_k2"])
 def test_optimized_step_matches_baseline(arch):
     """Deferred-grad shard_map + 2D experts == baseline loss (bf16 noise)."""
-    from repro.compat import HAS_PARTIAL_AUTO_SHARD_MAP
-    if not HAS_PARTIAL_AUTO_SHARD_MAP:
-        pytest.skip("partially-manual shard_map needs native jax.shard_map "
-                    "(this jax hits XLA CHECK IsManualSubgroup)")
     code = f"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.models import api
 from repro.launch import steps, sharding as shd
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config({arch!r}, smoke=True)
 shape = api.ShapeSpec("t", 32, 8, "train")

@@ -20,10 +20,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, quantize, ref
 
@@ -46,7 +43,7 @@ def _oracle(x, c, a):
                                            jnp.asarray(a), SIGMA, 2))
 
 
-@pytest.mark.parametrize("n,m,d,r", SHAPES)
+@pytest.mark.parametrize("n,m,d,r", SHAPES + [(300, 1100, 16, 8)])
 def test_int8_pallas_dense_bitwise(n, m, d, r):
     """int8 rounds the Gram with one shared expression and accumulates in
     int32, so the pallas kernel and the dense oracle are integer-exact:
@@ -183,3 +180,14 @@ def test_swap_publish_caches_quantized_projector():
     bound = np.asarray(quantize.projection_error_bound(
         np.asarray(s8._snapshot[1]), "int8"))
     assert np.all(np.abs(z8 - z32).max(axis=0) <= bound)
+
+
+def test_fp8_refused_without_fp8_mxu(monkeypatch):
+    """A chip without an fp8 MXU would only emulate the tier: the compiled
+    path refuses it instead (v5e is such a chip)."""
+    assert "TPU v5 lite".startswith(ops._NO_FP8_MXU)
+    monkeypatch.setattr(ops, "fp8_mxu", lambda: False)
+    x, c, a = _problem(8, 16, 4, 3)
+    with pytest.raises(ValueError, match="fp8 MXU"):
+        ops.kpca_project(x, c, a, sigma=SIGMA, precision="fp8",
+                         interpret=False)

@@ -2,10 +2,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis wheel
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (shadow_select_np, shadow_select_host,
                         shadow_select_blocked, shadow_select_streaming,
