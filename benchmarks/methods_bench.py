@@ -63,7 +63,7 @@ def _dense_nystrom_fit(x, ker, rank: int, m: int, seed: int = 0):
     dker = ker.with_backend("dense")
     k_nm = gram_matrix(dker, xj, landmarks)           # (n, m) materialized
     k_mm = gram_matrix(dker, landmarks, landmarks)    # (m, m) materialized
-    lam_m, u_m = _top_eigh(k_mm / m, rank)
+    lam_m, u_m, _ = _top_eigh(k_mm / m, rank)
     lam_m = jnp.maximum(lam_m, 1e-12)
     v = jnp.sqrt(m / n) * (k_nm / m) @ (u_m / lam_m[None, :])
     proj = v / jnp.sqrt(lam_m)[None, :] / np.sqrt(n)

@@ -413,9 +413,9 @@ def bench_matfree(m: int = 8192, d: int = 16, rank: int = 8):
 
     # --- matrix-free fit: warmup (compile + autotune), then min-of-2
     def run_mf():
-        lam, proj = _fit_rskpca_device(jnp.asarray(c), jnp.asarray(w),
-                                       jnp.float32(n), ker, rank,
-                                       matfree=True)
+        lam, proj, _ = _fit_rskpca_device(jnp.asarray(c), jnp.asarray(w),
+                                          jnp.float32(n), ker, rank,
+                                          matfree=True)
         jax.block_until_ready(proj)
         return lam, proj
 

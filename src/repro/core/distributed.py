@@ -303,7 +303,7 @@ def _fit_rskpca_sharded(c: Array, w: Array, n: Array, kernel: Kernel,
             )(c, w, c, w, v)
             return out / n
 
-        lam, u = _lobpcg_topk(matvec, m_pad, rank)
+        lam, u, _ = _lobpcg_topk(matvec, m_pad, rank)
     else:
         kt = sharded_weighted_gram(c, w, kernel, mesh, axis=axis) / n
         if m_pad > lobpcg_min_m and 5 * rank < m_pad:
@@ -316,7 +316,7 @@ def _fit_rskpca_sharded(c: Array, w: Array, n: Array, kernel: Kernel,
                     out_specs=P(axis, None), check_vma=False,
                 )(kt, v)
 
-            lam, u = _lobpcg_topk(matvec, m_pad, rank)
+            lam, u, _ = _lobpcg_topk(matvec, m_pad, rank)
         else:
             lam, u = jnp.linalg.eigh(kt)  # ascending
             lam = lam[::-1][:rank]

@@ -78,7 +78,7 @@ def _landmark_eigs_matfree(lmk, kernel: Kernel, rank: int):
             lmk, lmk, v, sigma=kernel.sigma, p=kernel.p,
             precision=kernel.precision, allow_dense=False) / mm
 
-    return _lobpcg_topk(matvec, mm, rank)
+    return _lobpcg_topk(matvec, mm, rank)[:2]
 
 
 def _landmark_eigs(landmarks: np.ndarray, kernel: Kernel, rank: int,
@@ -98,7 +98,8 @@ def _landmark_eigs(landmarks: np.ndarray, kernel: Kernel, rank: int,
         top = _host_subset_eigh(kt, rank)
         if top is not None:
             return top
-    lam, u = _top_eigh(gram_matrix(kernel, landmarks, landmarks) / mm, rank)
+    lam, u, _ = _top_eigh(gram_matrix(kernel, landmarks, landmarks) / mm,
+                          rank)
     return np.asarray(lam), np.asarray(u)
 
 

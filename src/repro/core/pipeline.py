@@ -37,6 +37,8 @@ from repro.core.kernels_math import Kernel
 from repro.core import shadow as shadow_mod
 from repro.core.shadow import _pow2_ceil
 from repro.core.rskpca import KPCAModel, _fit_rskpca_device, _use_matfree
+from repro.obs import trace as _trace
+from repro.obs.trace import span as _span
 
 
 def fit_centers(centers, weights, n: int, kernel: Kernel, rank: int, *,
@@ -54,30 +56,41 @@ def fit_centers(centers, weights, n: int, kernel: Kernel, rank: int, *,
     rows — so re-jit count stays logarithmic across m.  The cap slices are
     donated into the jitted device fit; ``matfree=None`` consults the
     bytes-budget crossover (above it no m x m buffer ever materializes).
+
+    Spans (DESIGN.md §16): ``fit.stage`` is the transfer, the padding and
+    the centers' fetch; ``fit.solve`` the device fit through the fetch of
+    its results, with LOBPCG's iteration count (0 on ``eigh``).
     """
-    c = jnp.asarray(centers, jnp.float32)
-    w = jnp.asarray(weights, jnp.float32)
-    m = c.shape[0] if m is None else int(m)
-    rank = min(rank, m)
-    cap = min(max(c.shape[0], 128), _pow2_ceil(max(m, 128)))
-    # materialize the model's center rows BEFORE the fit: the cap slices are
-    # donated into it, and when cap == c.shape[0] jax's full-slice fast path
-    # returns `c` ITSELF — reading it after donation would hit a deleted array
-    centers_host = np.asarray(c[:m], np.float32)
-    if c.shape[0] < cap:  # host center sets arrive exactly (m, d): pad
-        c = jnp.concatenate(
-            [c, jnp.zeros((cap - c.shape[0], c.shape[1]), jnp.float32)])
-        w = jnp.concatenate([w, jnp.zeros((cap - w.shape[0],), jnp.float32)])
+    with _span("fit.stage"):
+        c = jnp.asarray(centers, jnp.float32)
+        w = jnp.asarray(weights, jnp.float32)
+        m = c.shape[0] if m is None else int(m)
+        rank = min(rank, m)
+        cap = min(max(c.shape[0], 128), _pow2_ceil(max(m, 128)))
+        # materialize the model's center rows BEFORE the fit: the cap slices
+        # are donated into it, and when cap == c.shape[0] jax's full-slice
+        # fast path returns `c` ITSELF — reading it after donation would hit
+        # a deleted array
+        centers_host = np.asarray(c[:m], np.float32)
+        if c.shape[0] < cap:  # host center sets arrive exactly (m, d): pad
+            c = jnp.concatenate(
+                [c, jnp.zeros((cap - c.shape[0], c.shape[1]), jnp.float32)])
+            w = jnp.concatenate(
+                [w, jnp.zeros((cap - w.shape[0],), jnp.float32)])
     use_mf = _use_matfree(kernel, cap, rank, matfree)
-    lam, proj = _fit_rskpca_device(c[:cap], w[:cap], jnp.float32(n), kernel,
-                                   rank, matfree=use_mf)
-    return KPCAModel(
-        kernel=kernel,
-        centers=centers_host,
-        projector=np.asarray(proj[:m]),
-        eigvals=np.asarray(lam),
-        method=method,
-    )
+    with _span("fit.solve", m=m, cap=cap, matfree=use_mf) as sp:
+        lam, proj, iters = sp.sync(_fit_rskpca_device(
+            c[:cap], w[:cap], jnp.float32(n), kernel, rank, matfree=use_mf))
+        if _trace.enabled():  # the count is fetched only while tracing
+            sp.set(lobpcg_iters=int(iters))
+        model = KPCAModel(
+            kernel=kernel,
+            centers=centers_host,
+            projector=np.asarray(proj[:m]),
+            eigvals=np.asarray(lam),
+            method=method,
+        )
+    return model
 
 
 def fit_shadow_fused(x, kernel: Kernel, rank: int, *, ell: float,
@@ -95,8 +108,8 @@ def fit_shadow_fused(x, kernel: Kernel, rank: int, *, ell: float,
     n, d = xf.shape
     eps2 = jnp.float32(kernel.epsilon(ell)) ** 2
     b = max(1, min(256 if block is None else block, n))
-    _, centers, weights, _, m_dev = shadow_mod._blocked_select_device(
+    _, centers, weights, _, mr = shadow_mod._blocked_select_device(
         xf, eps2, b, jnp.ones((n,), bool), jnp.asarray(0, jnp.int32))
-    m = int(m_dev)  # the pipeline's single host sync: one scalar
+    m = int(np.asarray(mr)[0])  # the pipeline's single host sync: m
     return fit_centers(centers, weights, n, kernel, rank, m=m,
                        matfree=matfree, method="rskpca+shadow-fused")

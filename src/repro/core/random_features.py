@@ -145,7 +145,7 @@ def _solve_cov(cov: np.ndarray, rank: int):
         if top is not None:
             lam, u = top
             return np.maximum(lam, 1e-12), u
-    lam, u = _top_eigh(jnp.asarray(cov), rank)
+    lam, u, _ = _top_eigh(jnp.asarray(cov), rank)
     return np.maximum(np.asarray(lam), 1e-12), np.asarray(u)
 
 

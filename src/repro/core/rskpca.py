@@ -134,23 +134,24 @@ def _lobpcg_topk(operator, m: int, rank: int):
     start and sign convention.  Every large-m eigensolve path (materialized,
     matrix-free, sharded, streaming) shares THIS definition, so iteration
     budget / seed / canonicalization can never drift between the paths the
-    parity tests compare."""
+    parity tests compare.  Returns ``(lam, vec, iterations)``."""
     from jax.experimental.sparse.linalg import lobpcg_standard
 
     x0 = jax.random.normal(jax.random.PRNGKey(0), (m, rank), jnp.float32)
-    lam, vec, _ = lobpcg_standard(operator, x0, m=100)
-    return lam, _canonicalize_signs(vec)
+    lam, vec, iters = lobpcg_standard(operator, x0, m=100)
+    return lam, _canonicalize_signs(vec), iters
 
 
 def _top_eigh(mat: Array, rank: int):
-    """Top-``rank`` eigenpairs of a symmetric PSD matrix, descending."""
+    """Top-``rank`` eigenpairs of a symmetric PSD matrix, descending, and
+    LOBPCG's iteration count (0 where the exact ``eigh`` solves it)."""
     m = mat.shape[0]
     if m > _LOBPCG_MIN_M and 5 * rank < m:
         return _lobpcg_topk(mat, m, rank)
     lam, vec = jnp.linalg.eigh(mat)  # ascending
     lam = lam[::-1][:rank]
     vec = vec[:, ::-1][:, :rank]
-    return lam, _canonicalize_signs(vec)
+    return lam, _canonicalize_signs(vec), jnp.zeros((), jnp.int32)
 
 
 def _host_subset_eigh(kt: np.ndarray, rank: int):
@@ -200,6 +201,9 @@ def _fit_rskpca_device(c: Array, w: Array, n: Array, kernel: Kernel,
     freshly created device arrays (fit_rskpca converts from numpy; the fused
     pipeline slices fresh buffers out of the selection output), and XLA
     reuses their storage instead of copying.
+
+    Returns ``(lam, proj, iterations)``: LOBPCG's iteration count, 0 where
+    the exact ``eigh`` solved it.
     """
     sw = jnp.sqrt(w)
     if matfree:
@@ -208,16 +212,16 @@ def _fit_rskpca_device(c: Array, w: Array, n: Array, kernel: Kernel,
                 c, c, v, wx=w, wy=w, sigma=kernel.sigma, p=kernel.p,
                 precision=kernel.precision, allow_dense=False) / n
 
-        lam, u = _lobpcg_topk(matvec, c.shape[0], rank)
+        lam, u, iters = _lobpcg_topk(matvec, c.shape[0], rank)
     else:
         k_tilde = weighted_gram(kernel, c, w) / n  # normalized (divide by n)
-        lam, u = _top_eigh(k_tilde, rank)
+        lam, u, iters = _top_eigh(k_tilde, rank)
     lam = jnp.maximum(lam, 1e-12)
     # A = diag(sqrt(w)) U Lambda^{-1/2} / sqrt(n): z(x) = k(x,C) A has the same
     # scale as classical KPCA's z(x) = k(x,X) V Lambda_mat^{-1/2} (checked in
     # tests/test_rskpca.py::test_limit_equals_kpca).
     proj = (sw[:, None] * u) / jnp.sqrt(lam)[None, :] / jnp.sqrt(n)
-    return lam, proj
+    return lam, proj, iters
 
 
 def _use_matfree(kernel: Kernel, m: int, rank: int,
@@ -271,8 +275,8 @@ def fit_rskpca(rsde: RSDE, kernel: Kernel, rank: int,
         lam, proj = dist.fit_rskpca_sharded(c, w, rsde.n, kernel, rank,
                                             mesh, axis=axis, matfree=matfree)
     elif use_mf:
-        lam, proj = _fit_rskpca_device(c, w, jnp.float32(rsde.n), kernel,
-                                       rank, matfree=True)
+        lam, proj, _ = _fit_rskpca_device(c, w, jnp.float32(rsde.n), kernel,
+                                          rank, matfree=True)
     elif (jax.default_backend() == "cpu" and c.shape[0] <= _LOBPCG_MIN_M):
         # CPU dispatch: fused Gram on device, then the LAPACK subset
         # eigensolve on host — 2x the end-to-end fit at m ~ 500 vs keeping
@@ -280,13 +284,13 @@ def fit_rskpca(rsde: RSDE, kernel: Kernel, rank: int,
         kt = np.asarray(weighted_gram(kernel, c, w)) / np.float32(rsde.n)
         top = _host_subset_eigh(kt, rank)
         if top is None:
-            lam, proj = _fit_rskpca_device(c, w, jnp.float32(rsde.n),
-                                           kernel, rank)
+            lam, proj, _ = _fit_rskpca_device(c, w, jnp.float32(rsde.n),
+                                              kernel, rank)
         else:
             lam, proj = _fold_projector(*top, np.asarray(w), rsde.n)
     else:
-        lam, proj = _fit_rskpca_device(c, w, jnp.float32(rsde.n), kernel,
-                                       rank)
+        lam, proj, _ = _fit_rskpca_device(c, w, jnp.float32(rsde.n), kernel,
+                                          rank)
     return KPCAModel(
         kernel=kernel,
         centers=centers_np,
@@ -305,7 +309,7 @@ def fit_kpca(x, kernel: Kernel, rank: int) -> KPCAModel:
     x = jnp.asarray(x, jnp.float32)
     n = x.shape[0]
     k = gram_matrix(kernel, x, x) / n
-    lam, v = _top_eigh(k, rank)
+    lam, v, _ = _top_eigh(k, rank)
     lam = jnp.maximum(lam, 1e-12)
     proj = v / jnp.sqrt(lam)[None, :] / np.sqrt(n)
     return KPCAModel(

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kernel_ops
+from repro.obs.trace import span as _span
 
 Array = jax.Array
 
@@ -157,6 +158,10 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
     Every alive candidate leaves the alive set each round (kept ones absorb
     themselves; dropped ones are within eps of the keeper that shadowed
     them), so the round count is <= ceil(m/1) and typically ~m/B.
+
+    Returns ``(alive, centers, weights, assign, mr)``: ``mr`` is the int32
+    pair (centers selected, rounds run), one small array so the host reads
+    both with one scalar fetch.
     """
     n, d = xf.shape
     iota = jnp.arange(n)
@@ -200,7 +205,7 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
         return alive.any() & (alive.sum(dtype=jnp.int32) > stop_count)
 
     def body(state):
-        alive, centers, weights, assign, m = state
+        alive, centers, weights, assign, m, rounds = state
         cand, keep, counts, idx, absorbed, kept_rank = round_core(alive)
         pos = jnp.where(keep, m + kept_rank, n)  # n = out-of-bounds: dropped
         centers = centers.at[pos].set(cand, mode="drop")
@@ -209,7 +214,7 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
                            (m + kept_rank[idx]).astype(jnp.int32), assign)
         alive = alive & ~absorbed
         return alive, centers, weights, assign, \
-            m + keep.sum(dtype=jnp.int32)
+            m + keep.sum(dtype=jnp.int32), rounds + 1
 
     state = (
         alive0,
@@ -217,9 +222,11 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
         jnp.zeros((n,), jnp.float32),
         jnp.full((n,), -1, jnp.int32),
         jnp.asarray(0, jnp.int32),
+        jnp.asarray(0, jnp.int32),
     )
-    alive, centers, weights, assign, m = jax.lax.while_loop(cond, body, state)
-    return alive, centers, weights, assign, m
+    alive, centers, weights, assign, m, rounds = jax.lax.while_loop(
+        cond, body, state)
+    return alive, centers, weights, assign, jnp.stack([m, rounds])
 
 
 def _pow2_ceil(v: int) -> int:
@@ -279,29 +286,40 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
     assign = np.full((n,), -1, np.int64)
     centers_out, weights_out = [], []
     m = 0
-    w_np = None if weights is None else np.asarray(weights, np.float32)
-    # padded working set: rows, padded-row -> original-row map, alive mask
-    cur_x, cur_orig, cur_alive, cur_w = _pad_pow2(x_np, np.arange(n), w_np)
-    while cur_alive.any():
-        b = max(1, min(block, cur_x.shape[0]))
-        n_alive = int(cur_alive.sum())
-        alive, c, w, a, mm = _blocked_select_device(
-            jnp.asarray(cur_x), eps2, b, jnp.asarray(cur_alive),
-            jnp.asarray(n_alive // 2, jnp.int32),
-            None if cur_w is None else jnp.asarray(cur_w))
-        mm = int(mm)
-        a = np.asarray(a)
-        absorbed = a >= 0
-        assign[cur_orig[absorbed]] = m + a[absorbed]
-        centers_out.append(np.asarray(c[:mm]))
-        weights_out.append(np.asarray(w[:mm]))
-        m += mm
-        still = np.flatnonzero(np.asarray(alive))
-        if still.size == 0:
-            break
-        cur_x, cur_orig, cur_alive, cur_w = _pad_pow2(
-            cur_x[still], cur_orig[still],
-            None if cur_w is None else cur_w[still])
+    # each halving phase's working set: its rows, row -> original-row map,
+    # and masses.  Spans (DESIGN.md §16) split a phase into the host's pad,
+    # the transfer, the device rounds and the host's compaction.
+    cur_x, cur_orig = x_np, np.arange(n)
+    cur_w = None if weights is None else np.asarray(weights, np.float32)
+    while cur_x.shape[0]:
+        k = cur_x.shape[0]
+        with _span("select.phase", n_pad=_pow2_ceil(k), n_alive=k) as phase:
+            with _span("select.pad"):
+                xp, orig, alive0, wp = _pad_pow2(cur_x, cur_orig, cur_w)
+            b = max(1, min(block, xp.shape[0]))
+            nbytes = xp.nbytes + alive0.nbytes + (0 if wp is None
+                                                  else wp.nbytes)
+            with _span("select.put", bytes=nbytes) as sp:
+                xd, alive_d, wd = sp.sync((
+                    jnp.asarray(xp), jnp.asarray(alive0),
+                    None if wp is None else jnp.asarray(wp)))
+            with _span("select.rounds") as sp:
+                alive, c, w, a, mr = sp.sync(_blocked_select_device(
+                    xd, eps2, b, alive_d, jnp.asarray(k // 2, jnp.int32),
+                    wd))
+            del xd, alive_d, wd  # the phase's rows leave the device here
+            with _span("select.compact"):
+                mm, rounds = (int(v) for v in np.asarray(mr))
+                a = np.asarray(a)
+                absorbed = a >= 0
+                assign[orig[absorbed]] = m + a[absorbed]
+                centers_out.append(np.asarray(c[:mm]))
+                weights_out.append(np.asarray(w[:mm]))
+                m += mm
+                still = np.flatnonzero(np.asarray(alive))
+                cur_x, cur_orig = xp[still], orig[still]
+                cur_w = None if wp is None else wp[still]
+            phase.set(centers=mm, rounds=rounds)
     return (np.concatenate(centers_out),
             np.concatenate(weights_out).astype(np.float64),
             assign, m)

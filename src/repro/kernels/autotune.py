@@ -41,11 +41,9 @@ from repro.obs.trace import span as _span
 # plan-cache telemetry: a "miss" pays a measurement (warmup + reps per
 # candidate) inside the request, so the hit/miss ratio is the difference
 # between a warm serving process and one paying autotune latency on live
-# traffic.  ``autotune.roofline_abs_rel_err`` records |predicted-measured|
-# / measured of each roofline winner — the model-vs-hardware error.
+# traffic.
 _M_HITS = _om.counter("autotune.plan_hits")
 _M_MISSES = _om.counter("autotune.plan_misses")
-_ROOFLINE_ERR_BOUNDS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0)
 
 _LOCK = threading.RLock()
 _MEM: dict[str, dict] = {}     # key -> {"winner": name, "us": {name: micros}}
@@ -309,11 +307,6 @@ def best_roofline(key: str, candidates: dict[str, Callable[[], object]],
         t_best = min(pred.values())
         near = [c for c in pred if pred[c] <= 1.10 * t_best]
         winner = min(near, key=times.get)
-        # roofline model error on the winner: how far the analytic
-        # prediction sat from what the hardware actually did
-        _om.histogram("autotune.roofline_abs_rel_err",
-                      bounds=_ROOFLINE_ERR_BOUNDS).observe(
-            abs(pred[winner] - times[winner]) / max(times[winner], 1e-12))
         _MEM[key] = {
             "winner": winner,
             "us": {c: round(t * 1e6, 1) for c, t in times.items()},

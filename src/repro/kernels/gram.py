@@ -163,6 +163,7 @@ def gram_row_pallas(x: Array, centers: Array, *, sigma: float, p: int = 2,
     row = pl.BlockSpec((1, block_m), lambda j, k: (0, j))
     return pl.pallas_call(
         kernel,
+        name="gram_row",
         grid=(m // block_m, k_steps),
         in_specs=[
             pl.BlockSpec((8, block_k), lambda j, k: (0, k)),
@@ -264,6 +265,7 @@ def gram_matvec_pallas(x: Array, y: Array, v: Array, *, sigma: float,
                                p=int(p), weighted=weighted, k_steps=k_steps)
     return pl.pallas_call(
         kernel,
+        name="gram_matvec",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_k), lambda i, j, k: (i, k)),
@@ -307,6 +309,7 @@ def gram_pallas(x: Array, y: Array, *, sigma: float, p: int = 2,
                                weighted=weighted, k_steps=k_steps)
     return pl.pallas_call(
         kernel,
+        name="gram",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_k), lambda i, j, k: (i, k)),

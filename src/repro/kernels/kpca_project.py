@@ -75,6 +75,7 @@ def kpca_project_pallas(x: Array, centers: Array, projector: Array, *,
                                m_steps=m // block_m)
     return pl.pallas_call(
         kernel,
+        name="kpca_project",
         out_shape=jax.ShapeDtypeStruct((n, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_n, r), jnp.float32)],
         interpret=interpret,
@@ -146,6 +147,7 @@ def kpca_project_quant_pallas(x: Array, centers: Array, q: Array,
     acc_dtype = jnp.int32 if qmode == "int8" else jnp.float32
     return pl.pallas_call(
         kernel,
+        name="kpca_project_quant",
         out_shape=jax.ShapeDtypeStruct((n, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_n, r), acc_dtype)],
         interpret=interpret,

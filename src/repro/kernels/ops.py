@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import autotune
+from repro.obs.trace import profiled_span as _profiled_span
 
 # NOTE on donation: the donated fit/transform entry points mark their
 # scratch operands dead for the caller; XLA only ALIASES a donated buffer
@@ -131,6 +132,19 @@ def _fat_gram_blocks(d: int):
 # --------------------------------------------------------------------------
 
 
+def _named(kernel: str):
+    """Trace a dense plan under its kernel's name (``jax.named_scope``), the
+    name its Pallas plan gives ``pallas_call``, so that a device trace can
+    attribute the dense plan's ops to the kernel."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(kernel):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 def _dist_pow(d2: Array, p: int) -> Array:
     if p == 2:
         return d2
@@ -150,6 +164,7 @@ def _dense_sq_dists(x: Array, y: Array, precision: str) -> Array:
 
 @functools.partial(jax.jit,
                    static_argnames=("sigma", "p", "weighted", "precision"))
+@_named("gram")
 def _gram_dense(x, y, wx, wy, *, sigma, p, weighted, precision):
     d2 = _dense_sq_dists(x, y, precision)
     g = jnp.exp(-_dist_pow(d2, p) / sigma**p)
@@ -160,6 +175,7 @@ def _gram_dense(x, y, wx, wy, *, sigma, p, weighted, precision):
 
 @functools.partial(jax.jit,
                    static_argnames=("sigma", "p", "weighted", "precision"))
+@_named("gram_matvec")
 def _gram_matvec_dense(x, y, wx, wy, v, *, sigma, p, weighted, precision):
     """Below-crossover fallback: materialize the (small) Gram, then matmul."""
     g = _gram_dense(x, y, wx, wy, sigma=sigma, p=p, weighted=weighted,
@@ -169,6 +185,7 @@ def _gram_matvec_dense(x, y, wx, wy, v, *, sigma, p, weighted, precision):
 
 
 @functools.partial(jax.jit, static_argnames=())
+@_named("shadow_assign")
 def _assign_dense(x, c, valid):
     d2 = _dense_sq_dists(x, c, "f32")  # assignment always resolves in f32
     d2 = jnp.where(valid[None, :] > 0.0, d2, jnp.inf)
@@ -176,6 +193,7 @@ def _assign_dense(x, c, valid):
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "p", "precision"))
+@_named("kpca_project")
 def _project_dense(x, c, a, *, sigma, p, precision):
     cd = _compute_dtype(precision)
     d2 = _dense_sq_dists(x, c, precision)
@@ -184,6 +202,7 @@ def _project_dense(x, c, a, *, sigma, p, precision):
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "p", "qmode"))
+@_named("kpca_project_quant")
 def _project_dense_quant(x, c, q, s, *, sigma, p, qmode):
     # dense fallback of the quantized serving tier: IDENTICAL quantized
     # arithmetic to kernels/kpca_project._project_kernel_quant — the int8
@@ -592,6 +611,7 @@ def weighted_gram_matvec(centers, weights, v, *, sigma: float, p: int = 2,
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "p", "weighted"))
+@_named("gram_row")
 def _gram_row_dense(x, c, w, *, sigma, p, weighted):
     d2 = _dense_sq_dists(x[None, :], c, "f32")[0]
     g = jnp.exp(-_dist_pow(d2, p) / sigma**p)
@@ -785,46 +805,52 @@ def kpca_project(x, centers, projector, *, sigma: float, p: int = 2,
     ``plan`` forces a compute plan: "dense", "pallas" (default row tile) or
     "pallas:<row-tile>"; ``None`` asks the roofline autotuner.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    x = jnp.asarray(x, jnp.float32)
-    centers = jnp.asarray(centers, jnp.float32)
-    projector = jnp.asarray(projector, jnp.float32)
-    n, r = x.shape[0], projector.shape[1]
-    m, d = centers.shape
-    quant = precision in _quantize.QUANT_PRECISIONS
-    if projector_q is not None and not quant:
-        raise ValueError(
-            f"projector_q only applies to {_quantize.QUANT_PRECISIONS}, "
-            f"got precision={precision!r}")
-    if precision == "fp8" and not interpret and not fp8_mxu():
-        raise ValueError(
-            f"precision='fp8' needs an fp8 MXU; "
-            f"{jax.devices()[0].device_kind!r} has none (serve int8 or bf16 "
-            "on this chip)")
-    if plan is None:
-        plan = _project_plan(min(n, chunk or n), m, d, r, precision,
-                             interpret)
-    # the quantized tier keeps distance operands f32 (only the projector
-    # contraction drops precision); f32/bf16 tiers cast as before
-    cd = jnp.float32 if quant else _compute_dtype(precision)
-    # pad m to the center tile; padded projector rows are zero so padded
-    # centers cannot contribute
-    cp = _pad_rows(centers, center_tile(m)).astype(cd)
-    rp = _round_up(r, 128)
-    if quant:
-        if projector_q is None:
-            projector_q = _quantize.quantize_projector(projector, precision)
-        qv, qs = projector_q
-        # padded q rows/cols are zero (can't contribute); padded scale
-        # columns are 1 (never divide/NaN) and stripped with the output
-        qp = jnp.pad(qv, ((0, cp.shape[0] - m), (0, rp - r)))
-        sp = jnp.pad(jnp.asarray(qs, jnp.float32), (0, rp - r),
-                     constant_values=1.0).reshape(1, rp)
-    else:
-        ap = _pad_rows(projector, cp.shape[0])
-        ap = jnp.pad(ap, ((0, 0), (0, rp - r)))
-    tile = int(plan.split(":", 1)[1]) if plan.startswith("pallas:") else 512
+    # spans (DESIGN.md §16): ``project.prep`` is everything before the
+    # kernel call (operand conversion, the plan, the operator's padding),
+    # ``project.launch`` the call(s) of the jitted projection
+    with _profiled_span("project.prep"):
+        if interpret is None:
+            interpret = not _on_tpu()
+        x = jnp.asarray(x, jnp.float32)
+        centers = jnp.asarray(centers, jnp.float32)
+        projector = jnp.asarray(projector, jnp.float32)
+        n, r = x.shape[0], projector.shape[1]
+        m, d = centers.shape
+        quant = precision in _quantize.QUANT_PRECISIONS
+        if projector_q is not None and not quant:
+            raise ValueError(
+                f"projector_q only applies to {_quantize.QUANT_PRECISIONS}, "
+                f"got precision={precision!r}")
+        if precision == "fp8" and not interpret and not fp8_mxu():
+            raise ValueError(
+                f"precision='fp8' needs an fp8 MXU; "
+                f"{jax.devices()[0].device_kind!r} has none (serve int8 or "
+                "bf16 on this chip)")
+        if plan is None:
+            plan = _project_plan(min(n, chunk or n), m, d, r, precision,
+                                 interpret)
+        # the quantized tier keeps distance operands f32 (only the projector
+        # contraction drops precision); f32/bf16 tiers cast as before
+        cd = jnp.float32 if quant else _compute_dtype(precision)
+        # pad m to the center tile; padded projector rows are zero so padded
+        # centers cannot contribute
+        cp = _pad_rows(centers, center_tile(m)).astype(cd)
+        rp = _round_up(r, 128)
+        if quant:
+            if projector_q is None:
+                projector_q = _quantize.quantize_projector(projector,
+                                                           precision)
+            qv, qs = projector_q
+            # padded q rows/cols are zero (can't contribute); padded scale
+            # columns are 1 (never divide/NaN) and stripped with the output
+            qp = jnp.pad(qv, ((0, cp.shape[0] - m), (0, rp - r)))
+            sp = jnp.pad(jnp.asarray(qs, jnp.float32), (0, rp - r),
+                         constant_values=1.0).reshape(1, rp)
+        else:
+            ap = _pad_rows(projector, cp.shape[0])
+            ap = jnp.pad(ap, ((0, 0), (0, rp - r)))
+        tile = int(plan.split(":", 1)[1]) if plan.startswith("pallas:") \
+            else 512
 
     def run(xs, owned):
         if plan == "dense":
@@ -853,15 +879,18 @@ def kpca_project(x, centers, projector, *, sigma: float, p: int = 2,
         return out[: xs.shape[0], :r]
 
     if chunk is None or n <= chunk:
-        return run(x, owned=False)
+        with _profiled_span("project.launch", chunks=1):
+            return run(x, owned=False)
     chunk = _round_up(chunk, 128)
-    # fixed-shape streaming: pad the row count to a chunk multiple so EVERY
-    # slice (the ragged tail included) traces with one shape; each slice is
-    # a fresh buffer this function owns, so donation needs no copy
-    xpad = _pad_rows(x, chunk)
-    pieces = [run(xpad[s : s + chunk], owned=True)  # slices are fresh buffers
-              for s in range(0, xpad.shape[0], chunk)]
-    return jnp.concatenate(pieces, axis=0)[:n]
+    with _profiled_span("project.launch", chunks=-(-n // chunk)):
+        # fixed-shape streaming: pad the row count to a chunk multiple so
+        # EVERY slice (the ragged tail included) traces with one shape; each
+        # slice is a fresh buffer this function owns, so donation needs no
+        # copy
+        xpad = _pad_rows(x, chunk)
+        pieces = [run(xpad[s : s + chunk], owned=True)  # fresh buffers
+                  for s in range(0, xpad.shape[0], chunk)]
+        return jnp.concatenate(pieces, axis=0)[:n]
 
 
 # --------------------------------------------------------------------------
@@ -885,6 +914,7 @@ def rff_features(x, omega, phase, *, scale, precision="f32"):
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "precision"))
+@_named("rff")
 def _rff_dense(x, omega, phase, u, *, scale, precision):
     z = rff_features(x, omega, phase, scale=scale, precision=precision)
     cd = _compute_dtype(precision)

@@ -71,6 +71,7 @@ def rff_project_pallas(x: Array, omega: Array, phase: Array, u: Array, *,
                                f_steps=nfeat // block_f)
     return pl.pallas_call(
         kernel,
+        name="rff",
         grid=(n // block_n, nfeat // block_f),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),

@@ -78,6 +78,7 @@ def shadow_assign_pallas(x: Array, centers: Array, valid: Array, *,
     row = pl.BlockSpec((1, block_n), lambda i, j: (0, i))
     return pl.pallas_call(
         kernel,
+        name="shadow_assign",
         grid=(n // block_n, m_steps),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),

@@ -15,7 +15,8 @@ One switch governs everything::
 Disabled (the default), every instrumentation site costs one function call
 plus one module-global load — no locks, no allocation, no host syncs — so
 the hot paths keep their benchmarked numbers (gated ~0% by
-benchmarks/obs_overhead.py; enabled mode is gated <= 2%).  The flag is
+benchmarks/obs_overhead.py; enabled mode is gated <= 2%).  While enabled,
+spans also annotate a ``jax.profiler`` trace being taken.  The flag is
 process-wide and can be toggled at runtime; jitted code is never touched
 (all instrumentation lives on the host driver side), so toggling never
 retraces anything.

@@ -5,13 +5,21 @@ A span is one timed region of a hot path::
     with span("ingest.select_chunk", chunk=i, rows=n_valid):
         ...
 
-Spans NEST: each thread keeps a depth counter, so a Chrome-trace viewer
-renders ``serve.batch`` containing ``swap.transform`` containing the kernel
-dispatch as stacked bars.  Completed spans land in a bounded ``deque``
-(``maxlen`` ring semantics: CPython's deque append/popleft are atomic under
-the GIL, so producers on the dispatcher, producer-feed, and client threads
-never take a lock on the hot path and the buffer can never grow without
-bound).
+Spans NEST: each thread keeps a stack of its open spans, so every span
+records an integer ``id`` and the ``parent`` id of the span open on its
+thread when it began (0 for none); a Chrome-trace viewer renders
+``serve.batch`` containing ``project.launch`` as stacked bars, and a reader
+can attribute a parent's time to its children.  Completed spans land in a
+bounded ``deque`` (``maxlen`` ring semantics: CPython's deque
+append/popleft are atomic under the GIL, so producers on the dispatcher,
+producer-feed, and client threads never take a lock on the hot path and the
+buffer can never grow without bound).
+
+While enabled and while ``jax.profiler`` takes a trace, each span also
+enters a ``jax.profiler.TraceAnnotation`` of its name and the attributes
+known at entry, so it lands on its thread's host line of the ``.xplane.pb``,
+on the same clock as the device ops.  With no trace being taken the
+annotation would record nothing, so it is not made (one C++ flag read).
 
 Timing is wall-clock (``time.perf_counter``) by default.  JAX dispatch is
 asynchronous — a wall-clock exit can close a span whose device work is still
@@ -21,6 +29,9 @@ ready and records the synced fraction of the span separately::
 
     with span("serve.transform", rows=r) as sp:
         z = sp.sync(server.transform(x))   # dur now covers device work
+
+The layer spans inside one served batch use :func:`profiled_span`, which
+records only while a profiler trace is being taken as well.
 
 Everything is OFF by default: ``span()`` returns a shared no-op object
 (one module-global check, no allocation beyond the kwargs dict) until
@@ -33,11 +44,14 @@ Everything is OFF by default: ``span()`` returns a shared no-op object
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 #: Flipped by repro.obs.enable()/disable(); every hot-path check reads this
 #: module global directly (one dict lookup — the disabled-mode cost).
@@ -46,29 +60,41 @@ _ENABLED = False
 _DEFAULT_RING = 65536
 _EVENTS: deque = deque(maxlen=_DEFAULT_RING)
 _TLS = threading.local()
+#: Span ids, process-wide; ``next`` on a C iterator is atomic under the GIL.
+_IDS = itertools.count(1)
+#: True while a ``jax.profiler`` trace is being taken (one C++ flag read).
+_profiler_on = TraceAnnotation.is_enabled
 
 #: Process-epoch for relative timestamps: every event shares this origin so
 #: cross-thread ordering in the exported trace is meaningful.
 _T0 = time.perf_counter()
 
 
-def _depth() -> int:
-    return getattr(_TLS, "depth", 0)
-
-
 class Span:
     """One live timed region; use via the :func:`span` factory."""
 
-    __slots__ = ("name", "attrs", "t0", "sync_s")
+    __slots__ = ("name", "attrs", "t0", "sync_s", "id", "parent", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
         self.sync_s = 0.0
+        self.id = 0
+        self.parent = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
-        _TLS.depth = _depth() + 1
+        try:  # this thread's open spans, innermost last
+            stack = _TLS.stack
+        except AttributeError:
+            stack = _TLS.stack = []
+        self.parent = stack[-1].id if stack else 0
+        self.id = next(_IDS)
+        stack.append(self)
+        if _profiler_on():
+            self._ann = TraceAnnotation(self.name, **self.attrs)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -89,8 +115,10 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
-        depth = _depth()
-        _TLS.depth = depth - 1
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        stack = _TLS.stack
+        stack.pop()  # spans are context managers: this one is innermost
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         # attrs are flattened to a tuple of pairs: a ring of dicts keeps
@@ -100,8 +128,8 @@ class Span:
         # measurable: the serve-dispatch overhead in benchmarks/
         # obs_overhead.py was ~3% GC amplification before the flattening)
         _EVENTS.append((
-            self.name, threading.get_ident(), depth - 1,
-            self.t0 - _T0, t1 - self.t0, self.sync_s,
+            self.name, threading.get_ident(), self.id, self.parent,
+            len(stack), self.t0 - _T0, t1 - self.t0, self.sync_s,
             tuple(self.attrs.items()),
         ))
         return False
@@ -135,6 +163,22 @@ def span(name: str, **attrs):
     return Span(name, attrs)
 
 
+def profiling() -> bool:
+    """True while enabled and while a ``jax.profiler`` trace is being
+    taken: when :func:`profiled_span` records."""
+    return _ENABLED and _profiler_on()
+
+
+def profiled_span(name: str, **attrs):
+    """A :func:`span` recorded only while a ``jax.profiler`` trace is also
+    being taken.  For the layer boundaries inside one served batch: they
+    exist to attribute a profile's device gaps, and outside a profile four
+    more spans a batch would cost more than the enabled budget allows."""
+    if not profiling():
+        return _NULL
+    return Span(name, attrs)
+
+
 def enabled() -> bool:
     return _ENABLED
 
@@ -152,9 +196,11 @@ def clear() -> None:
 def events() -> list[dict]:
     """Snapshot of the buffered spans, oldest first, as plain dicts."""
     return [
-        {"name": n, "tid": tid, "depth": depth, "t_s": round(t, 6),
-         "dur_s": round(dur, 6), "sync_s": round(sync_s, 6), **dict(attrs)}
-        for n, tid, depth, t, dur, sync_s, attrs in list(_EVENTS)
+        {"name": n, "tid": tid, "id": sid, "parent": parent, "depth": depth,
+         "t_s": round(t, 6), "dur_s": round(dur, 6),
+         "sync_s": round(sync_s, 6), **dict(attrs)}
+        for n, tid, sid, parent, depth, t, dur, sync_s, attrs
+        in list(_EVENTS)
     ]
 
 
@@ -170,8 +216,10 @@ def export_chrome(path: str) -> int:
     one track per thread); returns the number of events written."""
     evs = list(_EVENTS)
     out = []
-    for name, tid, depth, t, dur, sync_s, attrs in evs:
+    for name, tid, sid, parent, _depth, t, dur, sync_s, attrs in evs:
         args = dict(attrs)  # ring stores flattened (k, v) pairs
+        args["id"] = sid
+        args["parent"] = parent
         if sync_s:
             args["sync_ms"] = round(sync_s * 1e3, 3)
         out.append({

@@ -40,6 +40,8 @@ import numpy as np
 
 from repro.core.shadow import _pow2_ceil
 from repro.obs import metrics as _om
+from repro.obs.trace import profiled_span as _profiled_span
+from repro.obs.trace import profiling as _profiling
 from repro.obs.trace import span as _span
 from repro.runtime import chaos
 from repro.runtime.fault import RetryPolicy, retry_call
@@ -115,6 +117,7 @@ class _Pending:
     future: Future
     deadline: float      # absolute time.monotonic() deadline
     enqueued: float
+    seq: int = 0         # admission order: a batch holds a contiguous run
 
 
 class BatchingFrontEnd:
@@ -153,11 +156,12 @@ class BatchingFrontEnd:
         #: closes admission and drains everything already queued.
         self._guard = guard
         self.stats = ServeStats()
-        # per-bucket (histogram, gauge) handles, resolved once per bucket:
-        # a registry lookup per dispatch (label-dict alloc + registry lock)
+        # per-bucket service-time histograms, resolved once per bucket: a
+        # registry lookup per dispatch (label-dict alloc + registry lock)
         # is exactly the kind of hot-path cost the <= 2% budget forbids
-        self._obs_bucket: dict[int, tuple] = {}
+        self._obs_bucket: dict[int, object] = {}
         self._pending: list[_Pending] = []
+        self._seq = 0  # sequence number of the next admitted request
         self._cond = threading.Condition()
         self._closed = False
         self._thread = None
@@ -185,6 +189,8 @@ class BatchingFrontEnd:
                 fut.set_exception(RequestShed(
                     f"queue at max_queue={self.max_queue}; request shed"))
                 return fut
+            req.seq = self._seq
+            self._seq += 1
             self._pending.append(req)
             self.stats.requests += 1
             self.stats.rows += x.shape[0]
@@ -273,12 +279,24 @@ class BatchingFrontEnd:
 
     def _serve(self, batch: list[_Pending]) -> None:
         """One fused transform for the whole batch + scatter to futures."""
-        xs = np.concatenate([p.x for p in batch], axis=0)
-        rows = xs.shape[0]
-        bucket = self._bucket(rows)
-        if rows < bucket:  # ragged tail: pad rows, mask on the way out
-            xs = np.concatenate(
-                [xs, np.zeros((bucket - rows, xs.shape[1]), xs.dtype)])
+        # the batch's requests (a contiguous run of admission numbers) and
+        # their queue waits, admission to now, for the serve.batch span of
+        # a profiled run; FIFO order makes the first request the longest
+        # waiting
+        ids = {}
+        if _profiling():
+            now = time.monotonic()
+            ids = {"req_lo": batch[0].seq, "req_hi": batch[-1].seq,
+                   "wait_ms_sum": 1e3 * (len(batch) * now
+                                         - sum(p.enqueued for p in batch)),
+                   "wait_ms_max": 1e3 * (now - batch[0].enqueued)}
+        with _profiled_span("serve.coalesce", requests=len(batch)):
+            xs = np.concatenate([p.x for p in batch], axis=0)
+            rows = xs.shape[0]
+            bucket = self._bucket(rows)
+            if rows < bucket:  # ragged tail: pad rows, mask on the way out
+                xs = np.concatenate(
+                    [xs, np.zeros((bucket - rows, xs.shape[1]), xs.dtype)])
         t0 = time.monotonic()
 
         def dispatch():
@@ -289,7 +307,7 @@ class BatchingFrontEnd:
             # time no request can use
             chaos.inject("serve.dispatch")
             with _span("serve.batch", rows=rows, bucket=bucket,
-                       requests=len(batch)):
+                       requests=len(batch), **ids):
                 return np.asarray(self.server.transform(xs))[:rows]
 
         retries = [0]
@@ -323,14 +341,13 @@ class BatchingFrontEnd:
                 self.stats.batched_rows += rows
         _M_BATCHES.inc()
         _M_COALESCE.observe(rows)
-        if _om.enabled():  # per-bucket series: one histogram + one gauge
-            handles = self._obs_bucket.get(bucket)
-            if handles is None:
-                handles = self._obs_bucket.setdefault(bucket, (
-                    _om.histogram("serve.service_ms", {"bucket": bucket}),
-                    _om.gauge("serve.ewma_service_ms", {"bucket": bucket})))
-            handles[0].observe(dt * 1e3)
-            handles[1].set(ewma * 1e3)
+        if _om.enabled():  # per-bucket series: one histogram
+            hist = self._obs_bucket.get(bucket)
+            if hist is None:
+                hist = self._obs_bucket.setdefault(
+                    bucket,
+                    _om.histogram("serve.service_ms", {"bucket": bucket}))
+            hist.observe(dt * 1e3)
         info = None
         if getattr(self.server, "degraded", False):
             # stale-snapshot serving (failed publish): tag every response

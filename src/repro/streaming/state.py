@@ -195,9 +195,9 @@ def _solve(kgram: Array, weights: Array, n: Array, rank1: int,
         def matvec(v):
             return sw[:, None] * (kgram @ (sw[:, None] * v)) / n
 
-        return _lobpcg_topk(matvec, cap, rank1)
+        return _lobpcg_topk(matvec, cap, rank1)[:2]
     kt = sw[:, None] * kgram * sw[None, :] / n
-    lam, u = _top_eigh(kt, rank1)
+    lam, u, _ = _top_eigh(kt, rank1)
     return lam, _canonicalize_signs(u)
 
 

@@ -22,6 +22,7 @@ import numpy as np
 from repro.kernels import ops as kernel_ops
 from repro.kernels import quantize
 from repro.obs import metrics as _om
+from repro.obs.trace import profiled_span as _profiled_span
 from repro.obs.trace import span as _span
 from repro.runtime import chaos
 from repro.streaming.state import StreamingRSKPCA
@@ -32,7 +33,6 @@ from repro.streaming.state import StreamingRSKPCA
 _M_PUBLISHES = _om.counter("swap.publishes")
 _M_PUB_MS = _om.histogram("swap.publish_ms")
 _M_AGE = _om.gauge("swap.snapshot_age_s")
-_M_TRANSFORMS = _om.counter("swap.transforms")
 # degradation telemetry (DESIGN.md §17): failed publishes and the §5
 # operator-drift budget the stale snapshot is serving under.
 _M_PUB_FAIL = _om.counter("swap.publish_failures")
@@ -188,19 +188,23 @@ class HotSwapServer:
         # pair the new centers with the old projector
         snapshot = self._snapshot
         assert snapshot is not None, "publish() an operator before serving"
-        if _om.enabled():
-            _M_TRANSFORMS.inc()
-            if self.published_at is not None:  # age of the snapshot SERVED
-                _M_AGE.set(time.monotonic() - self.published_at)
+        if _om.enabled() and self.published_at is not None:
+            # age of the snapshot SERVED
+            _M_AGE.set(time.monotonic() - self.published_at)
         centers, projector, kernel, projector_q = snapshot
         if mesh is not None:
             from repro.core import distributed as dist
             z = dist.sharded_kpca_project(
                 x, centers, projector, kernel, mesh,
                 axis=axis, chunk=self.chunk)
+        else:
+            z = kernel_ops.kpca_project(
+                x, centers, projector,
+                sigma=kernel.sigma, p=kernel.p, chunk=self.chunk,
+                precision=kernel.precision, projector_q=projector_q)
+        # start the copy out now, so that it follows the device work
+        # without waiting for the host to ask; the span is the rest of the
+        # device's work and the copy
+        z.copy_to_host_async()
+        with _profiled_span("swap.fetch"):
             return np.asarray(z)
-        z = kernel_ops.kpca_project(
-            x, centers, projector,
-            sigma=kernel.sigma, p=kernel.p, chunk=self.chunk,
-            precision=kernel.precision, projector_q=projector_q)
-        return np.asarray(z)

@@ -228,8 +228,9 @@ def test_sharded_matfree_matches_single_device():
     mesh = make_mesh((1,), ("data",))
     lam_s, proj_s = fit_rskpca_sharded(c, w, n, ker, 4, mesh,
                                        lobpcg_min_m=64, matfree=True)
-    lam_1, proj_1 = _fit_rskpca_device(jnp.asarray(c), jnp.asarray(w),
-                                       jnp.float32(n), ker, 4, matfree=True)
+    lam_1, proj_1, _ = _fit_rskpca_device(jnp.asarray(c), jnp.asarray(w),
+                                          jnp.float32(n), ker, 4,
+                                          matfree=True)
     np.testing.assert_allclose(np.asarray(lam_s), np.asarray(lam_1),
                                rtol=1e-4)
     np.testing.assert_allclose(np.asarray(proj_s), np.asarray(proj_1),
@@ -275,7 +276,7 @@ def test_fit_donates_and_aliases_center_buffer():
     ker = gaussian(1.0)
     c = jnp.asarray(rng.normal(size=(256, 8)).astype(np.float32))
     w = jnp.asarray(rng.uniform(1, 5, 256).astype(np.float32))
-    lam, proj = _fit_rskpca_device(c, w, jnp.float32(1000.0), ker, 8)
+    lam, proj, _ = _fit_rskpca_device(c, w, jnp.float32(1000.0), ker, 8)
     jax.block_until_ready(proj)
     assert c.is_deleted(), "donated center buffer was copied, not aliased"
     assert np.isfinite(np.asarray(proj)).all()

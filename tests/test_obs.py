@@ -7,6 +7,7 @@ which resets metric values and the trace ring on both sides — the layer is
 process-global state, and leaking an enabled flag or a counter value into
 an unrelated test would be exactly the kind of action at a distance the
 off-by-default design exists to prevent."""
+import contextlib
 import json
 import threading
 import time
@@ -289,7 +290,8 @@ def test_serve_frontend_metrics_and_spans(obs_on):
     assert metrics.histogram("serve.deadline_slack_ms").count == 1
     # per-bucket series: 7 rows pad to the pow2 bucket 8
     assert metrics.histogram("serve.service_ms", {"bucket": 8}).count == 1
-    assert metrics.gauge("serve.ewma_service_ms", {"bucket": 8}).value > 0.0
+    # the bucket's smoothed service time lives in the front end's stats
+    assert fe.snapshot().ewma_service_s[8] > 0.0
     names = [e["name"] for e in trace.events()]
     assert "serve.batch" in names
 
@@ -329,7 +331,8 @@ def test_swap_publish_metrics_and_age_gauge_resets(obs_on):
     assert age.value == 0.0
     time.sleep(0.01)
     srv.transform(np.zeros((4, 6), np.float32))
-    assert metrics.counter("swap.transforms").value == 1
+    # the fetch's span records only under a profiler trace
+    assert "swap.fetch" not in [e["name"] for e in trace.events()]
     served_age = age.value
     assert served_age > 0.0  # transform saw a snapshot published earlier
     # REGRESSION: a publish must reset the age gauge, not leave the last
@@ -530,3 +533,260 @@ def test_end_to_end_trace_and_metrics(tmp_path, obs_on):
     assert 'serve_service_ms_bucket{bucket="16"' in text
     assert "ingest_overlap_fraction" in text
     assert "stream_m" in text
+
+
+# -------------------------------------------------------------------------
+# span ids and parents, the profiler bridge, and the layer spans that the
+# benchmark's per-layer metrics read
+# -------------------------------------------------------------------------
+
+
+def test_disabled_span_is_the_shared_null():
+    assert not obs.enabled()
+    assert obs.span("x.y", rows=3) is trace._NULL
+    assert trace.span("z.w") is trace._NULL
+
+
+def test_span_ids_and_parents_across_nesting_and_threads(obs_on):
+    seen = {}
+
+    def worker():
+        with obs.span("thread.op") as sp:
+            seen["thread"] = (sp.id, sp.parent)
+
+    with obs.span("outer.op") as outer:
+        with obs.span("first.child") as a:
+            with obs.span("grand.child") as g:
+                pass
+        with obs.span("second.child") as b:
+            # a span opened on another thread while outer.op is open here
+            # has no parent: parents are per thread
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
+    with obs.span("next.root") as nxt:
+        pass
+    assert outer.parent == 0 and nxt.parent == 0
+    assert a.parent == b.parent == outer.id
+    assert g.parent == a.id
+    assert seen["thread"][1] == 0
+    ids = [outer.id, a.id, g.id, b.id, nxt.id, seen["thread"][0]]
+    assert len(set(ids)) == len(ids) and min(ids) > 0
+    evs = {e["name"]: e for e in trace.events()}
+    assert (evs["grand.child"]["id"], evs["grand.child"]["parent"]) == \
+        (g.id, a.id)
+    assert [evs[n]["depth"] for n in ("outer.op", "first.child",
+                                      "grand.child", "thread.op")] == \
+        [0, 1, 2, 0]
+
+
+def test_chrome_export_carries_ids_and_parents(tmp_path, obs_on):
+    with obs.span("outer.op") as outer:
+        with obs.span("inner.op", rows=2) as inner:
+            pass
+    path = tmp_path / "trace.json"
+    trace.export_chrome(str(path))
+    args = {e["name"]: e["args"]
+            for e in json.loads(path.read_text())["traceEvents"]}
+    assert args["inner.op"] == {"rows": 2, "id": inner.id,
+                                "parent": outer.id}
+    assert args["outer.op"]["parent"] == 0
+
+
+def _host_event_names(trace_dir) -> set:
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
+
+
+def test_enabled_spans_land_on_the_profilers_host_line(tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("obs.test_disabled", rows=1):
+            jnp.arange(4).block_until_ready()
+        obs.enable()
+        try:
+            with obs.span("obs.test_enabled", rows=2):
+                jnp.arange(4).block_until_ready()
+        finally:
+            obs.disable()
+            trace.clear()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(tmp_path)
+    assert "obs.test_enabled" in names
+    assert "obs.test_disabled" not in names
+
+
+@contextlib.contextmanager
+def _profiled(trace_dir):
+    """A ``jax.profiler`` trace around the block."""
+    import jax
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def test_profiled_span_records_only_under_a_profile(tmp_path):
+    assert trace.profiled_span("x.y") is trace._NULL  # disabled
+    obs.enable()
+    try:
+        assert trace.profiled_span("x.y") is trace._NULL  # no profile
+        assert not trace.profiling()
+        with _profiled(tmp_path):
+            assert trace.profiling()
+            with trace.profiled_span("obs.test_profiled", rows=3) as sp:
+                pass
+    finally:
+        obs.disable()
+    assert sp is not trace._NULL
+    (ev,) = [e for e in trace.events() if e["name"] == "obs.test_profiled"]
+    assert ev["rows"] == 3
+    trace.clear()
+    assert "obs.test_profiled" in _host_event_names(tmp_path)
+
+
+def test_serve_batch_carries_request_ids_and_waits(tmp_path, obs_on):
+    fe = BatchingFrontEnd(_StubServer(), max_batch=8, autostart=False)
+    futs = [fe.submit(np.ones((k, 3), np.float32)) for k in (2, 3, 2, 4, 1)]
+    with _profiled(tmp_path):
+        assert fe.step() == 7   # 2 + 3 + 2; the 4-row request would overflow
+        assert fe.step() == 5
+    for f in futs:
+        f.result(timeout=0)
+    batches = [e for e in trace.events() if e["name"] == "serve.batch"]
+    assert [(e["req_lo"], e["req_hi"]) for e in batches] == [(0, 2), (3, 4)]
+    for e in batches:
+        assert e["requests"] == e["req_hi"] - e["req_lo"] + 1
+        assert 0.0 <= e["wait_ms_max"] <= e["wait_ms_sum"]
+        assert e["wait_ms_sum"] <= e["requests"] * e["wait_ms_max"]
+    # the second batch waited through the first one's service
+    assert batches[1]["wait_ms_max"] >= batches[0]["wait_ms_max"]
+
+
+def test_serve_batch_children_on_a_published_operator(tmp_path, obs_on):
+    _, _, st = _state()
+    srv = streaming.HotSwapServer(st)
+    fe = BatchingFrontEnd(srv, max_batch=64, autostart=False)
+    fe.submit(_blobs(5, seed=4))
+    fe.submit(_blobs(3, seed=5))
+    with _profiled(tmp_path):
+        assert fe.step() == 8
+    evs = trace.events()
+    (batch,) = [e for e in evs if e["name"] == "serve.batch"]
+    (coalesce,) = [e for e in evs if e["name"] == "serve.coalesce"]
+    assert coalesce["parent"] == 0 and coalesce["requests"] == 2
+    kids = {e["name"]: e for e in evs if e["parent"] == batch["id"]}
+    assert set(kids) == {"project.prep", "project.launch", "swap.fetch"}
+    assert kids["project.launch"]["chunks"] == 1
+    assert kids["swap.fetch"]["sync_s"] == 0.0  # no sync of its own
+    assert sum(k["dur_s"] for k in kids.values()) <= batch["dur_s"]
+
+
+def _reference_rounds(x, eps, block):
+    """Rounds and centers of blocked selection, run to exhaustion in float64
+    with direct differences: each round takes the first ``block`` alive
+    rows, keeps the greedy eps-separated prefix, and absorbs every alive
+    row strictly within eps of a keeper."""
+    x = np.asarray(x, np.float64)
+    alive = np.ones(len(x), bool)
+    rounds = m = 0
+    while alive.any():
+        kept = []
+        for j in np.flatnonzero(alive)[:block]:
+            if all(np.sum((x[j] - x[k]) ** 2) >= eps ** 2 for k in kept):
+                kept.append(j)
+        d2 = ((x[:, None, :] - x[kept][None]) ** 2).sum(-1).min(axis=1)
+        alive &= ~(d2 < eps ** 2)
+        rounds += 1
+        m += len(kept)
+    return rounds, m
+
+
+@pytest.mark.parametrize("block", [1, 16])
+def test_select_phase_rounds_match_a_reference_count(obs_on, block):
+    from repro.core.shadow import shadow_select_blocked
+
+    x, eps = _blobs(300, seed=11), 0.5
+    centers, weights, _, m = shadow_select_blocked(x, eps, block=block)
+    ref_rounds, ref_m = _reference_rounds(x, eps, block)
+    evs = trace.events()
+    phases = [e for e in evs if e["name"] == "select.phase"]
+    assert len(phases) >= 2  # the alive set halves at least once
+    assert sum(e["rounds"] for e in phases) == ref_rounds
+    if block == 1:
+        assert ref_rounds == ref_m  # one keeper a round
+    assert m == ref_m == centers.shape[0] == sum(e["centers"]
+                                                  for e in phases)
+    assert weights.sum() == 300
+    assert phases[0]["n_alive"] == 300 and phases[0]["n_pad"] == 512
+    for ph in phases:
+        kids = [e["name"] for e in evs if e["parent"] == ph["id"]]
+        assert kids == ["select.pad", "select.put", "select.rounds",
+                        "select.compact"]
+    put = next(e for e in evs if e["name"] == "select.put")
+    assert put["bytes"] == 512 * 6 * 4 + 512  # rows and the alive mask
+
+
+@pytest.mark.parametrize("m,lobpcg", [(1100, True), (200, False)])
+def test_fit_solve_counts_lobpcg_iterations(obs_on, m, lobpcg):
+    from repro.core.pipeline import fit_centers
+
+    rng = np.random.default_rng(m)
+    c = rng.normal(size=(m, 4)).astype(np.float32) * 3.0
+    w = rng.integers(1, 5, m).astype(np.float32)
+    model = fit_centers(c, w, int(w.sum()), gaussian(1.0, backend="dense"),
+                        4, matfree=False)
+    assert model.projector.shape == (m, 4)
+    evs = trace.events()
+    (solve,) = [e for e in evs if e["name"] == "fit.solve"]
+    (stage,) = [e for e in evs if e["name"] == "fit.stage"]
+    # an exact host set of m >= 128 rows is fitted at its own size
+    assert (solve["m"], solve["cap"], solve["matfree"]) == (m, m, False)
+    if lobpcg:
+        assert solve["lobpcg_iters"] > 0
+    else:
+        assert solve["lobpcg_iters"] == 0
+    assert stage["t_s"] + stage["dur_s"] <= solve["t_s"]
+
+
+def test_disabled_spans_never_sync(monkeypatch):
+    """Off, the serve, selection and fit spans add no device sync: the
+    null span's ``sync`` hands its value back untouched."""
+    import jax
+
+    from repro.core.pipeline import fit_centers
+    from repro.core.shadow import shadow_select_blocked
+
+    assert not obs.enabled()
+    _, _, st = _state()
+    srv = streaming.HotSwapServer(st)
+    fe = BatchingFrontEnd(srv, max_batch=64, autostart=False)
+
+    def no_sync(value):
+        raise AssertionError("a disabled span blocked on the device")
+
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")  # no plan measurement
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
+    fut = fe.submit(_blobs(5, seed=6))
+    assert fe.step() == 5 and fut.result(timeout=0).shape == (5, RANK)
+    c, w, _, m = shadow_select_blocked(_blobs(200, seed=7), 0.5, block=16)
+    assert w.sum() == 200
+    model = fit_centers(c, w, 200, gaussian(SIGMA), 2)
+    assert model.projector.shape == (m, 2)
+    assert trace.events() == []

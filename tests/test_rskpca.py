@@ -126,7 +126,7 @@ def test_top_eigh_lobpcg_branch_matches_eigh():
     q, _ = np.linalg.qr(rng.normal(size=(m, 40)))
     lam_true = 2.0 ** -np.arange(40)  # fast-decaying, like a kernel spectrum
     mat = jnp.asarray((q * lam_true) @ q.T, jnp.float32)
-    lam, vec = _top_eigh(mat, 6)
+    lam, vec, _ = _top_eigh(mat, 6)
     assert vec.shape == (m, 6)
     np.testing.assert_allclose(np.asarray(lam), lam_true[:6], rtol=5e-4)
 
