@@ -236,7 +236,9 @@ _MEASURE_MAX_ROWS = 8192
 def _bench_rows(n: int, d: int) -> np.ndarray:
     # deterministic synthetic operands for plan measurement (values are
     # irrelevant to timing).  Host arrays, so they stay concrete when the op
-    # asking for a plan is itself being traced.
+    # asking for a plan is itself being traced.  Plan functions build them in
+    # a ``functools.cache``d thunk that only their candidates call, so a plan
+    # the cache holds builds none and a miss builds them once.
     n = min(n, _MEASURE_MAX_ROWS)
     return (np.arange(n * d, dtype=np.float32) % np.float32(977.0)
             ).reshape(n, d) / np.float32(977.0)
@@ -252,11 +254,12 @@ def _gram_plan(n: int, m: int, d: int, precision: str, interpret: bool):
         return ((kind, None) if kind == "dense"
                 else ("pallas", pick_gram_blocks(d)))
     key = f"gram|n{nb}|m{mb}|d{db}|{precision}|{mode}"
-    x, y = _bench_rows(nb, db), _bench_rows(mb, db)
+    operands = functools.cache(
+        lambda: (_bench_rows(nb, db), _bench_rows(mb, db)))
 
     def run(plan):
         return lambda: jax.block_until_ready(gram(
-            x, y, sigma=1.0, p=2, interpret=interpret,
+            *operands(), sigma=1.0, p=2, interpret=interpret,
             precision=precision, plan=plan))
 
     cands = {"pallas": run("pallas")}
@@ -291,12 +294,13 @@ def _matvec_plan(n: int, m: int, d: int, r: int, precision: str,
     mode = "interp" if interpret else "tpu"
     key = f"gmv|n{nb}|m{mb}|d{db}|r{rb}|{precision}|{mode}" \
         + ("" if allow_dense else "|nd")
-    x, y = _bench_rows(nb, db), _bench_rows(mb, db)
-    v = _bench_rows(min(mb, _MEASURE_MAX_ROWS), rb)
+    operands = functools.cache(
+        lambda: (_bench_rows(nb, db), _bench_rows(mb, db),
+                 _bench_rows(mb, rb)))
 
     def run(plan):
         return lambda: jax.block_until_ready(gram_matvec(
-            x, y, v, sigma=1.0, p=2, interpret=interpret,
+            *operands(), sigma=1.0, p=2, interpret=interpret,
             precision=precision, plan=plan))
 
     cands = {"pallas": run("pallas")}
@@ -325,11 +329,12 @@ def _assign_plan(n: int, m: int, d: int, interpret: bool,
         return autotune.heuristic_plan(n, m, interpret)
     mode = "interp" if interpret else "tpu"
     key = f"assign|n{nb}|m{mb}|d{db}|{mode}" + (f"|{tag}" if tag else "")
-    x, c = _bench_rows(nb, db), _bench_rows(mb, db)
+    operands = functools.cache(
+        lambda: (_bench_rows(nb, db), _bench_rows(mb, db)))
 
     def run(plan):
         return lambda: jax.block_until_ready(shadow_assign(
-            x, c, interpret=interpret, plan=plan)[1])
+            *operands(), interpret=interpret, plan=plan)[1])
 
     cands = {"pallas": run("pallas")}
     if nb * mb <= autotune.DENSE_MAX_CELLS:
@@ -376,25 +381,28 @@ def _project_plan(n: int, m: int, d: int, r: int, precision: str,
         return autotune.heuristic_plan(n, m, interpret)
     mode = "interp" if interpret else "tpu"
     key = f"project|n{nb}|m{mb}|d{db}|r{rb}|{precision}|{mode}"
-    x, c = _bench_rows(nb, db), _bench_rows(mb, db)
-    a = _bench_rows(c.shape[0], rb)
-    # quantize the bench projector once, ahead of the timed calls: the
-    # serving contract quantizes at snapshot publish, so per-call
-    # quantization must not pollute the timing.  Lazily, inside the
-    # measurement, where arrays are concrete even if this op is traced.
-    aq = []
+
+    @functools.cache
+    def operands():
+        x, c = _bench_rows(nb, db), _bench_rows(mb, db)
+        a = _bench_rows(mb, rb)
+        # quantize the bench projector once, ahead of the timed calls: the
+        # serving contract quantizes at snapshot publish, so per-call
+        # quantization must not pollute the timing.  Built on the
+        # measurement's thread, so it stays concrete under an outer trace
+        aq = (_quantize.quantize_projector(a, precision)
+              if precision in _quantize.QUANT_PRECISIONS else None)
+        return x, c, a, aq
 
     def run(plan):
         def call():
-            if precision in _quantize.QUANT_PRECISIONS and not aq:
-                aq.append(_quantize.quantize_projector(a, precision))
+            x, c, a, aq = operands()
             return jax.block_until_ready(kpca_project(
                 x, c, a, sigma=1.0, p=2, interpret=interpret,
-                precision=precision, plan=plan,
-                projector_q=aq[0] if aq else None))
+                precision=precision, plan=plan, projector_q=aq))
         return call
 
-    neff, meff = x.shape[0], c.shape[0]
+    neff, meff = min(nb, _MEASURE_MAX_ROWS), min(mb, _MEASURE_MAX_ROWS)
     tiles = _PROJECT_TILES_INTERPRET if interpret else _PROJECT_TILES_TPU
     cands, costs = {}, {}
     for t in tiles:
@@ -638,11 +646,12 @@ def _gram_row_plan(m: int, d: int, interpret: bool) -> str:
         return "dense" if interpret else "pallas"
     mode = "interp" if interpret else "tpu"
     key = f"gramrow|m{mb}|d{db}|{mode}"
-    x, c = _bench_rows(8, db)[0], _bench_rows(mb, db)
+    operands = functools.cache(
+        lambda: (_bench_rows(8, db)[0], _bench_rows(mb, db)))
 
     def run(plan):
         return lambda: jax.block_until_ready(gram_row(
-            x, c, sigma=1.0, p=2, interpret=interpret, plan=plan)[1])
+            *operands(), sigma=1.0, p=2, interpret=interpret, plan=plan)[1])
 
     return autotune.best(key, {"pallas": run("pallas"), "dense": run("dense")},
                          default="pallas")
@@ -806,7 +815,7 @@ def kpca_project(x, centers, projector, *, sigma: float, p: int = 2,
     "pallas:<row-tile>"; ``None`` asks the roofline autotuner.
     """
     # spans (DESIGN.md §16): ``project.prep`` is everything before the
-    # kernel call (operand conversion, the plan, the operator's padding),
+    # kernel call (operand conversion, the plan, a Pallas plan's padding),
     # ``project.launch`` the call(s) of the jitted projection
     with _profiled_span("project.prep"):
         if interpret is None:
@@ -829,28 +838,33 @@ def kpca_project(x, centers, projector, *, sigma: float, p: int = 2,
         if plan is None:
             plan = _project_plan(min(n, chunk or n), m, d, r, precision,
                                  interpret)
-        # the quantized tier keeps distance operands f32 (only the projector
-        # contraction drops precision); f32/bf16 tiers cast as before
-        cd = jnp.float32 if quant else _compute_dtype(precision)
-        # pad m to the center tile; padded projector rows are zero so padded
-        # centers cannot contribute
-        cp = _pad_rows(centers, center_tile(m)).astype(cd)
-        rp = _round_up(r, 128)
         if quant:
             if projector_q is None:
                 projector_q = _quantize.quantize_projector(projector,
                                                            precision)
             qv, qs = projector_q
-            # padded q rows/cols are zero (can't contribute); padded scale
-            # columns are 1 (never divide/NaN) and stripped with the output
-            qp = jnp.pad(qv, ((0, cp.shape[0] - m), (0, rp - r)))
-            sp = jnp.pad(jnp.asarray(qs, jnp.float32), (0, rp - r),
-                         constant_values=1.0).reshape(1, rp)
-        else:
-            ap = _pad_rows(projector, cp.shape[0])
-            ap = jnp.pad(ap, ((0, 0), (0, rp - r)))
-        tile = int(plan.split(":", 1)[1]) if plan.startswith("pallas:") \
-            else 512
+        if plan != "dense":
+            # the kernel's padded operands; the dense plan reads the
+            # caller's arrays as they are, so it builds none of them.
+            # The quantized tier keeps distance operands f32 (only the
+            # projector contraction drops precision); f32/bf16 tiers cast
+            cd = jnp.float32 if quant else _compute_dtype(precision)
+            # pad m to the center tile; padded projector rows are zero so
+            # padded centers cannot contribute
+            cp = _pad_rows(centers, center_tile(m)).astype(cd)
+            rp = _round_up(r, 128)
+            if quant:
+                # padded q rows/cols are zero (can't contribute); padded
+                # scale columns are 1 (never divide/NaN) and stripped with
+                # the output
+                qp = jnp.pad(qv, ((0, cp.shape[0] - m), (0, rp - r)))
+                sp = jnp.pad(jnp.asarray(qs, jnp.float32), (0, rp - r),
+                             constant_values=1.0).reshape(1, rp)
+            else:
+                ap = _pad_rows(projector, cp.shape[0])
+                ap = jnp.pad(ap, ((0, 0), (0, rp - r)))
+            tile = int(plan.split(":", 1)[1]) if plan.startswith("pallas:") \
+                else 512
 
     def run(xs, owned):
         if plan == "dense":
@@ -951,17 +965,19 @@ def _rff_plan(n: int, nfeat: int, d: int, r: int, precision: str,
         return autotune.heuristic_plan(n, nfeat, interpret)
     mode = "interp" if interpret else "tpu"
     key = f"rffproj|n{nb}|D{fb}|d{db}|r{rb}|{precision}|{mode}"
-    x, w = _bench_rows(nb, db), _bench_rows(fb, db)
-    u = _bench_rows(w.shape[0], rb)
-    phase = w[:, 0]
-    scale = (2.0 / w.shape[0]) ** 0.5
+    neff, feff = min(nb, _MEASURE_MAX_ROWS), min(fb, _MEASURE_MAX_ROWS)
+    scale = (2.0 / feff) ** 0.5
+
+    @functools.cache
+    def operands():
+        w = _bench_rows(fb, db)
+        return _bench_rows(nb, db), w, w[:, 0], _bench_rows(fb, rb)
 
     def run(plan):
         return lambda: jax.block_until_ready(rff_project(
-            x, w, phase, u, scale=scale, interpret=interpret,
+            *operands(), scale=scale, interpret=interpret,
             precision=precision, plan=plan))
 
-    neff, feff = x.shape[0], w.shape[0]
     tiles = _RFF_TILES_INTERPRET if interpret else _RFF_TILES_TPU
     cands, costs = {}, {}
     for t in tiles:

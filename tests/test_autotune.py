@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.kernels import autotune, ops, ref
+from repro import obs
+from repro.kernels import autotune, ops, quantize, ref
+from repro.obs import metrics
 
 
 @pytest.fixture
@@ -221,6 +223,135 @@ def test_assign_plan_tag_namespaces_key(fresh_cache, monkeypatch):
             if k.startswith("assign|n256|m128|d8|interp")]
     assert len(keys) == 2
     assert sum("|ingest|" in k for k in keys) == 1
+
+
+#: Every plan function at one small interpret-mode shape (n 200 -> bucket
+#: 256, m 90 -> 128, d 16, r 4 -> 8).
+_PLANS = {
+    "gram": lambda: ops._gram_plan(200, 90, 16, "f32", True),
+    "gram_matvec": lambda: ops._matvec_plan(200, 90, 16, 4, "f32", True),
+    "shadow_assign": lambda: ops._assign_plan(200, 90, 16, True),
+    "kpca_project": lambda: ops._project_plan(200, 90, 16, 4, "f32", True),
+    "gram_row": lambda: ops._gram_row_plan(90, 16, True),
+    "rff_project": lambda: ops._rff_plan(200, 90, 16, 4, "f32", True),
+}
+
+
+@pytest.fixture
+def counting(fresh_cache, monkeypatch):
+    """Counts the plan cache's hits and misses and every measurement
+    operand ``ops._bench_rows`` builds (as (n, d) pairs)."""
+    built = []
+    real = ops._bench_rows
+
+    def bench_rows(n, d):
+        built.append((n, d))
+        return real(n, d)
+
+    monkeypatch.setattr(ops, "_bench_rows", bench_rows)
+    metrics.clear()
+    obs.enable()
+    yield built
+    obs.disable()
+    metrics.clear()
+
+
+@pytest.mark.parametrize("op", sorted(_PLANS))
+def test_plan_cache_hit_builds_no_operands(counting, op):
+    """A plan the cache holds is answered with a key and a dict read: the
+    lookup builds no measurement operand, only a miss does."""
+    hits = metrics.counter("autotune.plan_hits")
+    misses = metrics.counter("autotune.plan_misses")
+    first = _PLANS[op]()
+    assert counting and misses.value == 1 and hits.value == 0
+    n_built = len(counting)
+    assert _PLANS[op]() == first
+    assert len(counting) == n_built
+    assert (hits.value, misses.value) == (1, 1)
+
+
+def _deterministic_measure(monkeypatch):
+    """Replace the timing with one run of each candidate and fixed times by
+    candidate order, so a winner depends only on the candidates and costs."""
+    def measure(key, candidates):
+        for thunk in candidates.values():
+            thunk()
+        return {name: (i + 1) * 1e-3 for i, name in enumerate(candidates)}
+
+    monkeypatch.setattr(autotune, "_measure", measure)
+
+
+@pytest.mark.parametrize("op,precision", [("kpca_project", "f32"),
+                                          ("kpca_project", "int8"),
+                                          ("shadow_assign", "f32")])
+def test_plan_miss_measures_the_eager_operands(fresh_cache, monkeypatch, op,
+                                               precision):
+    """A miss keys, builds and costs what the plan functions built eagerly
+    before they built lazily: the operands from ``_bench_rows`` at the
+    buckets, the same candidates and costs, so the same winner under the
+    same cache key (a persisted entry keeps answering the same plan)."""
+    _deterministic_measure(monkeypatch)
+    nb, mb, db, rb = 256, 128, 16, 8
+    x, c = ops._bench_rows(nb, db), ops._bench_rows(mb, db)
+    a = ops._bench_rows(c.shape[0], rb)
+    seen, costs_seen = [], []
+    real_op = getattr(ops, op)
+
+    def recording_op(*args, **kwargs):
+        seen.append((args, kwargs))
+        return real_op(*args, **kwargs)
+
+    monkeypatch.setattr(ops, op, recording_op)
+    real_roofline = autotune.best_roofline
+
+    def recording_roofline(key, candidates, costs, default):
+        costs_seen.append(costs)
+        return real_roofline(key, candidates, costs, default)
+
+    monkeypatch.setattr(autotune, "best_roofline", recording_roofline)
+    if op == "kpca_project":
+        winner = ops._project_plan(200, 90, 16, 4, precision, True)
+        key = f"project|n{nb}|m{mb}|d{db}|r{rb}|{precision}|interp"
+        names = [f"pallas:{t}" for t in ops._PROJECT_TILES_INTERPRET]
+        want_ops = (x, c, a)
+        costs = {n: ops._project_costs(
+            x.shape[0], c.shape[0], db, rb,
+            min(int(n.split(":")[1]), ops._round_up(x.shape[0], 128)),
+            dense=False, precision=precision) for n in names}
+        costs["dense"] = ops._project_costs(x.shape[0], c.shape[0], db, rb,
+                                            0, dense=True,
+                                            precision=precision)
+        assert costs_seen == [costs]
+        want = real_roofline("eager-reference", {n: lambda: None
+                                                 for n in costs},
+                             costs, default=names[0])
+    else:
+        winner = ops._assign_plan(200, 90, 16, True)
+        key = f"assign|n{nb}|m{mb}|d{db}|interp"
+        names = ["pallas"]
+        want_ops = (x, c)
+        want = autotune.best("eager-reference",
+                             {"pallas": lambda: None, "dense": lambda: None},
+                             default="pallas")
+    assert winner == want
+    assert autotune.qualified(key) in autotune._MEM
+    assert sorted(autotune._MEM[autotune.qualified(key)]["us"]) \
+        == sorted(names + ["dense"])
+    assert len(seen) == len(names) + 1  # one run of each candidate
+    for args, kwargs in seen:
+        assert len(args) == len(want_ops)
+        for got, ref_arr in zip(args, want_ops):
+            assert got.shape == ref_arr.shape
+            np.testing.assert_array_equal(got, ref_arr)
+        if op == "kpca_project":
+            q = kwargs["projector_q"]
+            if precision == "int8":
+                want_q = quantize.quantize_projector(a, precision)
+                for got, ref_arr in zip(q, want_q):
+                    np.testing.assert_array_equal(np.asarray(got),
+                                                  np.asarray(ref_arr))
+            else:
+                assert q is None
 
 
 @pytest.mark.parametrize("env_set", [False, True])

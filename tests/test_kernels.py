@@ -111,6 +111,29 @@ def test_kpca_project_sweep(n, m, d, r):
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
 
 
+@pytest.mark.parametrize("precision", ["f32", "int8"])
+def test_kpca_project_dense_pads_no_kernel_operand(monkeypatch, precision):
+    """The dense plan reads the caller's centers and projector as they are:
+    it builds none of the Pallas kernel's padded operands, which the
+    kernel's plan does build."""
+    padded = []
+    real = ops._pad_rows
+
+    def pad_rows(a, mult, value=0.0):
+        padded.append(a.shape)
+        return real(a, mult, value)
+
+    monkeypatch.setattr(ops, "_pad_rows", pad_rows)
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(100, 16)).astype(np.float32)
+    c = rng.normal(size=(37, 16)).astype(np.float32)
+    a = rng.normal(size=(37, 5)).astype(np.float32)
+    ops.kpca_project(x, c, a, sigma=2.0, precision=precision, plan="dense")
+    assert padded == []
+    ops.kpca_project(x, c, a, sigma=2.0, precision=precision, plan="pallas")
+    assert c.shape in padded
+
+
 DISPATCH_SHAPES = [(64, 16, 8), (100, 37, 24), (513, 129, 16), (1000, 7, 96)]
 
 
