@@ -24,11 +24,11 @@ Two scales share one child template:
 
 The timed region includes chunk generation (``common.timeit_stream``
 semantics: feeding the pipeline IS the workload) and the Algorithm 1 fit.
-Warmup runs a 2-chunk source of the same chunk shape (compiles the
-selection/feed/fit programs) and then drives a throwaway ``StreamingMerge``
-through every pow2 bucket up to the center budget, so the merge-path
-compilations and allocator high-water land BEFORE the RSS baseline — the
-sampled peak measures data-path growth, not one-time jit arenas.
+Warmup runs a 2-chunk source of the same chunk shape, which compiles the
+selection, merge, feed and fit programs (the merge state has one capacity
+for a budgeted stream), so compilations and allocator high-water land
+BEFORE the RSS baseline — the sampled peak measures data-path growth, not
+one-time jit arenas.
 """
 from __future__ import annotations
 
@@ -67,28 +67,11 @@ def run(src):
     box["out"] = ingest_fit(src, ker, 8, ell=ell, block=block,
                             budget=budget, mesh=mesh)
 
-# warmup 1: 2 chunks of the same shape compile the selection/feed/fit
+# warmup: 2 chunks of the same shape compile the selection/merge/feed/fit
 # programs and autotune plans; the timed run then measures the pipeline
 timeit_stream(
     lambda: ChunkedDataset("pendigits", n=2 * chunk, chunk=chunk, seed=0),
     run, repeat=1, warmup=0)
-# warmup 2: merge shape sweep.  The host merge recompiles (and the XLA CPU
-# allocator grows) at every pow2 bucket the merged set passes through on
-# its way to ``budget``; drive a throwaway merge through the whole bucket
-# ladder NOW — widely-spread random candidates all survive selection — so
-# the RSS baseline below sits above the one-time compilation high-water
-# and the sampled delta measures the DATA path, not jit arenas.
-import numpy as np
-from repro.core.shadow import StreamingMerge
-sweep = StreamingMerge(16, ker.epsilon(ell), budget=budget, block=block)
-rng = np.random.default_rng(0)
-while sweep.m < budget:
-    sweep.update(rng.uniform(0, 1e3, (8192, 16)).astype(np.float32),
-                 np.ones(8192))
-for _ in range(2):  # and the over-budget spill path
-    sweep.update(rng.uniform(0, 1e3, (8192, 16)).astype(np.float32),
-                 np.ones(8192))
-del sweep
 import gc
 gc.collect()
 rss = RssSampler().start()

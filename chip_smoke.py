@@ -422,6 +422,10 @@ def phase_sharded(mesh, n=1 << 20, chunk=65536, budget=32768, ell=3.0,
     stats = [d.memory_stats() for d in mesh.devices.flat]
     if all(stats):  # the TPU runtime reports them; the CPU backend does not
         peaks = [st["peak_bytes_in_use"] for st in stats]
+        rest = sorted(peaks[1:])
+        log(f"sharded ingest peak bytes per device: {peaks}; device 0 / "
+            f"median of the others = "
+            f"{peaks[0] / max(rest[len(rest) // 2], 1):.3f}")
         floor = chunk // ndev * ds.d * 4
         check("sharded ingest spans devices", min(peaks) >= floor,
               f"peak bytes per device {peaks} >= one chunk shard {floor}")

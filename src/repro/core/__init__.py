@@ -7,7 +7,7 @@ from repro.core.kernels_math import (  # noqa: F401
     pairwise_sq_dists, kde, rsde_eval,
 )
 from repro.core.shadow import (  # noqa: F401
-    StreamingMerge, shadow_select, shadow_select_np, shadow_select_host,
+    shadow_select, shadow_select_np, shadow_select_host,
     shadow_select_blocked, shadow_select_streaming, two_level_merge,
 )
 from repro.core.rsde import (  # noqa: F401

@@ -74,16 +74,14 @@ def _two_level_select(x: Array, valid: Array, eps: Array, mesh: Mesh,
 def _chunk_select_sharded(xp: Array, valid: Array, eps2: Array, mesh: Mesh,
                           axis: str, block: int):
     """Level-1 BLOCKED selection on one ingest chunk, rows sharded over
-    ``axis`` — the per-chunk device step of the out-of-core pipeline
-    (core/ingest_pipeline.py, DESIGN.md §9).
+    ``axis`` — the first device program of each chunk of the out-of-core
+    pipeline (core/ingest_pipeline.py, DESIGN.md §9).
 
-    Unlike ``_two_level_select`` this neither all-gathers nor merges: each
-    device runs the fused blocked-selection while_loop on its local rows and
-    the padded per-device (c, w) buffers come back still row-sharded (only
-    selected rows carry weight; the host-side ``StreamingMerge`` is the
-    level 2, shared across every chunk of the stream).  An all-invalid shard
-    (ragged final chunk confined to few devices) exits its loop immediately
-    with zero survivors — zero-weight rows are the merge's padding contract.
+    Each device runs the fused blocked-selection while_loop on its local
+    rows; the padded per-device (c, w) buffers stay row-sharded on the
+    device for ``_merge_chunk_sharded``.  An all-invalid shard (ragged
+    final chunk confined to few devices) exits its loop immediately with
+    zero candidates — zero-weight rows are the merge's padding contract.
     """
 
     def level1(x_loc, v_loc):
@@ -95,6 +93,25 @@ def _chunk_select_sharded(xp: Array, valid: Array, eps2: Array, mesh: Mesh,
         level1, mesh=mesh, in_specs=(P(axis, None), P(axis)),
         out_specs=(P(axis, None), P(axis)), check_vma=False,
     )(xp, valid)
+
+
+@partial(jax.jit, static_argnames=("mesh", "axis", "block", "budget"))
+def _merge_chunk_sharded(state: dict, c1: Array, w1: Array, eps2: Array,
+                         mesh: Mesh, axis: str, block: int,
+                         budget: int | None) -> dict:
+    """The level-2 merge of one chunk on a mesh: every device all-gathers
+    the devices' fixed-shape candidate buckets and folds them, in device
+    order, into its replica of the merge state
+    (``shadow.merge_chunk_local``), so the
+    merged set, its int32 masses and the counters stay replicated on the
+    devices and nothing comes back to the host."""
+    spec = {k: P() for k in state}
+    return jax.shard_map(
+        lambda st, c, w: shadow_mod.merge_chunk_local(
+            st, c, w, eps2, block, budget, axis),
+        mesh=mesh, in_specs=(spec, P(axis, None), P(axis)), out_specs=spec,
+        check_vma=False,
+    )(state, c1, w1)
 
 
 def distributed_shadow_rsde(x, kernel: Kernel, ell: float, mesh: Mesh,
@@ -329,16 +346,20 @@ def _fit_rskpca_sharded(c: Array, w: Array, n: Array, kernel: Kernel,
 def fit_rskpca_sharded(centers, weights, n: int, kernel: Kernel, rank: int,
                        mesh: Mesh, axis: str = "data",
                        lobpcg_min_m: int | None = None,
-                       matfree: bool | None = None):
+                       matfree: bool | None = None, m: int | None = None):
     """Sharded Algorithm 1 core: returns (eigvals (rank,), projector (m, r)).
 
-    Centers are padded to a device multiple with zero-weight rows (harmless:
-    they contribute zero rows/columns to K-tilde and zero projector rows)
-    and the padding is stripped before returning.  ``lobpcg_min_m`` is a
-    test hook to force the distributed-matvec eigensolve at small m.
-    ``matfree`` (None = the bytes-budget policy of kernels.ops.matfree_fit)
-    skips the sharded Gram assembly entirely and streams matvec row-tiles
-    through the fused Pallas kernel per device (DESIGN.md §6).
+    ``centers``/``weights`` are an exact host (m, d) set, or a device buffer
+    whose first ``m`` rows are live (the streaming merge's replicated
+    state).  Either way they are cut or zero-padded to the power-of-two
+    capacity bucket of m, as ``pipeline.fit_centers`` does (zero-weight
+    rows contribute zero K-tilde rows/columns and zero projector rows), so
+    one compile serves every m in a bucket; the padding is stripped on the
+    host.  ``lobpcg_min_m`` is a test hook to force the distributed-matvec
+    eigensolve at small m.  ``matfree`` (None = the bytes-budget policy of
+    kernels.ops.matfree_fit) skips the sharded Gram assembly entirely and
+    streams matvec row-tiles through the fused Pallas kernel per device
+    (DESIGN.md §6).
 
     On CPU, small-m eigensolves hop to the same LAPACK subset driver the
     single-device fit uses (rskpca._host_subset_eigh) — same solver on both
@@ -347,26 +368,32 @@ def fit_rskpca_sharded(centers, weights, n: int, kernel: Kernel, rank: int,
     from repro.core.rskpca import (_LOBPCG_MIN_M, _fold_projector,
                                    _host_subset_eigh, _use_matfree)
 
-    c = jnp.asarray(centers, jnp.float32)
-    w = jnp.asarray(weights, jnp.float32)
-    m = c.shape[0]
+    m = int(np.shape(centers)[0] if m is None else m)
     ndev = mesh.shape[axis]
-    cp = _pad_rows(c, ndev)
-    wp = _pad_rows(w, ndev)
+    cap = -(-shadow_mod._pow2_ceil(max(m, 128)) // ndev) * ndev
+    if isinstance(centers, jax.Array) and centers.shape[0] >= cap:
+        cp = centers[:cap].astype(jnp.float32)
+        wp = weights[:cap].astype(jnp.float32)
+    else:  # an exact host set: pad it on the host, one transfer
+        cp = np.zeros((cap, np.shape(centers)[1]), np.float32)
+        cp[:m] = np.asarray(centers)[:m]
+        wp = np.zeros((cap,), np.float32)
+        wp[:m] = np.asarray(weights)[:m]
+        cp, wp = jnp.asarray(cp), jnp.asarray(wp)
     min_m = _LOBPCG_MIN_M if lobpcg_min_m is None else int(lobpcg_min_m)
-    use_mf = _use_matfree(kernel, cp.shape[0], rank, matfree)
+    use_mf = _use_matfree(kernel, cap, rank, matfree)
     if (not use_mf and jax.default_backend() == "cpu"
-            and cp.shape[0] <= min_m):
+            and cap <= min_m):
         kt = np.asarray(_sharded_wgram_jit(cp, wp, kernel, mesh, axis)) \
             / np.float32(n)
         top = _host_subset_eigh(kt, rank)
         if top is not None:
             lam, proj = _fold_projector(*top, np.asarray(wp), n)
-            return jnp.asarray(lam), jnp.asarray(proj[:m])
+            return np.asarray(lam), np.asarray(proj)[:m]
     lam, proj = _fit_rskpca_sharded(
         cp, wp, jnp.float32(n), kernel, rank, mesh, axis, min_m,
         matfree=use_mf)
-    return lam, proj[:m]
+    return np.asarray(lam), np.asarray(proj)[:m]
 
 
 # --------------------------------------------------------------------------

@@ -13,12 +13,16 @@ resident.  This pipeline removes that assumption end to end:
     records the measured copy/compute overlap fraction;
   * per chunk, selection runs the fused ``_blocked_select_device`` rounds —
     on a mesh, per device shard via ``distributed._chunk_select_sharded`` —
-    and the resulting candidate centers fold into a ``StreamingMerge``
-    (weight-exact, center-budget spill; cover radius 2*eps, so the §5 bounds
-    hold with ell -> ell/2);
-  * the merged center set feeds Algorithm 1 directly (``pipeline.fit_centers``
-    single-device, ``fit_rskpca_sharded`` via ``fit_rskpca(mesh=...)``) — the
-    dataset is touched exactly once.
+    and a second device program folds the candidate centers into the
+    streaming merge (``shadow.merge_chunk_local``: weight-exact int32
+    masses, center-budget spill; cover radius 2*eps, so the §5 bounds hold
+    with ell -> ell/2).  The merge state lives on the device(s) at a fixed
+    capacity; on a mesh the candidates are all-gathered and the state is
+    replicated.  The host fetches nothing per chunk: the merged set comes
+    back once per stream, or at a checkpoint;
+  * the merged center set feeds Algorithm 1 straight from the device
+    (``pipeline.fit_centers`` single-device, ``fit_rskpca_sharded`` on a
+    mesh), both bucketed by capacity — the dataset is touched exactly once.
 
 This module deliberately takes ANY chunk source (``.chunks()`` method or a
 bare iterable of ``(x, n_valid)``) so it never imports ``repro.data``; the
@@ -26,6 +30,7 @@ deterministic synthetic source lives in ``data.kpca_datasets.ChunkedDataset``.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import queue
 import threading
@@ -38,8 +43,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import shadow as shadow_mod
 from repro.core.rsde import RSDE
-from repro.core.shadow import StreamingMerge
+from repro.core.rskpca import KPCAModel
 from repro.obs import metrics as _om
+from repro.obs import trace as _trace
 from repro.obs.trace import span as _span
 from repro.runtime import chaos
 from repro.runtime.fault import Preempted, RetryPolicy, retry_call
@@ -109,6 +115,16 @@ class IngestStats:
     wall_s: float = 0.0       # end-to-end select -> fit
     spilled: int = 0
     max_spill_dist: float = 0.0
+    # the streaming merge's counters, fetched once per stream: candidates
+    # gathered, absorbed by the merged set, kept as new centers; the merge
+    # state's capacity; the distance pairs its absorb and reselect passes
+    # needed at least (bench/costs/ingest_merge.py reads them)
+    gathered: int = 0
+    absorbed: int = 0
+    kept: int = 0
+    cap: int = 0
+    absorb_pairs: float = 0.0
+    reselect_pairs: float = 0.0
 
     @property
     def overlap_fraction(self) -> float:
@@ -207,7 +223,8 @@ class _PrefetchFeed:
     def __iter__(self):
         while True:
             t0 = time.perf_counter()
-            item = self._q.get()
+            with _span("ingest.feed_wait"):
+                item = self._q.get()
             self._stats.stall_s += time.perf_counter() - t0
             if item is _END:
                 self._t.join()
@@ -240,7 +257,17 @@ def _chunk_iter(source, start: int = 0):
 #: ``ChunkedDataset`` is a seed, so its "RNG state" is exactly the cursor.
 def _ckpt_template(d: int) -> dict:
     return {
-        "merge": StreamingMerge(d, 1.0).state_template(),
+        "merge": {"centers": np.zeros((0, d), np.float32),
+                  "weights": np.zeros((0,), np.int64),
+                  "counters": np.zeros((len(shadow_mod.MERGE_COUNTERS),),
+                                       np.int64),
+                  "pairs": np.zeros((len(shadow_mod.MERGE_PAIRS),),
+                                    np.float32)},
+        # the merge state's rows and the host's bound on its m: the
+        # capacity a resumed stream continues with, as the uninterrupted
+        # one does (shadow.merge_reserve)
+        "cap": np.asarray(0, np.int64),
+        "bound": np.asarray(0, np.int64),
         "cursor": np.asarray(0, np.int64),      # chunks fully ingested
         "rows": np.asarray(0, np.int64),        # valid rows ingested
         "chunks": np.asarray(0, np.int64),      # == cursor (stats mirror)
@@ -277,7 +304,7 @@ def select_streaming(source, eps: float, *, block: int = 256,
       eps: shadow radius sigma/ell.
       block: candidate batch size of the blocked selector.
       budget: cap on merged centers (over-budget mass spills weight-exactly
-        to the nearest retained center; see ``StreamingMerge``).
+        to the nearest retained center; see ``shadow.merge_chunk_local``).
       mesh: optional device mesh — each chunk's rows shard over ``axis`` and
         every device runs selection on its local rows; chunk size must then
         divide the axis size.
@@ -300,17 +327,37 @@ def select_streaming(source, eps: float, *, block: int = 256,
         flags here and counts into ``ingest.stragglers``.
 
     Returns ``(RSDE(scheme="shadow-ingest"), IngestStats)``.  Weights are
-    float64 and sum EXACTLY to the number of ingested rows; cover radius is
-    2*eps like every two-level path.
+    int32 on the device, float64 here, and sum EXACTLY to the number of
+    ingested rows; cover radius is 2*eps like every two-level path.
     """
+    rsde, stats, _ = _select_streaming(
+        source, eps, block=block, budget=budget, mesh=mesh, axis=axis,
+        prefetch=prefetch, checkpoint_dir=checkpoint_dir,
+        checkpoint_every=checkpoint_every, resume=resume, guard=guard,
+        watchdog=watchdog)
+    return rsde, stats
+
+
+def _select_streaming(source, eps: float, *, block: int = 256,
+                      budget: int | None = None, mesh=None,
+                      axis: str = "data", prefetch: int = 2,
+                      checkpoint_dir: str | None = None,
+                      checkpoint_every: int = 0, resume: bool = False,
+                      guard=None, watchdog=None):
+    """``select_streaming``, also returning the merge state still on the
+    device(s) — what ``ingest_fit`` hands to the fit."""
     stats = IngestStats()
     t_start = time.perf_counter()
+    if budget is not None and budget < 1:
+        raise ValueError(f"center budget {budget} leaves nowhere to spill")
     eps2 = jnp.float32(eps) ** 2
     stop0 = jnp.asarray(0, jnp.int32)
     ndev = 1 if mesh is None else mesh.shape[axis]
     if mesh is not None:
+        from repro.core import distributed
         x_shard = NamedSharding(mesh, P(axis, None))
         v_shard = NamedSharding(mesh, P(axis))
+        replicated = NamedSharding(mesh, P())
 
         def place(x, n_valid):
             assert x.shape[0] % ndev == 0, \
@@ -318,12 +365,37 @@ def select_streaming(source, eps: float, *, block: int = 256,
             ok = np.arange(x.shape[0]) < n_valid
             return (jax.device_put(x, x_shard),
                     jax.device_put(ok, v_shard), int(n_valid))
+
+        def on_devices(state):
+            return jax.device_put(state, replicated)
+
+        def step(state, xd, okd, b):
+            c1, w1 = distributed._chunk_select_sharded(xd, okd, eps2, mesh,
+                                                       axis, b)
+            with _span("ingest.merge"):
+                return distributed._merge_chunk_sharded(
+                    state, c1, w1, eps2, mesh, axis, block, budget)
     else:
         def place(x, n_valid):
             ok = np.arange(x.shape[0]) < n_valid
             return jax.device_put(x), jax.device_put(ok), int(n_valid)
 
-    merge: StreamingMerge | None = None
+        def on_devices(state):
+            return state
+
+        def step(state, xd, okd, b):
+            _, c1, w1, _, _ = shadow_mod._blocked_select_device(
+                xd, eps2, b, okd, stop0)
+            with _span("ingest.merge"):
+                return shadow_mod._merge_chunk(state, c1, w1, eps2, block,
+                                               budget)
+
+    # The merge state lives on the device(s).  Its capacity must hold
+    # every center the stream can keep: ``budget``'s bucket when one is set
+    # (one capacity for the whole stream, whatever m the data gives), else
+    # m and a chunk, m fetched only as the host's bound on it outgrows the
+    # state (shadow.merge_reserve).
+    state, bound = None, 0
     cursor = 0  # chunks FULLY ingested == resume start == checkpoint step
     if resume and checkpoint_dir is not None:
         d = getattr(source, "d", None)
@@ -332,12 +404,12 @@ def select_streaming(source, eps: float, *, block: int = 256,
             "a bare iterable cannot be replayed from a cursor"
         tree, ck_step = _ckpt_restore(checkpoint_dir, int(d))
         if tree is not None:
-            merge = StreamingMerge(int(d), eps, budget=budget, block=block)
-            merge.load_state(tree["merge"])
             cursor = int(tree["cursor"])
             stats.chunks = int(tree["chunks"])
             stats.rows = int(tree["rows"])
-            stats.m = merge.m
+            bound = int(tree["bound"])
+            state = on_devices(shadow_mod.merge_from_host(
+                tree["merge"], int(tree["cap"])))
             if _om.enabled():
                 _M_RESUMES.inc()
 
@@ -347,9 +419,13 @@ def select_streaming(source, eps: float, *, block: int = 256,
         The merge state is the ONLY accumulator and ``cursor`` replays the
         source (row i of a ChunkedDataset depends only on (name, seed, i)),
         so this pair IS crash consistency: resume == uninterrupted, bitwise.
+        The checkpoint is the one fetch of the merge state before the
+        stream ends.
         """
         from repro.checkpoint import store
-        tree = {"merge": merge.state(),
+        tree = {"merge": shadow_mod.merge_to_host(state),
+                "cap": np.asarray(state["c"].shape[0], np.int64),
+                "bound": np.asarray(bound, np.int64),
                 "cursor": np.asarray(cursor, np.int64),
                 "rows": np.asarray(stats.rows, np.int64),
                 "chunks": np.asarray(stats.chunks, np.int64)}
@@ -361,12 +437,16 @@ def select_streaming(source, eps: float, *, block: int = 256,
 
     feed = _PrefetchFeed(_chunk_iter(source, start=cursor), place, stats,
                          depth=prefetch)
+    # the states of the chunks dispatched and maybe not yet run: the loop
+    # waits (fetching nothing) for the oldest past ``prefetch``, so the
+    # chunks the device holds stay O(prefetch) however long the stream
+    in_flight: collections.deque = collections.deque()
     for xd, okd, n_valid in feed:
         if guard is not None and guard.should_stop:
             # drain: persist at the last FULLY ingested chunk, stop the
             # producer thread, and hand the resume step to the caller —
             # the pulled-but-unprocessed chunk is regenerated on resume.
-            if checkpoint_dir is not None and merge is not None:
+            if checkpoint_dir is not None and state is not None:
                 _save_ckpt()
             feed.close()
             raise Preempted(f"preempted at chunk {cursor}", step=cursor)
@@ -376,35 +456,28 @@ def select_streaming(source, eps: float, *, block: int = 256,
         t0 = time.perf_counter()
         with _span("ingest.select_chunk", chunk=stats.chunks,
                    rows=int(n_valid)):
-            if merge is None:
-                merge = StreamingMerge(xd.shape[1], eps, budget=budget,
-                                       block=block)
+            cap, bound = shadow_mod.merge_reserve(state, bound,
+                                                  max(n_valid, 1), budget)
+            if state is None:
+                state = on_devices(shadow_mod.merge_init(xd.shape[1], cap))
+            elif cap > state["c"].shape[0]:
+                state = on_devices(shadow_mod.merge_grow(state, cap))
             b = max(1, min(block, xd.shape[0] // ndev))
-            if mesh is not None:
-                from repro.core.distributed import _chunk_select_sharded
-                c, w = _chunk_select_sharded(xd, okd, eps2, mesh, axis, b)
-            else:
-                _, c, w, _, _ = shadow_mod._blocked_select_device(
-                    xd, eps2, b, okd, stop0)
-            # np.asarray blocks until the device round finishes — compute_s
-            # is true select+merge time, which is what overlap compares
-            # feed_s to
-            ch, wh = np.asarray(c), np.asarray(w)
 
             def fold():
-                # inject BEFORE the non-idempotent merge.update: a
-                # transient here retries safely because the mutation has
-                # not happened yet on the failed attempt
+                # inject BEFORE the merge: the state is replaced only when
+                # the step returns, so a transient here retries safely
                 chaos.inject("ingest.merge")
-                with _span("ingest.merge"):
-                    merge.update(ch, wh)
+                return step(state, xd, okd, b)
 
-            retry_call(fold, key=f"merge{cursor}")
+            state = retry_call(fold, key=f"merge{cursor}")
+            in_flight.append(state["cnt"])
+            if len(in_flight) > max(1, prefetch):
+                in_flight.popleft().block_until_ready()
         cursor += 1
         stats.chunks += 1
         stats.rows += n_valid
         stats.compute_s += time.perf_counter() - t0
-        stats.m = merge.m
         if watchdog is not None:
             watchdog.stop(cursor - 1)
             if _om.enabled() and len(watchdog.flags) > flags0:
@@ -413,22 +486,32 @@ def select_streaming(source, eps: float, *, block: int = 256,
             _M_CHUNKS.inc()
             _M_ROWS.inc(n_valid)
             _M_CHUNK_MS.observe((time.perf_counter() - t0) * 1e3)
-            stats.spilled = merge.spilled
             _observe_chunk(stats)
         if checkpoint_dir is not None and checkpoint_every \
                 and cursor % checkpoint_every == 0:
             _save_ckpt()
-    if merge is None:
+    if state is None:
         raise ValueError("empty source: no chunks to ingest")
     if checkpoint_dir is not None:
         _save_ckpt()  # final: a resume of a finished run is a no-op replay
+    out = shadow_mod.merge_to_host(state)  # the stream's one fetch
+    cnt = dict(zip(shadow_mod.MERGE_COUNTERS, out["counters"].tolist()))
+    pairs = dict(zip(shadow_mod.MERGE_PAIRS, out["pairs"].tolist()))
     stats.select_s = time.perf_counter() - t_start
-    stats.m = merge.m
-    stats.spilled = merge.spilled
-    stats.max_spill_dist = merge.max_spill_dist
-    rsde = RSDE(centers=merge.centers, weights=merge.weights, n=stats.rows,
+    stats.m = cnt["m"]
+    stats.spilled = cnt["spilled"]
+    stats.max_spill_dist = float(np.sqrt(pairs["spill_d2"]))
+    stats.gathered, stats.absorbed, stats.kept = \
+        cnt["gathered"], cnt["absorbed"], cnt["kept"]
+    stats.cap = int(state["c"].shape[0])
+    stats.absorb_pairs = pairs["absorb_pairs"]
+    stats.reselect_pairs = pairs["reselect_pairs"]
+    if _om.enabled():
+        _observe_chunk(stats)
+    rsde = RSDE(centers=out["centers"],
+                weights=out["weights"].astype(np.float64), n=stats.rows,
                 assign=None, scheme="shadow-ingest")
-    return rsde, stats
+    return rsde, stats, state
 
 
 def ingest_fit(source, kernel, rank: int, *, ell: float = 4.0,
@@ -443,7 +526,9 @@ def ingest_fit(source, kernel, rank: int, *, ell: float = 4.0,
     Streams ``source`` through ``select_streaming`` (eps = sigma/ell via
     ``kernel.epsilon``), then fits Algorithm 1 on the merged centers —
     ``pipeline.fit_centers`` on one device, the sharded/matrix-free fit when
-    ``mesh`` is given.  Returns ``(KPCAModel, IngestStats)``; the dataset is
+    ``mesh`` is given, both fed the merged set from the device and both
+    bucketed by capacity.  Returns ``(KPCAModel, IngestStats)``, the model
+    carrying the merged masses (``weights``, float64, exact); the dataset is
     generated, staged, and read exactly once.  The fault-tolerance knobs
     (``checkpoint_dir``/``checkpoint_every``/``resume``/``guard``/
     ``watchdog``) pass straight through to ``select_streaming`` — the fit
@@ -451,27 +536,40 @@ def ingest_fit(source, kernel, rank: int, *, ell: float = 4.0,
     crash consistency covers the whole front door.
     """
     from repro.core.pipeline import fit_centers
-    from repro.core.rskpca import fit_rskpca
 
     t0 = time.perf_counter()
-    with _span("ingest.select"):
-        rsde, stats = select_streaming(
+    with _span("ingest.select") as sp:
+        rsde, stats, state = _select_streaming(
             source, kernel.epsilon(ell), block=block, budget=budget,
             mesh=mesh, axis=axis, prefetch=prefetch,
             checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every, resume=resume,
             guard=guard, watchdog=watchdog)
+        if _trace.profiling():
+            sp.set(m=stats.m, cap=stats.cap, gathered=stats.gathered,
+                   absorbed=stats.absorbed, kept=stats.kept,
+                   spilled=stats.spilled, absorb_pairs=stats.absorb_pairs,
+                   reselect_pairs=stats.reselect_pairs,
+                   d=rsde.centers.shape[1])
     t1 = time.perf_counter()
+    # the merged set goes to the fit from the device; masses beyond 2^24
+    # round in the fit's float32 operator only, never in the RSDE's
+    w = state["w"].astype(jnp.float32)
     with _span("ingest.fit", m=rsde.m) as sp:
         if mesh is None:
-            model = fit_centers(rsde.centers, rsde.weights, rsde.n, kernel,
-                                rank, matfree=matfree,
+            model = fit_centers(state["c"], w, rsde.n, kernel, rank,
+                                m=rsde.m, matfree=matfree,
                                 method="rskpca+shadow-ingest")
         else:
-            model = fit_rskpca(rsde, kernel, rank, mesh=mesh, axis=axis,
-                               matfree=matfree)
-            model = dataclasses.replace(model, method="rskpca+shadow-ingest")
+            from repro.core.distributed import fit_rskpca_sharded
+            lam, proj = fit_rskpca_sharded(
+                state["c"], w, rsde.n, kernel, rank, mesh, axis=axis,
+                matfree=matfree, m=rsde.m)
+            model = KPCAModel(kernel=kernel, centers=rsde.centers,
+                              projector=proj, eigvals=lam,
+                              method="rskpca+shadow-ingest")
         sp.sync(model.projector)
+    model = dataclasses.replace(model, weights=rsde.weights)
     stats.fit_s = time.perf_counter() - t1
     stats.wall_s = time.perf_counter() - t0
     if _om.enabled():

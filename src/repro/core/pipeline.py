@@ -49,11 +49,11 @@ def fit_centers(centers, weights, n: int, kernel: Kernel, rank: int, *,
     (``ingest_pipeline.ingest_fit``) pipelines.
 
     ``centers``/``weights`` may be a device buffer with ``m`` live rows (the
-    fused selector's preallocated (n, d) output, sliced here without a host
-    round-trip) or an exact host (m, d) set (the streaming merge's).  Either
-    way they are sliced/zero-padded to the power-of-two capacity bucket —
-    zero-weight rows contribute zero K-tilde rows/columns and zero projector
-    rows — so re-jit count stays logarithmic across m.  The cap slices are
+    fused selector's preallocated (n, d) output, or the streaming merge's
+    state, sliced here without a host round-trip) or an exact host (m, d)
+    set.  Either way they are sliced/zero-padded to the power-of-two
+    capacity bucket — zero-weight rows contribute zero K-tilde rows/columns
+    and zero projector rows — so re-jit count stays logarithmic across m.  The cap slices are
     donated into the jitted device fit; ``matfree=None`` consults the
     bytes-budget crossover (above it no m x m buffer ever materializes).
 
@@ -62,31 +62,35 @@ def fit_centers(centers, weights, n: int, kernel: Kernel, rank: int, *,
     its results, with LOBPCG's iteration count (0 on ``eigh``).
     """
     with _span("fit.stage"):
-        c = jnp.asarray(centers, jnp.float32)
-        w = jnp.asarray(weights, jnp.float32)
-        m = c.shape[0] if m is None else int(m)
+        rows = np.shape(centers)[0]
+        m = rows if m is None else int(m)
         rank = min(rank, m)
-        cap = min(max(c.shape[0], 128), _pow2_ceil(max(m, 128)))
+        cap = min(max(rows, 128), _pow2_ceil(max(m, 128)))
+        if rows < cap:  # host center sets arrive exactly (m, d): pad here
+            c = np.zeros((cap, np.shape(centers)[1]), np.float32)
+            c[:rows] = np.asarray(centers, np.float32)
+            w = np.zeros((cap,), np.float32)
+            w[:rows] = np.asarray(weights, np.float32)
+            c, w = jnp.asarray(c), jnp.asarray(w)
+        else:  # a device buffer with m live rows: its cap-row prefix
+            c = jnp.asarray(centers, jnp.float32)[:cap]
+            w = jnp.asarray(weights, jnp.float32)[:cap]
         # materialize the model's center rows BEFORE the fit: the cap slices
         # are donated into it, and when cap == c.shape[0] jax's full-slice
         # fast path returns `c` ITSELF — reading it after donation would hit
-        # a deleted array
-        centers_host = np.asarray(c[:m], np.float32)
-        if c.shape[0] < cap:  # host center sets arrive exactly (m, d): pad
-            c = jnp.concatenate(
-                [c, jnp.zeros((cap - c.shape[0], c.shape[1]), jnp.float32)])
-            w = jnp.concatenate(
-                [w, jnp.zeros((cap - w.shape[0],), jnp.float32)])
+        # a deleted array.  Slices by m are taken on the host: a device
+        # slice would compile once per m.
+        centers_host = np.asarray(c)[:m]
     use_mf = _use_matfree(kernel, cap, rank, matfree)
     with _span("fit.solve", m=m, cap=cap, matfree=use_mf) as sp:
         lam, proj, iters = sp.sync(_fit_rskpca_device(
-            c[:cap], w[:cap], jnp.float32(n), kernel, rank, matfree=use_mf))
+            c, w, jnp.float32(n), kernel, rank, matfree=use_mf))
         if _trace.enabled():  # the count is fetched only while tracing
             sp.set(lobpcg_iters=int(iters))
         model = KPCAModel(
             kernel=kernel,
             centers=centers_host,
-            projector=np.asarray(proj[:m]),
+            projector=np.asarray(proj)[:m],
             eigvals=np.asarray(lam),
             method=method,
         )

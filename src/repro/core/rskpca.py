@@ -56,6 +56,9 @@ class KPCAModel:
     projector: np.ndarray    # (m, r)
     eigvals: np.ndarray      # (r,) of the (normalized) reduced operator
     method: str = "rskpca"
+    #: the reduced set's masses (m,), float64, where the fit path returns
+    #: them exactly (``ingest_fit``); None elsewhere
+    weights: np.ndarray | None = None
 
     @property
     def m(self) -> int:

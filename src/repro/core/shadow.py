@@ -12,11 +12,12 @@ Implementations (DESIGN.md §3):
     up to B mutually-eps-separated candidates and absorbs all their shadows
     in ONE Pallas assignment pass, cutting sequential depth from m to ~m/B.
   * ``shadow_select_streaming`` — two-level path for data that doesn't fit in
-    device memory: per-chunk blocked selection + a ``StreamingMerge`` fold
+    device memory: per-chunk blocked selection + the streaming merge
     (cover radius degrades to 2*eps; the §5 bounds hold with ell -> ell/2).
-  * ``StreamingMerge``     — weight-exact streaming reconciliation of
-    candidate-center batches (the level-2 merge of the out-of-core ingest
-    pipeline, core/ingest_pipeline.py), with center-budget spill handling;
+  * ``merge_chunk_local``  — the streaming merge: weight-exact, on the
+    device, fixed-shape reconciliation of candidate-center buckets into a
+    merged set (the level-2 merge of the out-of-core ingest pipeline,
+    core/ingest_pipeline.py), with center-budget spill handling;
     ``two_level_merge`` remains the one-shot replicated-merge variant the
     sharded selector uses.
 
@@ -138,9 +139,10 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
     ``alive0`` lets the caller mark padding rows dead up front (the
     compaction cascade in ``shadow_select_blocked`` pads the shrunken alive
     set to a power of two so re-jits stay bounded).  ``w0`` (optional (n,)
-    f32) gives each point a MASS instead of unit count — the streaming
-    merge runs selection over weighted candidate centers, and a keeper's
-    weight is then the sum of absorbed masses rather than a point count.
+    f32 or int32) gives each point a MASS instead of unit count — the
+    streaming merge runs selection over weighted candidate centers, and a
+    keeper's weight is then the sum of absorbed masses, in ``w0``'s dtype,
+    rather than a point count.
 
     Per round (the old per-round host loop paid a host sync + numpy
     conversion per round — fusing the loop cut n=32k selection ~2x):
@@ -173,12 +175,23 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
         cand_alive = alive[cand_idx]
         cand = xf[cand_idx]                                # (B, d)
         d2c = jnp.sum((cand[:, None, :] - cand[None, :, :]) ** 2, axis=-1)
+        # candidate i < j blocks j when it is alive and within eps of it;
+        # a candidate is decided once every earlier one blocking it is
+        # (kept: j drops; all dropped: j is kept), so the loop runs as
+        # many steps as the batch's longest chain of conflicts, not
+        # ``block`` — the same keepers as a scan over the batch in order
+        blocks = (d2c < eps2) & cand_alive[:, None] & (
+            jnp.arange(block)[:, None] < jnp.arange(block)[None, :])
 
-        def pick(j, keep):
-            sep = jnp.all(jnp.where(keep, d2c[:, j] >= eps2, True))
-            return keep.at[j].set(cand_alive[j] & sep)
+        def pick(state):
+            keep, open_ = state
+            killed = jnp.any(blocks & keep[:, None], axis=0)
+            waits = jnp.any(blocks & open_[:, None], axis=0)
+            return keep | (open_ & ~killed & ~waits), open_ & ~killed & waits
 
-        keep = jax.lax.fori_loop(0, block, pick, jnp.zeros((block,), bool))
+        keep, _ = jax.lax.while_loop(
+            lambda s: s[1].any(), pick,
+            (jnp.zeros((block,), bool), cand_alive))
 
         idx, d2min = kernel_ops.shadow_assign(
             xf, cand, valid=keep.astype(jnp.float32))
@@ -195,8 +208,8 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
         d2min = d2min.at[cand_idx].set(jnp.min(d2c_kept, axis=0))
         absorbed = alive & (d2min < eps2)
         mass = jnp.where(absorbed, 1.0, 0.0) if w0 is None \
-            else jnp.where(absorbed, w0, 0.0)
-        counts = jnp.zeros((block,), jnp.float32).at[idx].add(mass)
+            else jnp.where(absorbed, w0, jnp.zeros_like(w0))
+        counts = jnp.zeros((block,), mass.dtype).at[idx].add(mass)
         kept_rank = jnp.cumsum(keep) - 1                   # rank among kept
         return cand, keep, counts, idx, absorbed, kept_rank
 
@@ -219,7 +232,7 @@ def _blocked_select_device(xf: Array, eps2: Array, block: int,
     state = (
         alive0,
         jnp.zeros((n, d), jnp.float32),
-        jnp.zeros((n,), jnp.float32),
+        jnp.zeros((n,), jnp.float32 if w0 is None else w0.dtype),
         jnp.full((n,), -1, jnp.int32),
         jnp.asarray(0, jnp.int32),
         jnp.asarray(0, jnp.int32),
@@ -325,136 +338,222 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
             assign, m)
 
 
-class StreamingMerge:
-    """Weight-exact streaming extension of ``two_level_merge`` (DESIGN.md
-    §9): reconcile candidate-center batches ONE BATCH AT A TIME instead of
-    requiring every level-1 center in memory at once.
+#: Rows of one device's level-1 candidates folded per merge step: the
+#: fixed-shape candidate bucket of the streaming merge (DESIGN.md §9).  A
+#: device with more candidates folds them in several buckets.
+MERGE_BUCKET = 8192
 
-    Per ``update(cand_c, cand_w)``:
+#: Merged-set rows per step of the merge's nearest-center scans, which loop
+#: over the live prefix only: their work follows m, not the capacity.  A
+#: step is one assignment call; few, large steps keep a traced window's
+#: device events few (a profile keeps a bounded number of them).
+MERGE_TILE = 8192
 
-    1. **Absorb** — one assignment pass of the candidates against the
-       current merged set; any candidate strictly within eps of a merged
-       center hands its mass to that center.  Duplicate centers across
-       chunk/shard boundaries land here (d2 == 0 < eps^2), so they merge
-       instead of accumulating.
-    2. **Select** — survivors (all >= eps from every merged center) run
-       WEIGHTED blocked selection among themselves, restoring pairwise
-       eps-separation; the kept centers append to the merged set.
-    3. **Spill** — if appending would exceed ``budget`` centers, the
-       over-budget keepers are instead absorbed into their nearest
-       retained center (merged set + kept prefix) regardless of distance;
-       ``spilled``/``max_spill_dist`` record how much cover quality the
-       budget cost.
+#: The int32 counters of a merge state, in order.
+MERGE_COUNTERS = ("m", "gathered", "absorbed", "kept", "spilled")
+#: Its float32 accumulators: the distance pairs the absorb and reselect
+#: passes need at least, and the largest squared spill distance.
+MERGE_PAIRS = ("absorb_pairs", "reselect_pairs", "spill_d2")
 
-    Mass bookkeeping is float64 on host, so for integer point masses the
-    invariant ``weights.sum() == total ingested mass`` holds EXACTLY up to
-    2^53 (the one-shot device merge is only exact to f32's 2^24).  Cover
-    radius of the merged set is 2*eps (triangle inequality), exactly like
-    ``two_level_merge`` — the §5 bounds hold with ell -> ell/2.
+
+def merge_capacity(bound: int, budget: int | None) -> int:
+    """Rows of a merge state that can hold ``bound`` centers (at most
+    ``budget``): a power of two, at least 128 — so the scans' tiles divide
+    it — and fixed for a budgeted stream whatever m its data gives."""
+    if budget is not None:
+        bound = min(bound, budget)
+    return max(128, _pow2_ceil(bound))
+
+
+def merge_reserve(state: dict | None, bound: int, rows: int,
+                  budget: int | None) -> tuple[int, int]:
+    """The capacity a merge state needs before a chunk of ``rows`` more
+    rows, and the host's bound on m after that chunk: ``(cap, bound)``.
+
+    ``bound`` is the host's bound on m so far (m itself stays on the
+    device).  A chunk keeps at most ``rows`` new centers, so ``bound +
+    rows`` rows always suffice.  Within a budget that is at most the
+    budget's bucket, and nothing is fetched.  Without one the capacity
+    follows m, not the rows ingested: once ``bound + rows`` passes the
+    state's rows, the host fetches the one scalar m (which waits for the
+    chunks in flight) and grows the state only if m + ``rows`` could
+    overflow it, to twice that, so the fetches stay few and the state
+    O(m + chunk)."""
+    have = 0 if state is None else state["c"].shape[0]
+    if budget is not None:
+        bound = min(bound + rows, budget)
+        return max(have, merge_capacity(bound, budget)), bound
+    if bound + rows > have and state is not None:
+        bound = int(np.asarray(state["cnt"])[0])
+    bound += rows
+    return (have if bound <= have else merge_capacity(2 * bound, None),
+            bound)
+
+
+def merge_init(d: int, cap: int) -> dict:
+    """An empty merge state of capacity ``cap``: the merged centers
+    ``c`` (cap, d) float32 and their masses ``w`` (cap,) int32, live in
+    the prefix ``[0, m)``, and the counters."""
+    return {"c": jnp.zeros((cap, d), jnp.float32),
+            "w": jnp.zeros((cap,), jnp.int32),
+            "cnt": jnp.zeros((len(MERGE_COUNTERS),), jnp.int32),
+            "pairs": jnp.zeros((len(MERGE_PAIRS),), jnp.float32)}
+
+
+def merge_grow(state: dict, cap: int) -> dict:
+    """The same state in ``cap`` rows (zero rows added; nothing fetched)."""
+    extra = cap - state["c"].shape[0]
+    if not extra:
+        return state
+    return dict(state, c=jnp.pad(state["c"], ((0, extra), (0, 0))),
+                w=jnp.pad(state["w"], (0, extra)))
+
+
+def merge_to_host(state: dict) -> dict:
+    """The state's live part on the host, in one fetch: ``centers`` (m, d)
+    float32, ``weights`` (m,) int64, ``counters`` and ``pairs``."""
+    h = jax.device_get(state)
+    m = int(h["cnt"][0])
+    return {"centers": np.array(h["c"][:m], np.float32),
+            "weights": np.array(h["w"][:m], np.int64),
+            "counters": np.array(h["cnt"], np.int64),
+            "pairs": np.array(h["pairs"], np.float32)}
+
+
+def merge_from_host(tree: dict, cap: int) -> dict:
+    """Inverse of :func:`merge_to_host`, padded to ``cap`` rows."""
+    c = np.asarray(tree["centers"], np.float32)
+    w = np.asarray(tree["weights"])
+    m, d = c.shape
+    if w.shape != (m,) or m > cap or int(tree["counters"][0]) != m:
+        raise ValueError(f"merge state of {m} centers, {w.shape[0]} masses "
+                         f"and m={int(tree['counters'][0])} does not fit "
+                         f"{cap} rows")
+    cp = np.zeros((cap, d), np.float32)
+    cp[:m] = c
+    wp = np.zeros((cap,), np.int32)
+    wp[:m] = w
+    return {"c": jnp.asarray(cp), "w": jnp.asarray(wp),
+            "cnt": jnp.asarray(np.asarray(tree["counters"], np.int32)),
+            "pairs": jnp.asarray(np.asarray(tree["pairs"], np.float32))}
+
+
+def _nearest_live(x: Array, c: Array, live: Array):
+    """Each row's nearest center among ``c[:live]``: (idx, d2), d2 = inf
+    where ``live`` is 0.  The scan steps over ``MERGE_TILE``-row tiles of
+    the live prefix only, so the work follows the live count."""
+    tile = min(MERGE_TILE, c.shape[0])
+    n = x.shape[0]
+
+    def body(state):
+        t, best_i, best_d = state
+        ct = jax.lax.dynamic_slice_in_dim(c, t * tile, tile)
+        ok = (t * tile + jnp.arange(tile)) < live
+        i, d2 = kernel_ops.shadow_assign(x, ct, valid=ok.astype(jnp.float32),
+                                         tag="ingest")
+        better = d2 < best_d
+        return (t + 1, jnp.where(better, i + t * tile, best_i),
+                jnp.where(better, d2, best_d))
+
+    tiles = (live + tile - 1) // tile
+    _, idx, d2 = jax.lax.while_loop(
+        lambda s: s[0] < tiles, body,
+        (jnp.asarray(0, jnp.int32), jnp.zeros((n,), jnp.int32),
+         jnp.full((n,), jnp.inf, jnp.float32)))
+    return idx, d2
+
+
+def _merge_fold(state: dict, xb: Array, wb: Array, eps2: Array, block: int,
+                budget: int | None) -> dict:
+    """Fold one candidate bucket ``xb`` (k, d) with int32 masses ``wb``
+    (0 marks padding) into the merged set:
+
+    1. **Absorb** — a candidate strictly within eps of its nearest merged
+       center hands its mass to it.  Duplicates across chunk and device
+       boundaries (d2 == 0) merge here.
+    2. **Reselect** — the survivors (all >= eps from the merged set) run
+       weighted blocked selection among themselves, restoring pairwise
+       eps-separation; a keeper's mass is the int32 sum it absorbed.
+    3. **Append within budget** — keepers append at ``[m, m + kept)``;
+       those over ``budget`` spill their mass to the nearest retained
+       center (merged set and the appended keepers) whatever the distance.
+
+    Masses stay int32 throughout, exact to 2^31 rows.
     """
+    c, w, cnt, pairs = state["c"], state["w"], state["cnt"], state["pairs"]
+    cap, k = c.shape[0], xb.shape[0]
+    m = cnt[0]
+    live = wb > 0
+    idx, d2 = _nearest_live(xb, c, m)
+    hit = live & (d2 < eps2)
+    w = w.at[jnp.where(hit, idx, cap)].add(wb, mode="drop")
+    surv = live & ~hit
+    _, kc, kw, _, mr = _blocked_select_device(
+        xb, eps2, max(1, min(block, k)), surv, jnp.asarray(0, jnp.int32), wb)
+    new = mr[0]
+    kept = new if budget is None else jnp.clip(budget - m, 0, new)
+    rows = jnp.arange(k)
+    pos = jnp.where(rows < kept, m + rows, cap)
+    c = c.at[pos].set(kc, mode="drop")
+    w = w.at[pos].set(kw, mode="drop")
+    spill = (rows >= kept) & (rows < new)
 
-    def __init__(self, d: int, eps: float, budget: int | None = None,
-                 block: int | None = 256):
-        self.d = int(d)
-        self.eps = float(eps)
-        self.budget = None if budget is None else int(budget)
-        self.block = 256 if block is None else int(block)
-        self._c = np.zeros((0, self.d), np.float32)
-        self._w = np.zeros((0,), np.float64)
-        self.spilled = 0
-        self.max_spill_dist = 0.0
+    def spill_into(w):
+        sidx, sd2 = _nearest_live(kc, c, m + kept)
+        return (w.at[jnp.where(spill, sidx, cap)].add(kw, mode="drop"),
+                jnp.max(jnp.where(spill, sd2, 0.0)))
 
-    @property
-    def m(self) -> int:
-        return self._c.shape[0]
+    w, sd2 = jax.lax.cond(spill.any(), spill_into,
+                          lambda w: (w, jnp.float32(0.0)), w)
+    f32 = jnp.float32
+    n_live, n_surv = live.sum(dtype=jnp.int32), surv.sum(dtype=jnp.int32)
+    cnt = cnt + jnp.stack([kept, n_live, hit.sum(dtype=jnp.int32), kept,
+                           spill.sum(dtype=jnp.int32)])
+    pairs = jnp.stack([pairs[0] + n_live.astype(f32) * m.astype(f32),
+                       pairs[1] + n_surv.astype(f32) * new.astype(f32),
+                       jnp.maximum(pairs[2], sd2)])
+    return {"c": c, "w": w, "cnt": cnt, "pairs": pairs}
 
-    @property
-    def centers(self) -> np.ndarray:
-        return self._c
 
-    @property
-    def weights(self) -> np.ndarray:
-        return self._w
+def merge_chunk_local(state: dict, c1: Array, w1: Array, eps2: Array,
+                      block: int, budget: int | None,
+                      axis: str | None = None) -> dict:
+    """Fold one chunk's level-1 output into the merge state: this device's
+    candidates ``c1`` (n_loc, d), live in the prefix where the float
+    counts ``w1`` are positive, in ``MERGE_BUCKET``-row buckets.  Under
+    ``shard_map`` over ``axis`` each bucket is all-gathered, the devices'
+    buckets concatenated in device order, and every device folds that one
+    batch, so the replicated state stays identical on every device; with
+    ``axis=None`` it is the one-device merge.  The number of buckets is
+    the largest device's ``ceil(candidates / bucket)``, on the device:
+    nothing is fetched."""
+    n_loc, d = c1.shape
+    assert n_loc < 1 << 24, "level-1 counts must be exact in float32"
+    kb = min(MERGE_BUCKET, n_loc)
+    pad = -n_loc % kb
+    w1 = w1.astype(jnp.int32)
+    if pad:
+        c1 = jnp.pad(c1, ((0, pad), (0, 0)))
+        w1 = jnp.pad(w1, (0, pad))
+    buckets = ((w1 > 0).sum(dtype=jnp.int32) + kb - 1) // kb
+    if axis is not None:
+        buckets = jax.lax.pmax(buckets, axis)
 
-    # -- crash-consistent state (DESIGN.md §17) ---------------------------
-    # The merge IS the only cross-chunk ingest state, so these two methods
-    # are the whole checkpoint/resume contract: state() -> a flat numpy
-    # tree checkpoint/store can publish atomically, load_state() -> the
-    # bit-identical merge (f32 centers and f64 masses round-trip exactly;
-    # the store's numpy-template restore preserves the f64 dtype).
+    def bucket(s, st):
+        cs = jax.lax.dynamic_slice_in_dim(c1, s * kb, kb)
+        ws = jax.lax.dynamic_slice_in_dim(w1, s * kb, kb)
+        if axis is not None:
+            cs = jax.lax.all_gather(cs, axis, tiled=True)
+            ws = jax.lax.all_gather(ws, axis, tiled=True)
+        return _merge_fold(st, cs, ws, eps2, block, budget)
 
-    def state(self) -> dict:
-        return {
-            "centers": np.array(self._c, np.float32),
-            "weights": np.array(self._w, np.float64),
-            "spilled": np.asarray(self.spilled, np.int64),
-            "max_spill_dist": np.asarray(self.max_spill_dist, np.float64),
-        }
+    return jax.lax.fori_loop(0, buckets, bucket, state)
 
-    def state_template(self) -> dict:
-        """Zero-row tree with the same structure/dtypes as :meth:`state`
-        (restore takes shapes from the checkpoint meta, not the template)."""
-        return {
-            "centers": np.zeros((0, self.d), np.float32),
-            "weights": np.zeros((0,), np.float64),
-            "spilled": np.asarray(0, np.int64),
-            "max_spill_dist": np.asarray(0.0, np.float64),
-        }
 
-    def load_state(self, tree: dict) -> None:
-        self._c = np.asarray(tree["centers"], np.float32)
-        self._w = np.asarray(tree["weights"], np.float64)
-        assert self._c.shape[1] == self.d and \
-            self._c.shape[0] == self._w.shape[0]
-        self.spilled = int(tree["spilled"])
-        self.max_spill_dist = float(tree["max_spill_dist"])
-
-    def _absorb_into(self, target_c, target_w, cand_c, cand_w, spill: bool):
-        """Assign candidates to nearest target center; within-eps (or ALL,
-        when ``spill``) hand over their mass.  Returns the survivor mask."""
-        idx, d2 = kernel_ops.shadow_assign(cand_c, target_c, tag="ingest")
-        idx, d2 = np.asarray(idx), np.asarray(d2)
-        hit = np.ones_like(idx, dtype=bool) if spill \
-            else d2 < np.float32(self.eps) ** 2
-        np.add.at(target_w, idx[hit], cand_w[hit])
-        if spill and hit.any():
-            self.spilled += int(hit.sum())
-            self.max_spill_dist = max(self.max_spill_dist,
-                                      float(np.sqrt(d2[hit].max())))
-        return ~hit
-
-    def update(self, cand_c, cand_w) -> None:
-        """Fold one batch of candidate centers (zero-weight rows are
-        padding and ignored) into the merged set."""
-        cand_c = np.asarray(cand_c, np.float32)
-        cand_w = np.asarray(cand_w, np.float64)
-        live = cand_w > 0
-        cand_c, cand_w = cand_c[live], cand_w[live]
-        if cand_c.shape[0] == 0:          # empty shard / all-padding batch
-            return
-        if self.m:
-            keep = self._absorb_into(self._c, self._w, cand_c, cand_w,
-                                     spill=False)
-            cand_c, cand_w = cand_c[keep], cand_w[keep]
-            if cand_c.shape[0] == 0:
-                return
-        c_new, w_new, _, m_new = shadow_select_blocked(
-            cand_c, self.eps, block=self.block, weights=cand_w)
-        room = m_new if self.budget is None else max(0, self.budget - self.m)
-        kept = min(m_new, room)
-        kept_c = np.asarray(c_new[:kept], np.float32)
-        kept_w = np.asarray(w_new[:kept], np.float64)
-        if kept < m_new:                  # center-budget spill
-            target_c = np.concatenate([self._c, kept_c]) if self.m else kept_c
-            target_w = np.concatenate([self._w, kept_w]) if self.m else kept_w
-            if target_c.shape[0] == 0:
-                raise ValueError("center budget is 0: nowhere to spill")
-            self._absorb_into(target_c, target_w, c_new[kept:], w_new[kept:],
-                              spill=True)
-            self._c, self._w = target_c, target_w
-        else:
-            self._c = np.concatenate([self._c, kept_c]) if self.m else kept_c
-            self._w = np.concatenate([self._w, kept_w]) if self.m else kept_w
+@partial(jax.jit, static_argnames=("block", "budget"))
+def _merge_chunk(state, c1, w1, eps2, block: int, budget: int | None):
+    """One device's merge program: :func:`merge_chunk_local` jitted."""
+    return merge_chunk_local(state, c1, w1, eps2, block, budget)
 
 
 def shadow_select_streaming(x, eps: float, chunk: int = 8192,
@@ -462,35 +561,50 @@ def shadow_select_streaming(x, eps: float, chunk: int = 8192,
     """Two-level streaming selection for out-of-memory datasets.
 
     Level 1 runs blocked selection per fixed-size chunk (only one chunk is
-    device-resident at a time); level 2 folds each chunk's centers into a
-    ``StreamingMerge`` — the merged set is the ONLY cross-chunk state, so
-    peak memory is O(chunk + m) however large n grows.  Cover radius is
-    2*eps (triangle inequality), i.e. the §5 bounds hold with ell -> ell/2;
-    the final assign map is recovered with one Pallas assignment pass per
-    chunk.  ``budget`` caps the merged center count (over-budget candidates
-    spill weight-exactly into their nearest retained center).
+    device-resident at a time); level 2 folds each chunk's centers into the
+    device-resident streaming merge (``_merge_chunk``, the same program the
+    out-of-core ingest runs) — the merged set is the ONLY cross-chunk
+    state, so peak memory is O(chunk + m) however large n grows.  Cover
+    radius is 2*eps (triangle inequality), i.e. the §5 bounds hold with
+    ell -> ell/2; the final assign map is recovered with one Pallas
+    assignment pass per chunk.  ``budget`` caps the merged center count
+    (over-budget candidates spill weight-exactly into their nearest
+    retained center).
 
     Returns (centers, weights, assign, m).  Unlike the one-level selectors,
     ``weights`` are the MERGED level-1 shadow masses while ``assign`` maps
     each point to its NEAREST merged center, so ``bincount(assign)`` need
     not equal ``weights`` — both are valid 2*eps quantizations, they just
     answer different questions (density mass vs. nearest-cover membership).
-    ``weights.sum() == n`` holds exactly (float64 mass bookkeeping).
+    ``weights.sum() == n`` holds exactly (int32 masses, float64 out).
     """
     x = np.asarray(x, np.float32)
-    n = x.shape[0]
-    merge = StreamingMerge(x.shape[1], eps, budget=budget, block=block)
+    n, d = x.shape
+    block = 256 if block is None else block
+    chunk = max(1, min(chunk, n))
+    eps2 = jnp.float32(eps) ** 2
+    state, bound = None, 0
     for s in range(0, n, chunk):
-        c, w, _, _ = shadow_select_blocked(x[s : s + chunk], eps, block=block)
-        merge.update(c, w)
-    m = merge.m
-    centers = merge.centers
+        xs = x[s: s + chunk]
+        k = xs.shape[0]
+        xp = np.zeros((chunk, d), np.float32)
+        xp[:k] = xs
+        b = max(1, min(block, chunk))
+        _, c1, w1, _, _ = _blocked_select_device(
+            jnp.asarray(xp), eps2, b, jnp.asarray(np.arange(chunk) < k),
+            jnp.asarray(0, jnp.int32))
+        cap, bound = merge_reserve(state, bound, k, budget)
+        state = merge_init(d, cap) if state is None \
+            else merge_grow(state, cap)
+        state = _merge_chunk(state, c1, w1, eps2, block, budget)
+    out = merge_to_host(state)
+    centers, m = out["centers"], out["centers"].shape[0]
     assign = np.empty((n,), np.int64)
     for s in range(0, n, chunk):
         idx, _ = kernel_ops.shadow_assign(x[s : s + chunk], centers,
                                           tag="ingest")
         assign[s : s + chunk] = np.asarray(idx)
-    return centers, merge.weights, assign, m
+    return centers, out["weights"].astype(np.float64), assign, m
 
 
 def two_level_merge(centers: Array, weights: Array, eps: Array,

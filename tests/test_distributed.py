@@ -90,6 +90,81 @@ print("OK")
 """)
 
 
+def test_ingest_fit_8dev_equals_two_level_reference():
+    """ingest_fit on an 8-device mesh: every chunk's rows split over the
+    devices, level 1 per device, the candidate buckets all-gathered and
+    folded on the device as one batch in device order.  The merged centers
+    and masses equal the plain two-level reference over the same
+    per-device split (blocked selection per shard, then the host merge of
+    each chunk's candidates), and the fit equals the single-device fit of
+    that reference set."""
+    _run_multidevice("""
+import sys
+import numpy as np
+sys.path.insert(0, @TESTS@)
+from merge_reference import StreamingMerge
+from repro.core import fit_centers, gaussian
+from repro.core.ingest_pipeline import ingest_fit, pad_block
+from repro.core.shadow import shadow_select_blocked
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((8,), ("data",))
+rng = np.random.default_rng(1)
+cent = rng.uniform(0, 1, (24, 5))
+n, chunk, block = 3000, 1024, 32
+x = (cent[rng.integers(0, 24, n)]
+     + 0.05 * rng.normal(size=(n, 5))).astype(np.float32)
+ker = gaussian(0.6)
+eps = ker.epsilon(3.0)
+
+def chunks():
+    for s in range(0, n, chunk):
+        blk = x[s : s + chunk]
+        yield pad_block(blk, chunk)[0], blk.shape[0]
+
+model, stats = ingest_fit(chunks(), ker, 4, ell=3.0, block=block, mesh=mesh)
+ref = StreamingMerge(5, eps, block=block)
+loc = chunk // 8
+for s in range(0, n, chunk):
+    cs, ws = [np.zeros((0, 5), np.float32)], [np.zeros((0,))]
+    for j in range(8):
+        rows = x[s + j * loc : min(s + (j + 1) * loc, n)]
+        if len(rows):
+            c, w, _, m = shadow_select_blocked(rows, eps, block=block)
+            cs.append(c[:m])
+            ws.append(w[:m])
+    ref.update(np.concatenate(cs), np.concatenate(ws))
+assert stats.rows == n and stats.m == ref.m, (stats.m, ref.m)
+np.testing.assert_array_equal(model.centers, ref.centers)
+np.testing.assert_array_equal(model.weights, ref.weights)
+assert model.weights.sum() == n
+one = fit_centers(ref.centers, ref.weights, n, ker, 4)
+np.testing.assert_allclose(model.eigvals, one.eigvals, rtol=1e-4)
+print("OK")
+""".replace("@TESTS@", repr(os.path.dirname(os.path.abspath(__file__)))))
+
+
+def test_sharded_fit_compiles_once_per_capacity_bucket():
+    """Two center sets whose m fall in one power-of-two bucket run the same
+    compiled sharded fit."""
+    from repro.core import gaussian
+    from repro.core.distributed import _fit_rskpca_sharded, fit_rskpca_sharded
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
+    rng = np.random.default_rng(3)
+    ker = gaussian(1.0)
+    sizes = []
+    for m in (150, 230):
+        c = rng.normal(size=(m, 4)).astype(np.float32)
+        w = rng.integers(1, 5, m).astype(np.float64)
+        lam, proj = fit_rskpca_sharded(c, w, float(w.sum()), ker, 3, mesh,
+                                       lobpcg_min_m=64)
+        assert proj.shape == (m, 3) and np.isfinite(lam).all()
+        sizes.append(_fit_rskpca_sharded._cache_size())
+    assert sizes[1] == sizes[0]
+
+
 def test_train_step_runs_on_2x2_mesh():
     """Numerically execute one sharded train step (not just lower) on a
     (data=2, model=2) host mesh — validates the full distribution stack."""

@@ -11,7 +11,8 @@ from repro.core.ingest_pipeline import (_PrefetchFeed, IngestStats,
                                         ingest_fit, pad_block,
                                         select_streaming)
 from repro.core.pipeline import fit_shadow_fused
-from repro.core.shadow import StreamingMerge, shadow_select_blocked
+from repro.core.shadow import shadow_select_blocked
+from merge_reference import StreamingMerge
 from repro.data.kpca_datasets import ChunkedDataset
 
 
@@ -104,6 +105,26 @@ def test_multichunk_weight_exact_and_2eps_cover():
         assert np.sqrt(d2.min()) >= eps - 1e-5
 
 
+def test_unbudgeted_merge_state_follows_m_not_rows():
+    """Without a budget the device state grows with m and a chunk, not
+    with the rows ingested: a long stream over few clusters keeps a small
+    state, and its merge equals the plain host merge of the same chunks'
+    candidates, batch for batch."""
+    rng = np.random.default_rng(13)
+    centers = rng.uniform(0, 1, (6, 4))
+    x = (centers[rng.integers(0, 6, 6144)]
+         + 0.02 * rng.normal(size=(6144, 4))).astype(np.float32)
+    eps, chunk = 0.2, 128
+    rsde, stats = select_streaming(_chunks_of(x, chunk), eps, block=32)
+    assert stats.chunks == 48 and rsde.weights.sum() == len(x)
+    assert stats.cap <= 4 * (stats.m + chunk) and stats.cap < len(x) // 4
+    ref = StreamingMerge(x.shape[1], eps, block=32)
+    for c, w in _candidate_batches(x, eps, chunk):
+        ref.update(c, w)
+    np.testing.assert_array_equal(rsde.centers, ref.centers)
+    np.testing.assert_array_equal(rsde.weights, ref.weights)
+
+
 def test_budget_spill_caps_m_exactly():
     x = _mix(1200, seed=9)
     eps = 0.05  # tiny eps -> many more candidates than the budget
@@ -150,26 +171,101 @@ def test_select_streaming_empty_source_raises():
         select_streaming(iter([]), 0.1)
 
 
+def _device_merge(batches, d, eps, budget=None, block=32):
+    """The on-device streaming merge fed ``batches`` of (centers, int
+    masses) one bucket each; returns its host view."""
+    import jax.numpy as jnp
+
+    from repro.core import shadow
+
+    rows = sum(len(w) for _, w in batches)
+    state = shadow.merge_init(d, shadow.merge_capacity(max(rows, 1), budget))
+    for c, w in batches:
+        k = max(len(w), 1)
+        cp = np.zeros((k, d), np.float32)
+        cp[: len(w)] = c
+        wp = np.zeros((k,), np.int32)
+        wp[: len(w)] = w
+        state = shadow._merge_chunk(state, jnp.asarray(cp), jnp.asarray(wp),
+                                    jnp.float32(eps) ** 2, block, budget)
+    return shadow.merge_to_host(state)
+
+
+def _candidate_batches(x, eps, chunk, block=32):
+    out = []
+    for s in range(0, len(x), chunk):
+        c, w, _, m = shadow_select_blocked(x[s : s + chunk], eps, block=block)
+        out.append((c[:m], w[:m].astype(np.int64)))
+    return out
+
+
+@pytest.mark.parametrize("budget", [None, 40])
+def test_device_merge_matches_host_reference(budget):
+    """Batch for batch, the on-device merge keeps the centers and int
+    masses the plain host merge keeps, spilling the same candidates under a
+    budget; the merged set partitions the mass exactly, stays
+    eps-separated and, within no budget, covers every row within 2*eps."""
+    x = _mix(1200, seed=9)
+    eps = 0.05 if budget else 0.2
+    batches = _candidate_batches(x, eps, 256)
+    ref = StreamingMerge(x.shape[1], eps, budget=budget, block=32)
+    for c, w in batches:
+        ref.update(c, w)
+    got = _device_merge(batches, x.shape[1], eps, budget=budget)
+    np.testing.assert_array_equal(got["centers"], ref.centers)
+    np.testing.assert_array_equal(got["weights"], ref.weights)
+    assert got["weights"].sum() == len(x)
+    cnt = dict(zip(("m", "gathered", "absorbed", "kept", "spilled"),
+                   got["counters"]))
+    assert cnt["m"] == ref.m and cnt["spilled"] == ref.spilled
+    assert cnt["gathered"] == sum(len(w) for _, w in batches)
+    assert cnt["kept"] == ref.m
+    if budget:
+        assert ref.m == budget and ref.spilled > 0
+        np.testing.assert_allclose(np.sqrt(got["pairs"][2]),
+                                   ref.max_spill_dist, rtol=1e-6)
+    else:
+        d = np.linalg.norm(x[:, None] - got["centers"][None], axis=2).min(1)
+        assert (d < 2 * eps + 1e-5).all()
+    d2 = ((got["centers"][:, None] - got["centers"][None]) ** 2).sum(-1)
+    np.fill_diagonal(d2, np.inf)
+    assert np.sqrt(d2.min()) >= eps - 1e-5
+
+
+def test_device_merge_masses_exact_past_2_24():
+    """Masses are int32 on the device: duplicate candidates whose masses
+    sum past 2^24 merge to the exact integer, where float32 rounds."""
+    c = np.eye(3, dtype=np.float32)
+    big = np.array([(1 << 23) + 1, (1 << 23) + 3, 5], np.int64)
+    got = _device_merge([(c, big)] * 3, 3, 0.2)
+    exact = 3 * big
+    np.testing.assert_array_equal(got["weights"], exact)
+    assert got["weights"].sum() == 3 * big.sum()
+    f32 = np.float32(big[0]) + np.float32(big[0]) + np.float32(big[0])
+    assert int(f32) != exact[0]  # what a float32 accumulator would give
+
+
 def test_streaming_merge_duplicate_centers_across_batches():
     """The same centers arriving from two chunk/shard boundaries must merge
     (d2 == 0 < eps^2), not accumulate as near-duplicates."""
     x = _mix(400, seed=11)
     c, w, _, m = shadow_select_blocked(x, 0.2, block=32)
-    merge = StreamingMerge(x.shape[1], 0.2)
-    merge.update(c[:m], w[:m])
-    merge.update(c[:m], w[:m])  # identical batch again
-    assert merge.m == m
-    np.testing.assert_array_equal(merge.centers, c[:m])
-    assert merge.weights.sum() == 2 * len(x)
+    batch = (c[:m], w[:m].astype(np.int64))
+    got = _device_merge([batch, batch], x.shape[1], 0.2)  # identical again
+    assert got["centers"].shape[0] == m
+    np.testing.assert_array_equal(got["centers"], c[:m])
+    assert got["weights"].sum() == 2 * len(x)
 
 
 def test_streaming_merge_empty_and_padded_updates():
-    merge = StreamingMerge(3, 0.2)
-    merge.update(np.zeros((0, 3)), np.zeros((0,)))          # empty shard
-    merge.update(np.zeros((5, 3)), np.zeros((5,)))          # all padding
-    assert merge.m == 0
-    merge.update(np.eye(3, dtype=np.float32), np.ones((3,)))
-    assert merge.m == 3 and merge.weights.sum() == 3.0
+    empty = (np.zeros((0, 3), np.float32), np.zeros((0,), np.int64))
+    padding = (np.zeros((5, 3), np.float32), np.zeros((5,), np.int64))
+    got = _device_merge([empty, padding], 3, 0.2)
+    assert got["centers"].shape[0] == 0 and got["counters"].sum() == 0
+    got = _device_merge([empty, padding,
+                         (np.eye(3, dtype=np.float32), np.ones(3, np.int64))],
+                        3, 0.2)
+    assert got["centers"].shape[0] == 3 and got["weights"].sum() == 3
 
 
 def test_ingest_fit_matches_fused_fit_on_one_chunk():

@@ -250,3 +250,38 @@ def test_max_centers_overflow_guard():
     c, w, a, m = jax.jit(
         lambda x: shadow_select(x, 1e-9, max_centers=10))(jnp.asarray(x))
     assert int(m) == 10 and float(w.sum()) == 100  # absorbed remainder
+
+
+def _blocked_np(x, eps, block):
+    """Blocked Algorithm 2 in numpy with the batch pruned by an in-order
+    scan: each round's candidates are the first ``block`` alive rows, kept
+    when >= eps from every kept candidate before them."""
+    n = x.shape[0]
+    alive = np.ones(n, bool)
+    centers, weights = [], []
+    while alive.any():
+        cand = np.flatnonzero(alive)[:block]
+        keep = []
+        for j in cand:
+            if all(np.sum((x[j] - x[i]) ** 2) >= eps * eps for i in keep):
+                keep.append(j)
+        d2 = ((x[:, None, :] - x[keep][None, :, :]) ** 2).sum(-1)
+        near, dmin = d2.argmin(1), d2.min(1)
+        absorbed = alive & (dmin < eps * eps)
+        for r, j in enumerate(keep):
+            centers.append(x[j])
+            weights.append(int(np.sum(absorbed & (near == r))))
+        alive &= ~absorbed
+    return np.asarray(centers), np.asarray(weights, np.float64)
+
+
+@pytest.mark.parametrize("eps", [0.15, 0.4])
+def test_blocked_batch_pick_matches_an_in_order_scan(eps):
+    """Dense batches (many candidates within eps of one another) keep
+    exactly the candidates an in-order scan keeps."""
+    x = _data(600, 3, 21)
+    c, w, _, m = shadow_select_blocked(x, eps, block=128)
+    c_ref, w_ref = _blocked_np(x, eps, 128)
+    assert m == len(w_ref)
+    np.testing.assert_array_equal(c[:m], c_ref)
+    np.testing.assert_array_equal(w[:m], w_ref)

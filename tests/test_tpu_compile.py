@@ -118,3 +118,55 @@ def test_rff_project_compiles(one_chip, tile, nfeat, d):
                  x, w, b, u, plan=f"pallas:{tile}", interpret=False),
              ((tile, d), F32), ((nfeat, d), F32), ((nfeat,), F32),
              ((nfeat, 8), F32))
+
+
+def _merge_state(cap, d, sharding):
+    return {"c": jax.ShapeDtypeStruct((cap, d), F32, sharding=sharding),
+            "w": jax.ShapeDtypeStruct((cap,), jnp.int32, sharding=sharding),
+            "cnt": jax.ShapeDtypeStruct((5,), jnp.int32, sharding=sharding),
+            "pairs": jax.ShapeDtypeStruct((3,), F32, sharding=sharding)}
+
+
+@pytest.fixture
+def tpu_plans(monkeypatch):
+    """The program's TPU branch with the heuristic plans: the merge picks
+    its assignment plan while it is traced, and here there is no chip to
+    time one on."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+
+def test_ingest_merge_compiles(one_chip, tpu_plans):
+    """The streaming merge of one 65536-row chunk into a state of the
+    ingest budget's capacity, on one chip."""
+    from repro.core import shadow
+
+    d, n_loc = 16, 65536
+    shadow._merge_chunk.lower(
+        _merge_state(ASSIGN_CENTERS, d, one_chip),
+        jax.ShapeDtypeStruct((n_loc, d), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n_loc,), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), F32, sharding=one_chip),
+        256, ASSIGN_CENTERS).compile()
+
+
+def test_ingest_merge_compiles_on_a_2x2_mesh(topo, one_chip, tpu_plans):
+    """The mesh merge of a 262144-row chunk over four chips: it all-gathers
+    the chips' candidate buckets."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import distributed
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    rep = NamedSharding(mesh, P())
+    rows, d = 4 * 65536, 16
+    compiled = distributed._merge_chunk_sharded.lower(
+        _merge_state(ASSIGN_CENTERS, d, rep),
+        jax.ShapeDtypeStruct((rows, d), F32,
+                             sharding=NamedSharding(mesh, P("data", None))),
+        jax.ShapeDtypeStruct((rows,), F32,
+                             sharding=NamedSharding(mesh, P("data"))),
+        jax.ShapeDtypeStruct((), F32, sharding=rep),
+        mesh, "data", 256, ASSIGN_CENTERS).compile()
+    assert "all-gather" in compiled.as_text()
