@@ -21,12 +21,12 @@ Algorithm 1's fit as one device-resident dataflow:
     eigensolve streams Gram tiles through the fused ``gram_matvec`` kernel —
     the select->fit pipeline then never materializes ANY m x m buffer.
 
-Tradeoff vs ``shadow_select_blocked``: the host-compaction cascade (§3)
-halves late-round absorption work but pays a host sync + re-upload per
-phase; the fused loop keeps everything device-resident at full-n absorption
-cost per round.  At large n/m — exactly where the matrix-free fit engages —
-the removed host traffic wins; below it ``selector="blocked"`` remains the
-default.
+Tradeoff vs ``shadow_select_blocked``: both keep the rows on the device.
+The blocked selector's halving cascade (§3) compacts the survivors into a
+smaller set each time the alive set halves, at one small fetch per phase,
+and so halves late-round absorption work; the fused loop runs every round
+at full-n absorption cost but makes no fetch until m, and hands the
+selection buffers to the fit without a host round-trip of the centers.
 """
 from __future__ import annotations
 

@@ -246,23 +246,36 @@ def _pow2_ceil(v: int) -> int:
     return 1 << max(v - 1, 0).bit_length()
 
 
-def _pad_pow2(x: np.ndarray, orig: np.ndarray, w: np.ndarray | None):
-    """Pad a working set with dead zero rows to a power of two, so the
-    number of distinct jit shapes stays logarithmic in n.  Returns
-    (x, padded-row -> original-row map, alive mask, masses or None)."""
-    k = x.shape[0]
-    npad = _pow2_ceil(k)
-    xp = np.zeros((npad, x.shape[1]), np.float32)
-    xp[:k] = x
-    op = np.zeros((npad,), np.int64)
-    op[:k] = orig
-    alive = np.zeros((npad,), bool)
-    alive[:k] = True
-    if w is not None:
-        wp = np.zeros((npad,), np.float32)
-        wp[:k] = w
-        w = wp
-    return xp, op, alive, w
+@jax.jit
+def _phase_absorb(assign: Array, a: Array, orig: Array, alive: Array,
+                  mr: Array, m: Array):
+    """Write a phase's rows into the call's assign map: the original row
+    ``orig[r]`` of working row r gets ``m + a[r]`` if the phase absorbed
+    it, else -1 (a row still alive is written again by a later phase).
+    ``orig`` is sorted and unique, its padding rows past the map's end,
+    so the scatter is a plain permuted write.  Also returns the one small
+    array the host fetches per phase: (centers selected, rounds run, rows
+    still alive)."""
+    assign = assign.at[orig].set(jnp.where(a >= 0, m + a, -1), mode="drop",
+                                 indices_are_sorted=True, unique_indices=True)
+    return assign, jnp.stack([mr[0], mr[1], alive.sum(dtype=jnp.int32)])
+
+
+@partial(jax.jit, static_argnames=("size",))
+def _phase_compact(alive: Array, x: Array, orig: Array, w, n: Array,
+                   size: int):
+    """The next phase's working set: the alive rows of ``x`` (with their
+    original-row ids and masses) in index order, as ``np.flatnonzero``
+    lists them, in the first rows of a ``size``-row set whose other rows
+    are dead and zero, their ids ``n`` and up.  Returns (x, orig, alive,
+    w)."""
+    iota = jnp.arange(x.shape[0])
+    idx = jnp.argsort(jnp.where(alive, iota, x.shape[0] + iota))[:size]
+    live = jnp.arange(size) < alive.sum(dtype=jnp.int32)
+    x = jnp.where(live[:, None], x[idx], 0.0)
+    orig = jnp.where(live, orig[idx], n + jnp.arange(size, dtype=jnp.int32))
+    w = None if w is None else jnp.where(live, w[idx], 0.0)
+    return x, orig, live, w
 
 
 def shadow_select_blocked(x, eps: float, block: int | None = None,
@@ -272,12 +285,18 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
 
     Work efficiency: every round's absorption pass costs O(alive_now * B),
     but late rounds mostly revisit dead points if the loop keeps the full
-    array.  So the device loop runs until the alive set HALVES, the host
-    compacts the survivors, and selection resumes on the smaller array —
-    total absorption work drops from rounds*n to ~2x the first phase.  The
-    input and every compacted set are padded to a power of two, so a stream
-    of calls at varying n (the streaming merge's candidate batches) compiles
-    a logarithmic number of programs.
+    array.  So the device loop runs until the alive set HALVES, the
+    survivors are compacted into a smaller working set, and selection
+    resumes on it — total absorption work drops from rounds*n to ~2x the
+    first phase.  The working set stays on the device throughout: the rows
+    cross to it once per call (padded there to a power of two, and only
+    when n is not one), each phase's compaction is a device gather into a
+    power-of-two set, and the host fetches one small array per phase (the
+    centers selected, the rounds run and the rows still alive) and, at the
+    end, the assign map and the phases' centers in one fetch.  Every shape
+    is n or a power of two (each phase's centers are cut at one), so a
+    stream of calls at varying n (the streaming merge's candidate batches)
+    or m compiles a logarithmic number of programs.
 
     Returns (centers (m, d), weights (m,), assign (n,), m) exactly like
     ``shadow_select_host``.  The center SET differs from the sequential order
@@ -292,50 +311,52 @@ def shadow_select_blocked(x, eps: float, block: int | None = None,
     mass is 1).  Output weights then partition ``sum(weights)`` instead
     of ``n``.
     """
-    x_np = np.asarray(x, np.float32)
-    n = x_np.shape[0]
     block = 256 if block is None else block
     eps2 = jnp.asarray(eps, jnp.float32) ** 2
-    assign = np.full((n,), -1, np.int64)
-    centers_out, weights_out = [], []
-    m = 0
-    # each halving phase's working set: its rows, row -> original-row map,
-    # and masses.  Spans (DESIGN.md §16) split a phase into the host's pad,
-    # the transfer, the device rounds and the host's compaction.
-    cur_x, cur_orig = x_np, np.arange(n)
-    cur_w = None if weights is None else np.asarray(weights, np.float32)
-    while cur_x.shape[0]:
-        k = cur_x.shape[0]
-        with _span("select.phase", n_pad=_pow2_ceil(k), n_alive=k) as phase:
-            with _span("select.pad"):
-                xp, orig, alive0, wp = _pad_pow2(cur_x, cur_orig, cur_w)
-            b = max(1, min(block, xp.shape[0]))
-            nbytes = xp.nbytes + alive0.nbytes + (0 if wp is None
-                                                  else wp.nbytes)
-            with _span("select.put", bytes=nbytes) as sp:
-                xd, alive_d, wd = sp.sync((
-                    jnp.asarray(xp), jnp.asarray(alive0),
-                    None if wp is None else jnp.asarray(wp)))
+    # Spans (DESIGN.md §16): the first phase holds the one transfer and the
+    # device pad; every phase its device rounds and its compaction.
+    n, d = np.shape(x)
+    heads = []  # each phase's power-of-two (centers, weights) prefix, m
+    k, m = n, 0
+    while k:
+        npad = _pow2_ceil(k)
+        with _span("select.phase", n_pad=npad, n_alive=k) as phase:
+            if not heads:
+                with _span("select.put", bytes=4 * n * (
+                        d + (weights is not None))) as sp:
+                    xd, wd = sp.sync((
+                        jnp.asarray(x, jnp.float32),
+                        None if weights is None
+                        else jnp.asarray(weights, jnp.float32)))
+                with _span("select.pad"):
+                    if npad > n:
+                        xd = jnp.pad(xd, ((0, npad - n), (0, 0)))
+                        wd = None if wd is None \
+                            else jnp.pad(wd, (0, npad - n))
+                    orig = jnp.arange(npad, dtype=jnp.int32)
+                    alive = orig < n
+                    assign = jnp.full((n,), -1, jnp.int32)
             with _span("select.rounds") as sp:
                 alive, c, w, a, mr = sp.sync(_blocked_select_device(
-                    xd, eps2, b, alive_d, jnp.asarray(k // 2, jnp.int32),
-                    wd))
-            del xd, alive_d, wd  # the phase's rows leave the device here
+                    xd, eps2, max(1, min(block, npad)), alive,
+                    jnp.asarray(k // 2, jnp.int32), wd))
             with _span("select.compact"):
-                mm, rounds = (int(v) for v in np.asarray(mr))
-                a = np.asarray(a)
-                absorbed = a >= 0
-                assign[orig[absorbed]] = m + a[absorbed]
-                centers_out.append(np.asarray(c[:mm]))
-                weights_out.append(np.asarray(w[:mm]))
+                assign, counts = _phase_absorb(assign, a, orig, alive, mr,
+                                               np.int32(m))
+                mm, rounds, k = (int(v) for v in jax.device_get(counts))
+                top = _pow2_ceil(mm)
+                heads.append((c[:top], w[:top], mm))
                 m += mm
-                still = np.flatnonzero(np.asarray(alive))
-                cur_x, cur_orig = xp[still], orig[still]
-                cur_w = None if wp is None else wp[still]
+                del c, w, a  # the phase's buffers leave the device here
+                if k:
+                    xd, orig, alive, wd = _phase_compact(
+                        alive, xd, orig, wd, np.int32(n), _pow2_ceil(k))
             phase.set(centers=mm, rounds=rounds)
-    return (np.concatenate(centers_out),
-            np.concatenate(weights_out).astype(np.float64),
-            assign, m)
+    assign, heads = jax.device_get((assign, heads))
+    return (np.concatenate([c[:mm] for c, _, mm in heads]),
+            np.concatenate([w[:mm] for _, w, mm in heads]).astype(
+                np.float64),
+            assign.astype(np.int64), m)
 
 
 #: Rows of one device's level-1 candidates folded per merge step: the
