@@ -450,7 +450,6 @@ import os, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
 from repro.core import gaussian, shadow_rsde
-from repro.core.distributed import distributed_shadow_rsde
 from repro.core import mmd as M
 from repro.data import make_dataset
 
@@ -461,9 +460,9 @@ for n in (4096, 16384):
     ker = gaussian(sigma)
     # warmup both paths (compile)
     shadow_rsde(x[:512], ker, 4.0)
-    distributed_shadow_rsde(x[:1024], ker, 4.0, mesh)
+    shadow_rsde(x[:1024], ker, 4.0, mesh=mesh)
     t0 = time.perf_counter(); r1 = shadow_rsde(x, ker, 4.0)
-    t1 = time.perf_counter(); r2 = distributed_shadow_rsde(x, ker, 4.0, mesh)
+    t1 = time.perf_counter(); r2 = shadow_rsde(x, ker, 4.0, mesh=mesh)
     t2 = time.perf_counter()
     m1 = M.mmd_weighted(ker, x, r1.centers, r1.weights)
     m2 = M.mmd_weighted(ker, x, r2.centers, r2.weights)

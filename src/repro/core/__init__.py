@@ -8,7 +8,7 @@ from repro.core.kernels_math import (  # noqa: F401
 )
 from repro.core.shadow import (  # noqa: F401
     shadow_select, shadow_select_np, shadow_select_host,
-    shadow_select_blocked, shadow_select_streaming, two_level_merge,
+    shadow_select_blocked, two_level_merge,
 )
 from repro.core.rsde import (  # noqa: F401
     RSDE, make_rsde, shadow_rsde, kmeans_rsde, kmeans_rsde_stream,
@@ -18,7 +18,7 @@ from repro.core.rskpca import (  # noqa: F401
     KPCAModel, fit, fit_rskpca, fit_kpca, fit_subsampled_kpca,
     embedding_alignment_error, eigenvalue_error,
 )
-from repro.core.pipeline import fit_centers, fit_shadow_fused  # noqa: F401
+from repro.core.pipeline import fit_centers  # noqa: F401
 from repro.core.ingest_pipeline import (  # noqa: F401
     IngestStats, ingest_fit, pad_block, select_streaming,
 )

@@ -1,12 +1,16 @@
-"""Distributed ShDE + RSKPCA (DESIGN.md §3 selection, §5 sharded
+"""Sharded ShDE + RSKPCA (DESIGN.md §3 selection, §5 sharded
 fit/transform — the TPU-pod adaptation).
 
 The paper's Algorithm 2 is a greedy sequential scan — fine on one host,
-hostile to a 256-chip pod.  We adapt it as a two-level blocked selection:
+hostile to a pod.  On a mesh, selection is the chunk stream's two-level
+scheme (core/ingest_pipeline.py; in memory through ``rsde.shadow_rsde``
+with a mesh):
 
-  level 1: each device runs Algorithm 2 on its local shard (shard_map);
-  level 2: candidate centers are all-gathered and a single merge pass runs
-           Algorithm 2 *on the centers*, summing absorbed weights.
+  level 1: each device runs blocked selection on its rows of a chunk
+           (``_chunk_select_sharded``);
+  level 2: the devices' candidate buckets are all-gathered and folded into
+           a replicated merge state with int32 masses
+           (``_merge_chunk_sharded``).
 
 Correctness: every data point is within eps of its level-1 center, and every
 level-1 center is within eps of its level-2 center, so the two-level
@@ -26,48 +30,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.kernels_math import Kernel, gram_matrix, gram_matrix_dense
-from repro.core.rsde import RSDE
 from repro.core import shadow as shadow_mod
 from repro.kernels import ops as kernel_ops
 from repro.kernels.ops import _pad_rows
 
 Array = jax.Array
-
-
-def _local_shadow(x_loc: Array, eps: Array, max_centers: int,
-                  valid_loc: Array):
-    """Level-1 selection on one device's shard. Returns padded (c, w)."""
-    centers, weights, _, _ = shadow_mod.shadow_select(
-        x_loc, eps, max_centers=max_centers, valid=valid_loc
-    )
-    return centers, weights
-
-
-@partial(jax.jit, static_argnames=("mesh", "axis", "max_local", "max_global"))
-def _two_level_select(x: Array, valid: Array, eps: Array, mesh: Mesh,
-                      axis: str, max_local: int, max_global: int):
-    """shard_map level-1 + all-gather + replicated level-2 merge."""
-
-    def level1(x_loc, valid_loc):
-        c, w = _local_shadow(x_loc, eps, max_centers=max_local,
-                             valid_loc=valid_loc)
-        # gather every device's candidates (m_loc is data-dependent; padded)
-        all_c = jax.lax.all_gather(c, axis, tiled=True)   # (ndev*max_local, d)
-        all_w = jax.lax.all_gather(w, axis, tiled=True)   # (ndev*max_local,)
-        return all_c, all_w
-
-    all_c, all_w = jax.shard_map(
-        level1, mesh=mesh, in_specs=(P(axis, None), P(axis)),
-        out_specs=(P(None, None), P(None)), check_vma=False,
-    )(x, valid)
-    # level-2 merge is replicated (centers are tiny); weights>0 masks padding
-    out_c, out_w, m = shadow_mod.two_level_merge(
-        all_c, all_w, eps, max_centers=max_global
-    )
-    return out_c, out_w, m
 
 
 @partial(jax.jit, static_argnames=("mesh", "axis", "block"))
@@ -112,38 +82,6 @@ def _merge_chunk_sharded(state: dict, c1: Array, w1: Array, eps2: Array,
         mesh=mesh, in_specs=(spec, P(axis, None), P(axis)), out_specs=spec,
         check_vma=False,
     )(state, c1, w1)
-
-
-def distributed_shadow_rsde(x, kernel: Kernel, ell: float, mesh: Mesh,
-                            axis: str = "data",
-                            max_local: int | None = None,
-                            max_global: int | None = None) -> RSDE:
-    """Two-level distributed ShDE over a device mesh axis.
-
-    n need not divide the axis: rows are padded to a device multiple and
-    masked out of selection (they are never centers and carry no weight)."""
-    ndev = mesh.shape[axis]
-    x = jnp.asarray(x, jnp.float32)
-    n = x.shape[0]
-    xp = _pad_rows(x, ndev)
-    valid = (jnp.arange(xp.shape[0]) < n)
-    n_loc = xp.shape[0] // ndev
-    max_local = max_local or n_loc
-    max_global = max_global or min(xp.shape[0], ndev * max_local)
-    sharding = NamedSharding(mesh, P(axis, None))
-    xp = jax.device_put(xp, sharding)
-    c, w, m = _two_level_select(
-        xp, valid, jnp.float32(kernel.epsilon(ell)), mesh, axis, max_local,
-        max_global
-    )
-    m = int(m)
-    return RSDE(
-        centers=np.asarray(c[:m]),
-        weights=np.asarray(w[:m], np.float64),
-        n=n,
-        assign=None,  # assignment is recomputable in one blocked pass if needed
-        scheme="shadow2",
-    )
 
 
 def blocked_gram_rows(x, centers, kernel: Kernel, mesh: Mesh,

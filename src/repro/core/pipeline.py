@@ -1,32 +1,14 @@
-"""Single-pass select->fit pipeline (DESIGN.md §6).
+"""The capacity-bucketed fit of a selected center set (DESIGN.md §6).
 
-``fit_shadow_fused`` runs blocked shadow selection (Algorithm 2, §3) and
-Algorithm 1's fit as one device-resident dataflow:
-
-  * selection runs to exhaustion inside ONE jitted while_loop
-    (``shadow._blocked_select_device`` with ``stop_count=0``) — the accepted
-    centers scatter straight into a preallocated (n, d) device buffer;
-  * the ONLY host synchronization between the stages is the scalar center
-    count m (needed to pick the power-of-two capacity bucket the fit
-    compiles against — the same bucketing contract as streaming/serving);
-  * the fit consumes a ``cap``-row slice of the selection output directly:
-    no host round-trip of the center data, no re-padding — rows beyond m
-    carry zero weight, which zeroes their K-tilde rows/columns and their
-    projector rows (the established zero-weight-padding invariant);
-  * the sliced center/weight buffers are donated into the jitted fit
-    (``_fit_rskpca_device``) and XLA reuses their storage (the model's
-    center rows are materialized to host BEFORE the donation, since a
-    cap == n slice is the selection buffer itself);
-  * above the matrix-free crossover (kernels.ops.matfree_fit) the fit's
-    eigensolve streams Gram tiles through the fused ``gram_matvec`` kernel —
-    the select->fit pipeline then never materializes ANY m x m buffer.
-
-Tradeoff vs ``shadow_select_blocked``: both keep the rows on the device.
-The blocked selector's halving cascade (§3) compacts the survivors into a
-smaller set each time the alive set halves, at one small fetch per phase,
-and so halves late-round absorption work; the fused loop runs every round
-at full-n absorption cost but makes no fetch until m, and hands the
-selection buffers to the fit without a host round-trip of the centers.
+``fit_centers`` is Algorithm 1's tail for a selected center set: the
+in-memory fit job (``shadow_rsde`` then ``fit_centers``) and the
+out-of-core ``ingest_pipeline.ingest_fit``, whose merged set it takes
+straight from the device.  The set is cut or zero-padded to the
+power-of-two capacity bucket of m — zero-weight rows carry zero K-tilde
+rows/columns and zero projector rows — so one compile serves every m in a
+bucket; above the matrix-free crossover (kernels.ops.matfree_fit) the
+eigensolve streams Gram tiles through the fused ``gram_matvec`` kernel and
+no m x m buffer materializes.
 """
 from __future__ import annotations
 
@@ -34,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kernels_math import Kernel
-from repro.core import shadow as shadow_mod
 from repro.core.shadow import _pow2_ceil
 from repro.core.rskpca import KPCAModel, _fit_rskpca_device, _use_matfree
 from repro.obs import trace as _trace
@@ -45,13 +26,12 @@ def fit_centers(centers, weights, n: int, kernel: Kernel, rank: int, *,
                 m: int | None = None, matfree: bool | None = None,
                 method: str = "rskpca") -> KPCAModel:
     """Capacity-bucketed Algorithm 1 fit of a selected center set — the
-    shared fit tail of the fused (``fit_shadow_fused``) and out-of-core
+    shared fit tail of the in-memory (``shadow_rsde``) and out-of-core
     (``ingest_pipeline.ingest_fit``) pipelines.
 
     ``centers``/``weights`` may be a device buffer with ``m`` live rows (the
-    fused selector's preallocated (n, d) output, or the streaming merge's
-    state, sliced here without a host round-trip) or an exact host (m, d)
-    set.  Either way they are sliced/zero-padded to the power-of-two
+    streaming merge's state, sliced here without a host round-trip) or an
+    exact host (m, d) set.  Either way they are sliced/zero-padded to the power-of-two
     capacity bucket — zero-weight rows contribute zero K-tilde rows/columns
     and zero projector rows — so re-jit count stays logarithmic across m.  The cap slices are
     donated into the jitted device fit; ``matfree=None`` consults the
@@ -96,24 +76,3 @@ def fit_centers(centers, weights, n: int, kernel: Kernel, rank: int, *,
         )
     return model
 
-
-def fit_shadow_fused(x, kernel: Kernel, rank: int, *, ell: float,
-                     block: int | None = None,
-                     matfree: bool | None = None) -> KPCAModel:
-    """ShDE selection + RSKPCA fit with the centers never leaving device.
-
-    Equivalent to ``fit(x, ..., method="shadow", selector="blocked")``
-    followed by ``fit_rskpca`` — same cover invariants, same operator — but
-    with the intermediate RSDE elided.  ``matfree=None`` consults the
-    bytes-budget crossover; the model is materialized to host only at the
-    very end (sliced to the true m).
-    """
-    xf = jnp.asarray(x, jnp.float32)
-    n, d = xf.shape
-    eps2 = jnp.float32(kernel.epsilon(ell)) ** 2
-    b = max(1, min(256 if block is None else block, n))
-    _, centers, weights, _, mr = shadow_mod._blocked_select_device(
-        xf, eps2, b, jnp.ones((n,), bool), jnp.asarray(0, jnp.int32))
-    m = int(np.asarray(mr)[0])  # the pipeline's single host sync: m
-    return fit_centers(centers, weights, n, kernel, rank, m=m,
-                       matfree=matfree, method="rskpca+shadow-fused")

@@ -99,13 +99,7 @@ class RSKPCAProbe:
         x = self.buffer.data
         sigma = max(median_sigma(x), 1e-6)
         kernel = gaussian(sigma)
-        if self.mesh is not None and np.prod(self.mesh.devices.shape) > 1:
-            from repro.core.distributed import distributed_shadow_rsde
-            ndev = self.mesh.shape["data"]
-            n_fit = (x.shape[0] // ndev) * ndev
-            rsde = distributed_shadow_rsde(x[:n_fit], kernel, self.ell, self.mesh)
-        else:
-            rsde = shadow_rsde(x, kernel, self.ell)
+        rsde = shadow_rsde(x, kernel, self.ell, mesh=self.mesh)
         rank = min(self.rank, rsde.m)
         model = fit_rskpca(rsde, kernel, rank=rank)
         if self._anchor is None:

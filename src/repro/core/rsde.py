@@ -43,41 +43,46 @@ class RSDE:
         return self.m / self.n
 
 
-#: "auto" selector crossover: below this n the sequential while_loop beats
+#: One-device crossover: below this n the sequential while_loop beats
 #: blocked selection (the per-round assign/prune overhead only amortizes once
 #: m is large — measured 2x either way at n=2048 vs n=8192).
 _BLOCKED_MIN_N = 4096
 
 
-def shadow_rsde(x, kernel: Kernel, ell: float, *,
-                selector: str = "auto", block: int | None = None,
-                chunk: int = 8192) -> RSDE:
-    """ShDE via Algorithm 2 with eps = sigma/ell.
+def shadow_rsde(x, kernel: Kernel, ell: float, *, block: int | None = None,
+                mesh=None, axis: str = "data") -> RSDE:
+    """ShDE via Algorithm 2 with eps = sigma/ell — the one in-memory front
+    door, and the one place the selection path is chosen (DESIGN.md §3):
 
-    ``selector`` picks the implementation (DESIGN.md §3):
-      * "auto"       — sequential below ``_BLOCKED_MIN_N`` rows, blocked
-        above (default; both sides of the crossover are exact eps-covers);
-      * "blocked"    — batched selection, ~m/B sequential rounds;
-      * "sequential" — the paper's literal one-center-per-iteration scan;
-      * "streaming"  — per-chunk blocked selection + two-level merge (2*eps
-        cover) for datasets that don't fit in device memory.
-    All produce a valid eps-cover whose weights sum to n.
+      * one device, n <= ``_BLOCKED_MIN_N``: the sequential scan
+        (``shadow_select_host``);
+      * one device, n above it: the blocked halving cascade
+        (``shadow_select_blocked``);
+      * ``mesh``: rows split over ``axis`` and selected by the out-of-core
+        stream's programs (``ingest_pipeline.select_streaming`` over ``x``
+        as one chunk, padded to a multiple of the axis): blocked selection
+        per device, then the device merge of the all-gathered candidates.
+        Cover radius 2*eps (the §5 bounds hold with ell -> ell/2), masses
+        exact, no assign map, scheme ``"shadow-ingest"``.
+
+    Every path gives weights that sum to n.
     """
     eps = kernel.epsilon(ell)
-    if selector == "auto":
-        selector = "sequential" if np.shape(x)[0] <= _BLOCKED_MIN_N \
-            else "blocked"
-    if selector == "blocked":
+    n = np.shape(x)[0]
+    if mesh is not None:
+        from repro.core.ingest_pipeline import pad_block, select_streaming
+        ndev = mesh.shape[axis]
+        xp, _ = pad_block(x, -(-n // ndev) * ndev)
+        rsde, _ = select_streaming([(xp, n)], eps,
+                                   block=256 if block is None else block,
+                                   mesh=mesh, axis=axis)
+        return rsde
+    if n <= _BLOCKED_MIN_N:
+        centers, weights, assign, m = shadow_mod.shadow_select_host(x, eps)
+    else:
         centers, weights, assign, m = shadow_mod.shadow_select_blocked(
             x, eps, block=block)
-    elif selector == "sequential":
-        centers, weights, assign, m = shadow_mod.shadow_select_host(x, eps)
-    elif selector == "streaming":
-        centers, weights, assign, m = shadow_mod.shadow_select_streaming(
-            x, eps, chunk=chunk, block=block)
-    else:
-        raise ValueError(f"unknown selector {selector!r}")
-    return RSDE(centers, weights, n=np.shape(x)[0], assign=assign, scheme="shadow")
+    return RSDE(centers, weights, n=n, assign=assign, scheme="shadow")
 
 
 @partial(jax.jit, static_argnames=("m", "iters"))

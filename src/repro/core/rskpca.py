@@ -343,9 +343,11 @@ def fit(x, kernel: Kernel, rank: int, *, method: str = "shadow",
     ``backend`` overrides the kernel's compute path ("pallas" | "dense") for
     this fit and the returned model — the parity-testing switch of
     DESIGN.md §3.  ``precision`` overrides the MXU operand dtype the same
-    way ("f32" | "bf16").  ``mesh`` runs selection (two-level distributed
-    ShDE), the Gram assembly, and the eigensolve sharded over the mesh's
-    ``axis`` (DESIGN.md §5); the returned model's ``transform`` accepts the
+    way ("f32" | "bf16").  ``mesh`` shards the fit's Gram assembly and
+    eigensolve over the mesh's ``axis`` (DESIGN.md §5); with
+    ``method="shadow"`` it also splits selection over that axis
+    (``shadow_rsde(mesh=)``: per-device blocked selection and the device
+    merge, a 2*eps cover).  The returned model's ``transform`` accepts the
     same ``mesh=`` for sharded serving.
     """
     if backend is not None:
@@ -385,24 +387,8 @@ def fit(x, kernel: Kernel, rank: int, *, method: str = "shadow",
             return fit_kpca(x, kernel, rank)
         assert m is not None
         return fit_subsampled_kpca(x, kernel, rank, m, **kw)
-    if method == "shadow" and mesh is None and kw.get("selector") == "fused":
-        # single-pass select->fit: device-resident blocked selection streams
-        # its accepted centers straight into the (matrix-free above the
-        # crossover) fit operator — no host round-trip between the stages
-        # (DESIGN.md §6; core/pipeline.py)
-        assert ell is not None, "shadow RSDE is parameterized by ell"
-        from repro.core.pipeline import fit_shadow_fused
-        kw2 = {k: v for k, v in kw.items() if k != "selector"}
-        return fit_shadow_fused(x, kernel, rank, ell=ell, **kw2)
-    if mesh is not None and method == "shadow":
-        assert ell is not None, "shadow RSDE is parameterized by ell"
-        from repro.core import distributed as dist
-        # **kw forwards so distributed selection kwargs (max_local,
-        # max_global) work and unsupported single-device selector kwargs
-        # raise instead of being silently dropped
-        rsde = dist.distributed_shadow_rsde(x, kernel, ell, mesh, axis=axis,
-                                            **kw)
-        return fit_rskpca(rsde, kernel, rank, mesh=mesh, axis=axis)
+    if method == "shadow":  # shadow_rsde picks the selection path
+        kw.update(mesh=mesh, axis=axis)
     rsde = make_rsde(method, x, kernel, ell=ell, m=m, **kw)
     return fit_rskpca(rsde, kernel, rank, mesh=mesh, axis=axis)
 

@@ -8,18 +8,24 @@ Implementations (DESIGN.md §3):
   * ``shadow_select_np``      — numpy oracle, literal Algorithm 2.
   * ``shadow_select``         — jittable ``lax.while_loop`` version with
     static padding (``max_centers``); sequential depth m.
-  * ``shadow_select_blocked`` — blocked selector: each round keeps a batch of
-    up to B mutually-eps-separated candidates and absorbs all their shadows
-    in ONE Pallas assignment pass, cutting sequential depth from m to ~m/B.
-  * ``shadow_select_streaming`` — two-level path for data that doesn't fit in
-    device memory: per-chunk blocked selection + the streaming merge
-    (cover radius degrades to 2*eps; the §5 bounds hold with ell -> ell/2).
+  * ``_blocked_select_device`` — the blocked rounds in one device
+    while_loop: each round keeps a batch of up to B mutually-eps-separated
+    candidates and absorbs all their shadows in ONE Pallas assignment pass,
+    cutting sequential depth from m to ~m/B.  It runs in the halving
+    cascade ``shadow_select_blocked`` (in memory, one device), in the
+    stream's per-chunk level 1 (core/ingest_pipeline.py; on a mesh per
+    device, ``distributed._chunk_select_sharded``), and as the streaming
+    merge's weighted reselection of new candidates (``_merge_fold``).
   * ``merge_chunk_local``  — the streaming merge: weight-exact, on the
     device, fixed-shape reconciliation of candidate-center buckets into a
-    merged set (the level-2 merge of the out-of-core ingest pipeline,
-    core/ingest_pipeline.py), with center-budget spill handling;
-    ``two_level_merge`` remains the one-shot replicated-merge variant the
-    sharded selector uses.
+    merged set (the level-2 merge of the chunk stream, whose cover radius
+    is 2*eps: the §5 bounds hold with ell -> ell/2), with center-budget
+    spill handling.
+  * ``two_level_merge`` — a one-shot shadow pass over weighted candidate
+    centers with float masses: the decayed-mass refresh of
+    streaming/drift.py.
+
+``rsde.shadow_rsde`` chooses among the in-memory paths from n and the mesh.
 
 Invariants (property-tested in tests/test_shadow.py):
   * every data point lies strictly within eps of its assigned center;
@@ -577,65 +583,16 @@ def _merge_chunk(state, c1, w1, eps2, block: int, budget: int | None):
     return merge_chunk_local(state, c1, w1, eps2, block, budget)
 
 
-def shadow_select_streaming(x, eps: float, chunk: int = 8192,
-                            block: int = 256, budget: int | None = None):
-    """Two-level streaming selection for out-of-memory datasets.
-
-    Level 1 runs blocked selection per fixed-size chunk (only one chunk is
-    device-resident at a time); level 2 folds each chunk's centers into the
-    device-resident streaming merge (``_merge_chunk``, the same program the
-    out-of-core ingest runs) — the merged set is the ONLY cross-chunk
-    state, so peak memory is O(chunk + m) however large n grows.  Cover
-    radius is 2*eps (triangle inequality), i.e. the §5 bounds hold with
-    ell -> ell/2; the final assign map is recovered with one Pallas
-    assignment pass per chunk.  ``budget`` caps the merged center count
-    (over-budget candidates spill weight-exactly into their nearest
-    retained center).
-
-    Returns (centers, weights, assign, m).  Unlike the one-level selectors,
-    ``weights`` are the MERGED level-1 shadow masses while ``assign`` maps
-    each point to its NEAREST merged center, so ``bincount(assign)`` need
-    not equal ``weights`` — both are valid 2*eps quantizations, they just
-    answer different questions (density mass vs. nearest-cover membership).
-    ``weights.sum() == n`` holds exactly (int32 masses, float64 out).
-    """
-    x = np.asarray(x, np.float32)
-    n, d = x.shape
-    block = 256 if block is None else block
-    chunk = max(1, min(chunk, n))
-    eps2 = jnp.float32(eps) ** 2
-    state, bound = None, 0
-    for s in range(0, n, chunk):
-        xs = x[s: s + chunk]
-        k = xs.shape[0]
-        xp = np.zeros((chunk, d), np.float32)
-        xp[:k] = xs
-        b = max(1, min(block, chunk))
-        _, c1, w1, _, _ = _blocked_select_device(
-            jnp.asarray(xp), eps2, b, jnp.asarray(np.arange(chunk) < k),
-            jnp.asarray(0, jnp.int32))
-        cap, bound = merge_reserve(state, bound, k, budget)
-        state = merge_init(d, cap) if state is None \
-            else merge_grow(state, cap)
-        state = _merge_chunk(state, c1, w1, eps2, block, budget)
-    out = merge_to_host(state)
-    centers, m = out["centers"], out["centers"].shape[0]
-    assign = np.empty((n,), np.int64)
-    for s in range(0, n, chunk):
-        idx, _ = kernel_ops.shadow_assign(x[s : s + chunk], centers,
-                                          tag="ingest")
-        assign[s : s + chunk] = np.asarray(idx)
-    return centers, out["weights"].astype(np.float64), assign, m
-
-
 def two_level_merge(centers: Array, weights: Array, eps: Array,
                     max_centers: int):
-    """Second-level shadow pass over candidate centers (distributed variant).
+    """Second-level shadow pass over weighted candidate centers.
 
     Runs Algorithm 2 on the *centers* themselves, summing absorbed weights
-    instead of counting points.  Quantization error of the two-level scheme is
-    at most 2*eps (triangle inequality), i.e. the paper's bounds hold with
-    ell -> ell/2 in the worst case (DESIGN.md §3).
+    instead of counting points.  The masses are floats (streaming/drift.py
+    merges decayed, non-integer masses); ``merge_chunk_local`` is the
+    int32, fixed-shape merge of the chunk stream.  Quantization error of
+    the two-level scheme is at most 2*eps (triangle inequality), i.e. the
+    paper's bounds hold with ell -> ell/2 in the worst case (DESIGN.md §3).
     """
     n, d = centers.shape
     cf = centers.astype(jnp.float32)

@@ -12,9 +12,10 @@ The refresh is a PARTIAL refit in the paper's reduced-set sense: it needs
 only the live centers (with their masses, optionally decayed) and the raw
 window — never the historical stream — because the RSDE weight structure
 carries all surviving mass.  Window points are shadow-selected at the same
-eps and merged with the decayed centers by ``two_level_merge`` (cover
-radius 2*eps, i.e. the §5 bounds with ell -> ell/2, as in the distributed
-selector), and the eigensystem is re-solved exactly.
+eps and merged with the decayed centers by ``two_level_merge``, the
+float-mass shadow pass over weighted centers (cover radius 2*eps, i.e. the
+§5 bounds with ell -> ell/2, as in every two-level selection), and the
+eigensystem is re-solved exactly.
 """
 from __future__ import annotations
 

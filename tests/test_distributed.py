@@ -31,8 +31,7 @@ def test_two_level_shadow_and_blocked_gram_8dev():
     _run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import gaussian, shadow_rsde, gram_matrix
-from repro.core.distributed import (distributed_shadow_rsde,
-                                    blocked_gram_rows, distributed_assign)
+from repro.core.distributed import blocked_gram_rows, distributed_assign
 from repro.core import mmd as M
 from repro.data import make_dataset
 x, y, sigma = make_dataset("pendigits", seed=1, n=1024)
@@ -40,8 +39,9 @@ ker = gaussian(sigma)
 from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("data",))
 r1 = shadow_rsde(x, ker, 4.0)
-r2 = distributed_shadow_rsde(x, ker, 4.0, mesh)
-assert abs(r2.weights.sum() - 1024) < 1e-3
+r2 = shadow_rsde(x, ker, 4.0, mesh=mesh)
+assert r2.scheme == "shadow-ingest"
+assert r2.weights.sum() == 1024   # exact int32 masses
 mmd2 = M.mmd_weighted(ker, x, r2.centers, r2.weights)
 assert mmd2 <= ker.mmd_bound(2.0) + 1e-6   # ell/2 worst case (2-level)
 assert mmd2 <= 2 * M.mmd_weighted(ker, x, r1.centers, r1.weights) + 0.05

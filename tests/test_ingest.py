@@ -10,7 +10,6 @@ from repro.core import gaussian
 from repro.core.ingest_pipeline import (_PrefetchFeed, IngestStats,
                                         ingest_fit, pad_block,
                                         select_streaming)
-from repro.core.pipeline import fit_shadow_fused
 from repro.core.shadow import shadow_select_blocked
 from merge_reference import StreamingMerge
 from repro.data.kpca_datasets import ChunkedDataset
@@ -269,13 +268,23 @@ def test_streaming_merge_empty_and_padded_updates():
 
 
 def test_ingest_fit_matches_fused_fit_on_one_chunk():
-    """Single-chunk stream: ingest_fit and fit_shadow_fused see the exact
-    same center set, so the fitted models must embed identically."""
+    """Single-chunk stream: ingest_fit and the fused blocked-selection loop
+    run to exhaustion (``_blocked_select_device``), then ``fit_centers`` on
+    its device buffers, see the exact same center set, so the fitted models
+    must embed identically."""
+    import jax.numpy as jnp
+    from repro.core.pipeline import fit_centers
+    from repro.core.shadow import _blocked_select_device
+
     x = _mix(600, seed=4)
     sigma = float(np.median(np.linalg.norm(x[:50, None] - x[None, :50],
                                            axis=2)))
     ker = gaussian(sigma)
-    model_f = fit_shadow_fused(x, ker, 4, ell=3.0, block=64)
+    eps2 = jnp.float32(ker.epsilon(3.0)) ** 2
+    _, c, w, _, mr = _blocked_select_device(
+        jnp.asarray(x), eps2, 64, jnp.ones((len(x),), bool),
+        jnp.asarray(0, jnp.int32))
+    model_f = fit_centers(c, w, len(x), ker, 4, m=int(np.asarray(mr)[0]))
     model_i, stats = ingest_fit(_chunks_of(x, 1024), ker, 4, ell=3.0,
                                 block=64)
     assert model_i.method == "rskpca+shadow-ingest"

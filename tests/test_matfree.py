@@ -1,6 +1,6 @@
 """Matrix-free fit: gram_matvec parity, LOBPCG eigenpair property tests,
-the fused select->fit pipeline, and the donation (no-copy) contracts
-(DESIGN.md §6)."""
+the capacity-bucketed fit tail (fit_centers), and the donation (no-copy)
+contracts (DESIGN.md §6)."""
 import numpy as np
 import pytest
 import jax
@@ -160,54 +160,63 @@ def test_forced_matfree_with_unsound_rank_fails_loudly():
         fit_rskpca(rsde, gaussian(1.0), 4, matfree=True)
 
 
-def test_fused_pipeline_matches_blocked_selection():
-    """selector="fused" (single-pass select->fit) produces the same center
-    set and an equivalent model as blocked selection + separate fit."""
-    from repro.core import gaussian, fit, embedding_alignment_error
+def test_inmem_fit_centers_matches_front_door():
+    """The in-memory fit's pair, shadow_rsde then the capacity-bucketed
+    fit_centers, gives the same center set and an equivalent model as the
+    front door fit(method="shadow")."""
+    from repro.core import (gaussian, fit, fit_centers, shadow_rsde,
+                            embedding_alignment_error)
     from repro.data import make_dataset
 
     x, _, sigma = make_dataset("german", seed=0, n=400)
     ker = gaussian(sigma)
-    fused = fit(x, ker, 4, method="shadow", ell=6.0, selector="fused")
-    blocked = fit(x, ker, 4, method="shadow", ell=6.0, selector="blocked")
-    assert fused.method == "rskpca+shadow-fused"
-    assert fused.m == blocked.m
+    rsde = shadow_rsde(x, ker, 6.0)
+    pair = fit_centers(rsde.centers, rsde.weights, rsde.n, ker, 4)
+    front = fit(x, ker, 4, method="shadow", ell=6.0)
+    assert pair.m == front.m == rsde.m
     q = x[:100]
-    ref_z = blocked.transform(q)
-    err = embedding_alignment_error(ref_z, fused.transform(q))
+    ref_z = front.transform(q)
+    err = embedding_alignment_error(ref_z, pair.transform(q))
     assert err <= 1e-3 * np.linalg.norm(ref_z)
 
 
-def test_fused_pipeline_full_capacity_alias_survives():
-    """Regression: with n <= 128 the pow2 capacity bucket equals n, so the
-    cap slice IS the selection buffer (jax full-slice fast path) and with
+def test_fit_centers_full_capacity_alias_survives():
+    """Regression: a device buffer whose rows equal its capacity bucket
+    (128) is the cap slice ITSELF (jax full-slice fast path), and with
     rank == d XLA aliases the donated buffer into the projector output —
-    the model's centers must be materialized BEFORE that donation."""
-    from repro.core import gaussian
-    from repro.core.pipeline import fit_shadow_fused
+    the model's centers must be materialized BEFORE that donation.  This
+    is the merge state ingest_fit hands over."""
+    import jax.numpy as jnp
+    from repro.core import gaussian, fit_centers, shadow_rsde
 
     rng = np.random.default_rng(5)
     x = rng.normal(size=(128, 4)).astype(np.float32)
-    mdl = fit_shadow_fused(x, gaussian(1.0), 4, ell=4.0)
+    ker = gaussian(1.0)
+    rsde = shadow_rsde(x, ker, 4.0)
+    c = jnp.zeros((128, 4), jnp.float32).at[:rsde.m].set(rsde.centers)
+    w = jnp.zeros((128,), jnp.float32).at[:rsde.m].set(rsde.weights)
+    mdl = fit_centers(c, w, 128, ker, 4, m=rsde.m)
     assert mdl.centers.shape[1] == 4 and mdl.m >= 1
+    np.testing.assert_array_equal(mdl.centers, rsde.centers)
     assert np.isfinite(mdl.transform(x[:16])).all()
 
 
-def test_fused_pipeline_matfree_end_to_end(monkeypatch):
-    """The full tentpole dataflow at test scale: fused selection streaming
-    into a matrix-free fit (forced via env), vs the all-default pipeline."""
-    monkeypatch.setenv("REPRO_MATFREE_MIN_M", "1")
-    from repro.core import gaussian, fit, embedding_alignment_error
+def test_fit_centers_matfree_end_to_end():
+    """One shadow_rsde fitted through fit_centers twice: the matrix-free
+    eigensolve (forced) against the default materialized one."""
+    from repro.core import (gaussian, fit_centers, shadow_rsde,
+                            embedding_alignment_error)
     from repro.data import make_dataset
 
     x, _, sigma = make_dataset("german", seed=1, n=400)
     ker = gaussian(sigma)
-    fused = fit(x, ker, 4, method="shadow", ell=5.0, selector="fused")
-    monkeypatch.delenv("REPRO_MATFREE_MIN_M")
-    base = fit(x, ker, 4, method="shadow", ell=5.0, selector="blocked")
+    rsde = shadow_rsde(x, ker, 5.0)
+    mf = fit_centers(rsde.centers, rsde.weights, rsde.n, ker, 4,
+                     matfree=True)
+    base = fit_centers(rsde.centers, rsde.weights, rsde.n, ker, 4)
     q = x[:100]
     ref_z = base.transform(q)
-    err = embedding_alignment_error(ref_z, fused.transform(q))
+    err = embedding_alignment_error(ref_z, mf.transform(q))
     assert err <= 1e-2 * np.linalg.norm(ref_z)
 
 

@@ -3,9 +3,10 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    gaussian, laplacian, shadow_rsde, fit_rskpca, fit_kpca,
+    RSDE, gaussian, laplacian, shadow_rsde, fit_rskpca, fit_kpca,
     fit_subsampled_kpca, fit_nystrom, fit_weighted_nystrom, fit,
-    embedding_alignment_error, make_rsde,
+    embedding_alignment_error, make_rsde, pad_block, select_streaming,
+    shadow_select_blocked, shadow_select_host,
     reduced_laplacian_eigenmaps, reduced_diffusion_maps,
 )
 from repro.data import make_dataset
@@ -102,14 +103,26 @@ def test_backend_switch_parity(data):
 
 
 def test_selector_variants_fit_equivalently(data):
-    """blocked / sequential / streaming / fused selectors all produce usable
-    RSKPCA models with comparable embedding quality."""
+    """The sequential scan, the blocked cascade and the chunk stream (over
+    128-row chunks of x) each feed fit_rskpca a usable center set, with
+    comparable embedding quality."""
     x, _, sigma = data
     ker = gaussian(sigma)
+    eps = ker.epsilon(6.0)
     ref = fit_kpca(x, ker, rank=4).transform(x[:100])
+
+    def rsde_of(select):
+        c, w, a, _ = select(x, eps)
+        return RSDE(c, w, n=len(x), assign=a)
+
+    chunks = [(pad_block(x[s:s + 128], 128)[0], min(128, len(x) - s))
+              for s in range(0, len(x), 128)]
+    rsdes = {"sequential": rsde_of(shadow_select_host),
+             "blocked": rsde_of(shadow_select_blocked),
+             "streaming": select_streaming(chunks, eps)[0]}
     errs = {}
-    for sel in ("blocked", "sequential", "streaming", "fused"):
-        mdl = fit(x, ker, 4, method="shadow", ell=6.0, selector=sel)
+    for sel, rsde in rsdes.items():
+        mdl = fit_rskpca(rsde, ker, 4)
         errs[sel] = embedding_alignment_error(ref, mdl.transform(x[:100]))
     scale = np.linalg.norm(ref)
     assert all(e < 0.5 * scale for e in errs.values()), errs

@@ -5,8 +5,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (shadow_select_np, shadow_select_host,
-                        shadow_select_blocked, shadow_select_streaming,
-                        gaussian)
+                        shadow_select_blocked, gaussian)
+from repro.core.ingest_pipeline import pad_block, select_streaming
 from repro.core.shadow import two_level_merge
 
 import jax.numpy as jnp
@@ -17,6 +17,18 @@ def _data(n, d, seed):
     centers = rng.uniform(0, 1, (max(2, n // 20), d))
     idx = rng.integers(0, centers.shape[0], n)
     return (centers[idx] + 0.05 * rng.normal(size=(n, d))).astype(np.float32)
+
+
+def _stream(x, eps, chunk, block, budget=None):
+    """The chunk stream over x in ``chunk``-row zero-padded blocks: merged
+    (centers, weights, each row's nearest center, m)."""
+    chunk = max(1, min(chunk, len(x)))
+    blocks = ((pad_block(x[s:s + chunk], chunk)[0], min(chunk, len(x) - s))
+              for s in range(0, len(x), chunk))
+    rsde, _ = select_streaming(blocks, eps, block=block, budget=budget)
+    c = rsde.centers
+    a = np.argmin(((x[:, None, :] - c[None]) ** 2).sum(-1), axis=1)
+    return c, rsde.weights, a, rsde.m
 
 
 def test_jax_matches_numpy_oracle():
@@ -136,8 +148,7 @@ def test_blocked_block1_matches_sequential_exactly():
 def test_streaming_two_level_cover(n, d, eps, seed):
     """Streaming selection: weights partition n; 2*eps cover (two-level)."""
     x = _data(n, d, seed)
-    c, w, a, m = shadow_select_streaming(x, eps, chunk=max(32, n // 3),
-                                         block=32)
+    c, w, a, m = _stream(x, eps, chunk=max(32, n // 3), block=32)
     assert abs(w.sum() - n) < 1e-3
     assert (a >= 0).all() and (a < m).all()
     dist = np.linalg.norm(x - c[a], axis=1)
@@ -149,7 +160,7 @@ def test_streaming_ragged_final_block():
     ``block``); its centers must still merge into a valid 2*eps cover."""
     x = _data(101, 4, 5)  # chunk=32 -> chunks of 32,32,32,5
     for eps in (0.1, 0.25):
-        c, w, a, m = shadow_select_streaming(x, eps, chunk=32, block=8)
+        c, w, a, m = _stream(x, eps, chunk=32, block=8)
         assert abs(w.sum() - 101) < 1e-3
         assert (a >= 0).all() and (a < m).all()
         dist = np.linalg.norm(x - c[a], axis=1)
@@ -233,12 +244,11 @@ def test_streaming_budget_caps_centers():
     """``budget`` makes m deterministic: over-budget candidates spill
     weight-exactly into the nearest retained center."""
     x = _data(500, 4, 13)
-    c, w, a, m = shadow_select_streaming(x, 0.05, chunk=128, block=16,
-                                         budget=32)
+    c, w, a, m = _stream(x, 0.05, chunk=128, block=16, budget=32)
     assert m == 32 and c.shape[0] == 32
     assert w.sum() == 500.0  # exact (f64 mass bookkeeping)
     assert (a >= 0).all() and (a < 32).all()
-    c2, w2, _, m2 = shadow_select_streaming(x, 0.05, chunk=128, block=16)
+    c2, w2, _, m2 = _stream(x, 0.05, chunk=128, block=16)
     assert m2 > 32  # the budget really was binding
 
 

@@ -136,8 +136,8 @@ np.testing.assert_allclose(np.asarray(d2), np.asarray(d0), atol=1e-4)
 # front door must work end-to-end (data_mesh's 'always safe' contract)
 from repro.core import fit
 mdl = fit(x[:1001], ker, 4, method="shadow", ell=4.0, mesh=mesh)
-r = dist.distributed_shadow_rsde(x[:1001], ker, 4.0, mesh)
-assert abs(r.weights.sum() - 1001) < 1e-3, r.weights.sum()
+r = shadow_rsde(x[:1001], ker, 4.0, mesh=mesh)
+assert r.weights.sum() == 1001, r.weights.sum()
 assert np.isfinite(mdl.transform(x[:77], mesh=mesh)).all()
 print("OK")
 """], env=env, capture_output=True, text=True, timeout=600)
